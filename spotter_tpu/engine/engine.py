@@ -12,6 +12,7 @@ The engine is synchronous (one device stream); `MicroBatcher` feeds it from
 async request handlers.
 """
 
+import logging
 import os
 import threading
 import time
@@ -23,6 +24,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 from PIL import Image
 
 from spotter_tpu import obs
@@ -51,6 +53,8 @@ from spotter_tpu.ops.preprocess import (
     device_rescale_normalize,
     shortest_edge_size,
 )
+
+logger = logging.getLogger(__name__)
 
 DEVICE_PREPROCESS_ENV = "SPOTTER_TPU_DEVICE_PREPROCESS"
 
@@ -125,6 +129,28 @@ def _host_checksum(a: np.ndarray) -> int:
     else:
         u = a
     return int(u.astype(np.uint64).sum() % (2**32))
+
+
+def describe_devices(devices: Sequence) -> dict:
+    """The `device` block of /healthz: what JAX reports for the devices an
+    engine places work on. Raises when they are CPUs nobody asked for: a
+    replica that found no accelerator must fail bring-up, not serve from
+    the host without a word. CPU serving (tests, the stub fleet) is asked
+    for by name, with `JAX_PLATFORMS` naming `cpu`."""
+    info = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    named = (jax.config.jax_platforms or "").lower().split(",")
+    if info["platform"] == "cpu" and "cpu" not in named:
+        raise RuntimeError(
+            f"no accelerator: JAX placed the engine on {info} and "
+            f"JAX_PLATFORMS={jax.config.jax_platforms!r} does not name cpu — "
+            "refusing to serve from the host silently (set JAX_PLATFORMS=cpu "
+            "to ask for it)"
+        )
+    return info
 
 
 def default_batch_buckets(max_batch: int = 8) -> tuple[int, ...]:
@@ -221,26 +247,14 @@ class InferenceEngine:
         else:
             forward = apply_post
 
-        # One compiled program per batch bucket; jit caches by shape. Only
-        # the uint8 staging buffer that device_rescale_normalize consumes is
-        # donated (it is per-call scratch and freeing it keeps HBM headroom
-        # at large buckets). The host-float path's pixel tensor is NOT: XLA
-        # cannot alias it to any of the tiny postprocess outputs, so donating
-        # it frees nothing and emits a "Some donated buffers were not
-        # usable: float32[...]" warning on every call (BENCH_r05 tail;
-        # ISSUE 5 satellite — tests/test_device_preprocess.py asserts the
-        # float path stays warning-free).
-        self._forward = jax.jit(
-            forward,
-            donate_argnums=(1,) if (donate_pixels and self.device_preprocess) else (),
-        )
+        self._forward_fn = forward
 
         # Open-vocabulary forward (ISSUE 13): same staging substrate, but the
         # query matrix is an ARGUMENT instead of a baked jit constant, so one
         # engine serves arbitrary vocabularies. The query count is padded to
         # a bucket (caching/text_cache.py QUERY_PAD) with a validity mask, so
         # the compile count is bounded by pad multiples, not vocabularies.
-        self._forward_q = None
+        self._forward_q_fn = None
         if built.text_encoder is not None:
 
             def apply_post_q(params, pixels, masks, target_sizes,
@@ -269,12 +283,57 @@ class InferenceEngine:
 
             else:
                 forward_q = apply_post_q
+            self._forward_q_fn = forward_q
+        self._donate_pixels = donate_pixels
+        self._jit_programs()
+
+    def _jit_programs(self) -> None:
+        """jit the forward(s) for the current placement; called again by
+        `rebuild_degraded`, whose narrower mesh is a different program.
+
+        One compiled program per batch bucket; jit caches by shape. Only
+        the uint8 staging buffer that device_rescale_normalize consumes is
+        donated (it is per-call scratch and freeing it keeps HBM headroom
+        at large buckets). The host-float path's pixel tensor is NOT: XLA
+        cannot alias it to any of the tiny postprocess outputs, so donating
+        it frees nothing and emits a "Some donated buffers were not
+        usable: float32[...]" warning on every call (pre-round record r05;
+        ISSUE 5 satellite — tests/test_device_preprocess.py asserts the
+        float path stays warning-free).
+        """
+        donate = (1,) if (self._donate_pixels and self.device_preprocess) else ()
+        self._forward = jax.jit(
+            self._data_parallel(self._forward_fn), donate_argnums=donate
+        )
+        self._forward_q = None
+        if self._forward_q_fn is not None:
             self._forward_q = jax.jit(
-                forward_q,
-                donate_argnums=(1,)
-                if (donate_pixels and self.device_preprocess)
-                else (),
+                self._data_parallel(self._forward_q_fn, n_replicated=2),
+                donate_argnums=donate,
             )
+
+    def _data_parallel(self, fn, n_replicated: int = 0):
+        """`fn(params, pixels, second, sizes, *replicated)` for this
+        placement. On a dp-only mesh it runs under `shard_map`: each chip
+        runs the one-chip program on its slice of the batch, params whole on
+        every chip. That is all data parallelism means here, and it is the
+        only form the chip's compiler takes for a program that holds a
+        Pallas kernel — left to the SPMD partitioner, lowering stops with
+        "Mosaic kernels cannot be automatically partitioned. Please wrap the
+        call in a shard_map." With tp > 1 the partitioner still places the
+        collectives, so a kernel-bearing program cannot serve tensor-parallel
+        on a TPU yet (ROADMAP D8)."""
+        if not self._shard_mapped:
+            return fn
+        batch, whole = P("dp"), P()
+        return jax.shard_map(
+            fn,
+            mesh=self.mesh,
+            in_specs=(whole, batch, batch, batch) + (whole,) * n_replicated,
+            out_specs=batch,
+            # the kernels' outputs carry no varying-axes annotation
+            check_vma=False,
+        )
 
     def _place(self, mesh, device, batch_buckets: Sequence[int]) -> None:
         """Bind params + input sharding + bucket ladder to a topology.
@@ -313,20 +372,26 @@ class InferenceEngine:
             self.device = device or jax.devices()[0]
             self.params = jax.device_put(self.built.params, self.device)
             self._in_sharding = self.device
-        # Device-efficiency plane (ISSUE 10): tell the perf ledger what
-        # chips it measures against (peak-TFLOPs autodetect keys on
-        # device_kind) and seed the HBM gauges with one synchronous sample
-        # (None-safe on CPU). Re-run on every re-place so a degraded
-        # rebuild's narrower device set is reflected in the MFU math.
-        try:
-            devs = self.devices()
-            self.metrics.perf.set_device_info(
-                getattr(devs[0], "device_kind", None) if devs else None,
-                len(devs),
-            )
-            sample_hbm_once(self.devices, self.metrics.perf)
-        except Exception:
-            pass
+        # What this engine runs on, checked and said once per placement;
+        # the same block answers /healthz. Re-run on every re-place so a
+        # degraded rebuild's narrower device set is reflected there and in
+        # the perf ledger's MFU math (peak-TFLOPs autodetect keys on
+        # device_kind). The HBM gauges get one synchronous seed sample
+        # (None-safe on CPU).
+        self.device_info = describe_devices(self.devices())
+        logger.info(
+            "engine placed on platform=%(platform)s kind=%(device_kind)s "
+            "count=%(count)d", self.device_info,
+        )
+        self.metrics.perf.set_device_info(
+            self.device_info["device_kind"], self.device_info["count"]
+        )
+        sample_hbm_once(self.devices, self.metrics.perf)
+
+    @property
+    def _shard_mapped(self) -> bool:
+        """True when the programs run under `_data_parallel`'s shard_map."""
+        return self.mesh is not None and self.tp == 1
 
     @property
     def dp(self) -> int:
@@ -487,6 +552,7 @@ class InferenceEngine:
 
             mesh = make_mesh(dp=new_dp, tp=1, devices=list(alive_devices)[:new_dp])
             self._place(mesh, None, new_buckets)
+            self._jit_programs()
             with self._compile_source("rebuild"):
                 self.warmup()
             # bumped only once the rescaled ladder is compiled and warm:
@@ -545,7 +611,12 @@ class InferenceEngine:
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else {}
         flops = ca.get("flops") if hasattr(ca, "get") else None
-        return perf_mod.combine_flops(flops, noted.get("__total__"))
+        total = perf_mod.combine_flops(flops, noted.get("__total__"))
+        if total is not None and self._shard_mapped:
+            # under `_data_parallel` the lowered module is ONE chip's
+            # program, and every chip of the dp axis runs it
+            total *= self.dp
+        return total
 
     def warmup(self) -> None:
         """Compile every bucket ahead of traffic (first compile is slow).

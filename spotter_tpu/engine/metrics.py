@@ -1,7 +1,7 @@
 """Serving metrics: images/sec and latency percentiles.
 
 The reference has no metrics endpoint (SURVEY.md §5.5); the north-star targets
-(BASELINE.md: >=2000 img/s, p50 < 40 ms) make them mandatory here. Lock-light
+(BASELINE.json: >=2000 img/s, p50 < 40 ms) make them mandatory here. Lock-light
 counters + a bounded reservoir; snapshot() is what /metrics serves.
 """
 

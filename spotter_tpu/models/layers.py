@@ -355,7 +355,7 @@ class PatchEmbed(nn.Module):
     avoids both XLA's small-channel conv lowering and the patch transpose:
     each `pixels[:, ry::P]` slice strides over CONTIGUOUS (gw*P*C)-element
     blocks (XLA copies those well — unlike the per-element minor-dim
-    strides that make 3-channel convs slow, BASELINE.md round 4), and each
+    strides that make 3-channel convs slow, pre-round note, round 4, git history), and each
     slice feeds one (B*gh*gw, P*C) @ (P*C, D) dot, accumulated in fp32.
     Measured on v5e bf16 at OWL-ViT patchify shapes ((8, 768^2, 3), P=32):
     2.89 ms vs 5.76 for the conv (the transpose-based reshape+matmul TIES

@@ -24,7 +24,7 @@ from spotter_tpu.models.layers import (
 )
 
 # Space-to-depth first stem conv (process-start knob, default off until the
-# measured win is recorded in BASELINE.md): the deep stem's 3x3 stride-2
+# measured win is recorded in the pre-round notes, git history): the deep stem's 3x3 stride-2
 # conv on (H, W, 3) runs at a few percent of MXU peak on v5e (3 input
 # channels). With SPOTTER_TPU_S2D_STEM=1 the same conv executes as
 # space-to-depth(2) + a 2x2 stride-1 conv over 12 channels — an EXACT

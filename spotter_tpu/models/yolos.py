@@ -119,7 +119,7 @@ class YolosDetector(nn.Module):
         t = cfg.num_detection_tokens
 
         # row-dot patchify (layers.PatchEmbed): exact conv rewrite, ~2x on
-        # v5e for 3-channel patchify (BASELINE.md round 4)
+        # v5e for 3-channel patchify (pre-round note, round 4, git history)
         x = PatchEmbed(
             cfg.hidden_size, p, dtype=self.dtype, name="patch_projection"
         )(pixel_values)

@@ -67,21 +67,21 @@ FAST_WINDOW_S = 60.0
 SLOW_WINDOW_S = 1800.0
 
 # Peak dense bf16 TFLOPs per chip by device_kind substring (first match
-# wins; sources: public TPU spec sheets). The CPU entry is a rough host
-# figure so CPU test runs produce finite, nonzero MFU instead of None.
+# wins; source: the Google Cloud TPU documentation page of each generation —
+# "TPU v5e": 197 TFLOP/s bf16 per chip). Each marker names one generation:
+# there is no catch-all, and no CPU row — a device this table does not know
+# has no peak, and its MFU is not measured (peak_tflops None, mfu_pct 0.0).
 _PEAK_TFLOPS_BY_KIND = (
     ("v6e", 918.0),
+    ("v6 lite", 918.0),
     ("trillium", 918.0),
-    ("v6", 918.0),
     ("v5p", 459.0),
     ("v5e", 197.0),
     ("v5 lite", 197.0),
     ("v5litepod", 197.0),
-    ("v5", 197.0),
     ("v4", 275.0),
     ("v3", 123.0),
     ("v2", 46.0),
-    ("cpu", 0.2),
 )
 
 
@@ -108,8 +108,9 @@ def perf_enabled() -> bool:
 def peak_tflops_for(device_kind: str | None) -> float | None:
     """Per-chip peak TFLOPs: the env override wins, then the kind table.
 
-    Unknown kinds (new accelerators, GPUs) return None — MFU then reports
-    0.0 rather than a number computed against a made-up peak.
+    Unknown kinds (new accelerators, GPUs, the CPU backend) return None —
+    MFU then reports 0.0 rather than a number computed against a made-up
+    peak.
     """
     raw = os.environ.get(PEAK_TFLOPS_ENV, "").strip()
     if raw:

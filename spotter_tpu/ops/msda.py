@@ -1028,7 +1028,7 @@ pallas_onehot_sampling_merged.defvjp(_onehot_merged_fwd, _onehot_merged_bwd)
 # in-bounds corner when each level tile spans whole rows (ts % W == 0:
 # tile_of(y0*W + x0) == y0 // rows_per_tile for any x0 < W), a superset
 # otherwise only for out-of-bounds corners whose weight the kernel zeroes.
-# Default stays "xla" until the on-chip A/B records a win (BASELINE.md).
+# Default stays "xla" until the on-chip A/B records a win.
 #
 # TRAINING caveat (ADVICE r3): this path's custom VJP backward runs the
 # jnp gather reference (_loc_ref) plus a forward recompute, so under
@@ -1523,8 +1523,8 @@ def deformable_sampling(
     if (MSDA_SG or MSDA_NEST) and backend is not None and chosen != "pallas":
         # Same contract as the import-time env guards (above, after the
         # MSDA_SG parse) but scoped to EXPLICIT per-call `backend=`
-        # overrides, so e.g. bench_msda with SPOTTER_TPU_MSDA_SG=8
-        # --backends pallas,pallas_sep cannot silently no-op the knobs and
+        # overrides, so e.g. an A/B harness with SPOTTER_TPU_MSDA_SG=8 over
+        # backends pallas,pallas_sep cannot silently no-op the knobs and
         # record a wrong A/B conclusion. Auto resolution is NOT re-checked
         # here: the import-time guard already rejected hosts where auto
         # cannot mean pallas (ADVICE r5 #3 — the old resolved-backend check

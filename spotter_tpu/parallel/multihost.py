@@ -43,17 +43,6 @@ def coordinator_timeout_s() -> int:
     return timeout
 
 
-def _distributed_is_initialized() -> bool:
-    """`jax.distributed.is_initialized` with a fallback for jax versions
-    that predate the public accessor (the distributed client global)."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    from jax._src.distributed import global_state
-
-    return global_state.client is not None
-
-
 def multihost_env_summary() -> dict:
     """The env contract the k8s template must satisfy (also used by tests)."""
     return {
@@ -68,19 +57,15 @@ def multihost_env_summary() -> dict:
 
 def _enable_cpu_collectives() -> None:
     """On the CPU backend, multi-process computations need a CPU collectives
-    implementation (jax >= 0.4.34 ships gloo but defaults to "none", which
-    fails any cross-process jit with "Multiprocess computations aren't
-    implemented on the CPU backend"). The 2-process CPU dryruns — the
-    driver-gate stand-in for a DCN slice (tests/test_multihost.py) — hit
-    exactly that, so arm gloo before distributed init when we're on CPU.
-    Must run before the backend initializes; a no-op on TPU or when the jax
-    version predates the option."""
+    implementation: the default "none" fails any cross-process jit with
+    "Multiprocess computations aren't implemented on the CPU backend". The
+    2-process CPU dryruns — the stand-in for a DCN slice
+    (tests/test_multihost.py) — hit exactly that, so arm gloo before
+    distributed init when we're on CPU. Must run before the backend
+    initializes; a no-op on TPU."""
     if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
         return
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # option absent in this jax, or backend already up
-        logger.debug("could not arm gloo CPU collectives", exc_info=True)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def initialize_multihost(force: bool = False) -> bool:
@@ -89,22 +74,26 @@ def initialize_multihost(force: bool = False) -> bool:
     Returns True when distributed init ran (or had already run), False for the
     single-host case. Safe to call unconditionally at serving bootstrap — the
     single-host path is a no-op, mirroring how the reference's serve.py runs
-    identically in 1-pod and autoscaled deployments.
+    identically in 1-pod and autoscaled deployments. A TPU host may export
+    both variables for a single worker: one hostname is one host, and one
+    host never joins jax.distributed whatever the variables say.
     """
     env = multihost_env_summary()
-    hostnames = env["TPU_WORKER_HOSTNAMES"]
+    hostnames = env["TPU_WORKER_HOSTNAMES"] or ""
     worker_id = env["TPU_WORKER_ID"]
-    if not hostnames or worker_id is None:
+    hosts = [h.strip() for h in hostnames.split(",") if h.strip()]
+    if not hosts or worker_id is None:
         if force:
             raise RuntimeError(
                 "initialize_multihost(force=True) but TPU_WORKER_HOSTNAMES / "
                 "TPU_WORKER_ID are not set"
             )
         return False
+    if len(hosts) == 1:
+        return False
 
-    hosts = [h.strip() for h in hostnames.split(",") if h.strip()]
     coordinator = f"{hosts[0]}:{env['SPOTTER_COORDINATOR_PORT']}"
-    if _distributed_is_initialized():  # already up
+    if jax.distributed.is_initialized():  # already up
         return True
     _enable_cpu_collectives()
     timeout_s = coordinator_timeout_s()

@@ -103,18 +103,19 @@ def build_detector_app(
     if not model_name:
         raise ValueError("MODEL_NAME environment variable not set.")
     # Warm restart (ISSUE 2): arm JAX's persistent compilation cache
-    # (SPOTTER_TPU_COMPILE_CACHE_DIR) before the first jit — a preempted
-    # replica restarting on the same model + bucket ladder then loads its
-    # compiled programs from disk instead of recompiling them, which is
-    # most of time_to_ready_s.
-    from spotter_tpu.serving.lifecycle import maybe_enable_compile_cache
+    # (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache) before the
+    # first jit — a preempted replica restarting on the same model + bucket
+    # ladder then loads its compiled programs from disk instead of
+    # recompiling them, which is most of time_to_ready_s.
+    from spotter_tpu.serving.lifecycle import enable_compile_cache
 
-    maybe_enable_compile_cache()
+    enable_compile_cache()
     env_buckets = False
     if batch_buckets is None:
         # Per-model ladder tuning is a deployment concern: R18's per-chip
-        # peak is batch 16 (485 vs 449 img/s — BASELINE.md round-4 sweep),
-        # R101's is batch 8; the default stays the conservative 8-max.
+        # peak is batch 16 (485 vs 449 img/s — pre-round note, round 4, git
+        # history), R101's is batch 8; the default stays the conservative
+        # 8-max.
         # `is not None` (not truthiness): an explicitly-set empty value is
         # a malformed spec and must raise, not silently serve the default.
         spec = os.environ.get("SPOTTER_TPU_BATCH_BUCKETS")

@@ -755,6 +755,9 @@ class AmenitiesDetector:
             # replica serves — a mixed-version window during a rollout is
             # auditable per pod, same as the topology flags below
             "version": self.engine.metrics.version,
+            # what JAX placed the engine on (platform, device_kind, count);
+            # None for the model-free stub engine
+            "device": getattr(self.engine, "device_info", None),
             # ingest/topology config (ISSUE 3): which serving shape this
             # replica runs — dp width and whether preprocess is on-device —
             # so a fleet rollout of the new pipeline is auditable per pod

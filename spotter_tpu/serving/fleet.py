@@ -30,7 +30,7 @@ arXiv:2501.14417, is the blueprint for the serverless half):
 - **Scale-to-zero + restore**: a managed pool idle for
   `SPOTTER_TPU_SCALE_TO_ZERO_S` drains and stops all members; the next
   classed request triggers a demand restore through the persistent compile
-  cache (SPOTTER_TPU_COMPILE_CACHE_DIR), with `time_to_ready_s` measured
+  cache (`lifecycle.compile_cache_dir()`), with `time_to_ready_s` measured
   restore-trigger -> first member available and published in /metrics —
   the <15 s (stubbed) gate `bench.py --preemption-storm` records.
 

@@ -20,10 +20,12 @@ serverless serving viable). Three pieces:
   the detector's existing `drain()`, and exits with a DISTINCT code
   (`PREEMPTED_EXIT_CODE`) so the supervisor can tell preemption from a crash
   and skip the crash-loop backoff.
-- `maybe_enable_compile_cache()`: points JAX's persistent compilation cache
-  at `SPOTTER_TPU_COMPILE_CACHE_DIR` before any program is compiled, so a
-  restarted replica (same model, same bucket ladder) skips recompilation —
-  the difference between a minutes-long and a seconds-long `time_to_ready_s`.
+- `enable_compile_cache()`: arms JAX's persistent compilation cache before
+  any program is compiled, so a restarted replica (same model, same bucket
+  ladder) skips recompilation — the difference between a minutes-long and a
+  seconds-long `time_to_ready_s`. The cache is placed from outside by JAX's
+  own `JAX_COMPILATION_CACHE_DIR`; unset, it lives at the fixed
+  `<checkout>/.jax_cache` (`compile_cache_dir()`).
 """
 
 import asyncio
@@ -35,7 +37,16 @@ from typing import Awaitable, Callable, Optional
 
 logger = logging.getLogger(__name__)
 
-COMPILE_CACHE_ENV = "SPOTTER_TPU_COMPILE_CACHE_DIR"
+# JAX's own variable: when set, JAX reads it itself and this module sets no
+# directory in code.
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# The fallback is a fixed path inside the checkout — never a temp name, pid
+# or timestamp: the directory is part of the cache key, so one that moves
+# never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 PREEMPTION_FILE_ENV = "SPOTTER_TPU_PREEMPTION_FILE"
 PREEMPTION_URL_ENV = "SPOTTER_TPU_PREEMPTION_URL"
 PREEMPTION_POLL_ENV = "SPOTTER_TPU_PREEMPTION_POLL_S"
@@ -89,23 +100,30 @@ INTEGRITY_EXIT_CODE = 86
 _PROCESS_START = time.monotonic()
 
 
-def maybe_enable_compile_cache() -> Optional[str]:
-    """Arm JAX's persistent compilation cache from the env (idempotent).
+def compile_cache_dir() -> str:
+    """The persistent compile-cache directory this process uses: wherever
+    `JAX_COMPILATION_CACHE_DIR` places it, else `<checkout>/.jax_cache`.
+    jax-free, so the supervisor resolves (and quarantines) the same dir."""
+    return os.environ.get(JAX_CACHE_DIR_ENV, "").strip() or DEFAULT_COMPILE_CACHE_DIR
+
+
+def enable_compile_cache() -> str:
+    """Arm JAX's persistent compilation cache (idempotent).
 
     Must run before the first jit compilation of the process. Thresholds are
     zeroed so every bucket program is cached — the ladder is a handful of
     programs and a preempted replica wants all of them back.
     """
-    cache_dir = os.environ.get(COMPILE_CACHE_ENV, "").strip()
-    if not cache_dir:
-        return None
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = compile_cache_dir()
+    if cache_dir != (os.environ.get(JAX_CACHE_DIR_ENV) or "").strip():
+        # not placed from outside (then JAX's own handling stands)
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    logger.info("persistent compile cache enabled at %s (warm restart)", cache_dir)
+    logger.info("persistent compile cache at %s", cache_dir)
     return cache_dir
 
 

@@ -18,8 +18,9 @@ Replica lifecycle (ISSUE 2): the HTTP surface binds BEFORE the model loads —
 bring-up runs as a background task through the `loading -> warming -> ready`
 state machine exposed at /startupz, so a k8s startupProbe can wait out a
 long warmup without the pod being killed (readiness stays 503 throughout).
-`SPOTTER_TPU_COMPILE_CACHE_DIR` arms JAX's persistent compilation cache
-before the engine compiles, making a post-preemption restart warm;
+JAX's persistent compilation cache (`JAX_COMPILATION_CACHE_DIR`, else
+`<checkout>/.jax_cache`) is armed before the engine compiles, making a
+post-preemption restart warm;
 `time_to_ready_s` and `restarts_total` (from `SPOTTER_TPU_RESTARTS`, set by
 the supervisor) land in /metrics. A `PreemptionWatcher` (SIGTERM + the
 `SPOTTER_TPU_PREEMPTION_FILE`/`_URL` maintenance source) drains and exits
@@ -104,9 +105,9 @@ def _not_ready_response(tracker: lifecycle.StartupTracker) -> web.Response:
 
 
 def _build_detector_blocking(model_name: str | None):
-    """The heavy half of bring-up, run in an executor: compile-cache arming
-    must precede the first jit, then the model/engine build."""
-    lifecycle.maybe_enable_compile_cache()
+    """The heavy half of bring-up, run in an executor: the model/engine
+    build (`build_detector_app` arms the compile cache before its first
+    jit; the stub engine compiles nothing)."""
     if stub_engine.stub_mode_enabled():
         logger.warning(
             "STUB ENGINE ACTIVE (%s) — canned detections, no device; "

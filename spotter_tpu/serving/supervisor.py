@@ -66,10 +66,10 @@ import time
 
 from spotter_tpu.engine.errors import FATAL_ENGINE_EXIT_CODE
 from spotter_tpu.serving.lifecycle import (
-    COMPILE_CACHE_ENV,
     INTEGRITY_EXIT_CODE,
     PREEMPTED_EXIT_CODE,
     RESTARTS_ENV,
+    compile_cache_dir,
 )
 
 # The jitter knob moved to serving/resilience.py (ISSUE 8 satellite: the
@@ -98,9 +98,9 @@ def quarantine_compile_cache() -> str | None:
     re-ingest, so it is renamed — never deleted, the quarantined copy IS
     the forensic artifact — to `<dir>.quarantined.<n>`. The child then
     recreates the dir empty and recompiles from scratch. Returns the
-    quarantine path, or None when no cache dir is configured/present."""
-    cache_dir = os.environ.get(COMPILE_CACHE_ENV, "").strip()
-    if not cache_dir or not os.path.isdir(cache_dir):
+    quarantine path, or None when the cache dir does not exist yet."""
+    cache_dir = compile_cache_dir()
+    if not os.path.isdir(cache_dir):
         return None
     n = 0
     while True:
@@ -117,6 +117,7 @@ def quarantine_compile_cache() -> str | None:
         "quarantined suspect compile cache: %s -> %s", cache_dir, target
     )
     return target
+
 
 class Supervisor:
     def __init__(

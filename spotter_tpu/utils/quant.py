@@ -40,13 +40,13 @@ INT8_ENV = "SPOTTER_TPU_INT8"
 INT8 = os.environ.get(INT8_ENV, "0").strip() != "0"
 
 # Channel floor: small-channel convs (the stem) are lowering-bound, not
-# MXU-bound (BASELINE.md round 4 — the ~2.5 ms stem gap is a compiler/ISA
+# MXU-bound (pre-round note, round 4, git history — the ~2.5 ms stem gap is a compiler/ISA
 # limitation int8 cannot touch), and quantizing them would add a quantize
 # pass for no MXU win. Contraction dim (k*k*cin) must fill the MXU.
 INT8_MIN_CH = int(os.environ.get("SPOTTER_TPU_INT8_MIN_CH", "64"))
 
 # Batch floor (ISSUE 3): int8 REGRESSES small batches — R101 bucket 4
-# measured 33.0 vs 18.7 ms/call bf16 (BASELINE round 5): under-filled MXU
+# measured 33.0 vs 18.7 ms/call bf16 (pre-round note, round 5, git history): under-filled MXU
 # contractions make the quantize/dequant passes pure overhead. Batch is a
 # static shape under jit, so the guard resolves per compiled bucket: the
 # default `--int8` serving config quantizes the batch>=8 throughput buckets
@@ -63,7 +63,7 @@ def int8_wanted(in_channels: int, batch: int | None = None) -> bool:
 
 # Dense projections (QuantDense in models/layers.py) are a SEPARATE opt-in:
 # SPOTTER_TPU_INT8=1 reproduces exactly the conv-only config the R101/R18
-# numbers were measured with (BASELINE.md round 5), while
+# numbers were measured with (pre-round note, round 5, git history), while
 # SPOTTER_TPU_INT8_DENSE=1 additionally quantizes the attention/FFN
 # projections routed through QuantDense (ViT towers, MultiHeadAttention —
 # measured +6% on yolos on top of the block-q win). Keeping the gates

@@ -119,13 +119,12 @@ def test_main_handles_unreadable_file(tmp_path, capsys):
     assert "unreadable" in capsys.readouterr().err
 
 
-def test_load_record_roundtrip_on_repo_evidence(tmp_path):
-    # the committed BENCH_r04/r05 evidence wrappers must satisfy the guard
-    # (the CI self-check depends on it)
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for name in ("BENCH_r04.json", "BENCH_r05.json"):
-        record, problems = load_record(os.path.join(repo, name))
-        assert problems == [], problems
-        assert record["unit"] == "images/sec"
+def test_load_record_roundtrip_on_evidence_wrapper(tmp_path):
+    # the driver's evidence wrapper ({"n", "cmd", "rc", "parsed"}) around a
+    # bench record must satisfy the guard; the test writes its own wrapper
+    # (the repo commits no bench records any more)
+    wrapper = {"n": 5, "cmd": "python bench.py", "rc": 0, "parsed": GOOD}
+    path = _write(tmp_path, "BENCH_wrapped.json", wrapper)
+    record, problems = load_record(path)
+    assert problems == [], problems
+    assert record == GOOD and record["unit"] == "images/sec"

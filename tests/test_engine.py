@@ -223,7 +223,7 @@ def test_host_float_path_emits_no_donation_warning():
     device_rescale_normalize consumes is donated. The host-float path's
     float pixels can never alias the tiny postprocess outputs, so donating
     them freed nothing and warned "Some donated buffers were not usable:
-    float32[...]" on every call (BENCH_r05 tail)."""
+    float32[...]" on every call (pre-round record r05)."""
     import warnings
 
     built = build_detector("PekingU/rtdetr_v2_r101vd")

@@ -78,7 +78,7 @@ def test_splash_interpret_matches_naive_on_cpu():
 
 
 def test_splash_block_kv_policy():
-    """The swept block_kv ladder (BASELINE.md rounds 3-4): 2304 when it
+    """The swept block_kv ladder (pre-round note, round 3-4, git history): 2304 when it
     divides the padded length (yolos 4608), full-row kv up to 3840
     (owlv2's 3601->3840: 10.18 vs 12.67 ms/layer at the old 768
     fallback), else the 768-multiple fallback."""

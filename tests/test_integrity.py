@@ -703,7 +703,7 @@ def test_supervisor_exit_code_ladder(
     from spotter_tpu.serving.supervisor import Supervisor
 
     cache_dir = tmp_path / "compile-cache"
-    monkeypatch.setenv(lifecycle.COMPILE_CACHE_ENV, str(cache_dir))
+    monkeypatch.setenv(lifecycle.JAX_CACHE_DIR_ENV, str(cache_dir))
     counter = tmp_path / "count"
     counter.write_text(str(failures))
     sup = Supervisor(
@@ -727,6 +727,24 @@ def test_supervisor_exit_code_ladder(
             f"compile-cache.quarantined.{i}"
             for i in range(want_quarantined)
         ]
+
+
+def test_quarantine_follows_the_resolved_cache_dir(tmp_path, monkeypatch):
+    """The supervisor quarantines whatever `lifecycle.compile_cache_dir()`
+    resolves: JAX's variable when set, the fixed checkout path when not."""
+    from spotter_tpu.serving import supervisor
+
+    placed = tmp_path / "placed"
+    fixed = tmp_path / ".jax_cache"
+    monkeypatch.setattr(lifecycle, "DEFAULT_COMPILE_CACHE_DIR", str(fixed))
+    for d in (placed, fixed):
+        d.mkdir()
+    monkeypatch.setenv(lifecycle.JAX_CACHE_DIR_ENV, str(placed))
+    assert supervisor.quarantine_compile_cache() == f"{placed}.quarantined.0"
+    assert fixed.is_dir() and not placed.exists()
+    monkeypatch.delenv(lifecycle.JAX_CACHE_DIR_ENV)
+    assert supervisor.quarantine_compile_cache() == f"{fixed}.quarantined.0"
+    assert supervisor.quarantine_compile_cache() is None  # nothing left to move
 
 
 def test_exit_codes_are_distinct():

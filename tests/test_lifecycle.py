@@ -219,17 +219,47 @@ def test_preemption_trigger_is_idempotent():
 # ---- warm restart plumbing ----
 
 
-def test_compile_cache_env(monkeypatch, tmp_path):
-    cache_dir = tmp_path / "compile-cache"
-    monkeypatch.setenv(lifecycle.COMPILE_CACHE_ENV, str(cache_dir))
-    assert lifecycle.maybe_enable_compile_cache() == str(cache_dir)
-    assert cache_dir.is_dir()
+@pytest.fixture
+def restore_jax_cache_dir():
     import jax
 
-    assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
 
-    monkeypatch.delenv(lifecycle.COMPILE_CACHE_ENV)
-    assert lifecycle.maybe_enable_compile_cache() is None
+
+def test_compile_cache_placed_by_jax_env(monkeypatch, tmp_path, restore_jax_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR set: JAX's own handling stands — the helper
+    resolves that directory and sets NO directory in code."""
+    import jax
+
+    cache_dir = tmp_path / "compile-cache"
+    jax.config.update("jax_compilation_cache_dir", "/sentinel/untouched")
+    monkeypatch.setenv(lifecycle.JAX_CACHE_DIR_ENV, str(cache_dir))
+    assert lifecycle.compile_cache_dir() == str(cache_dir)
+    assert lifecycle.enable_compile_cache() == str(cache_dir)
+    assert jax.config.jax_compilation_cache_dir == "/sentinel/untouched"
+    assert not cache_dir.exists()  # JAX creates what JAX was pointed at
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compile_cache_default_is_fixed_path_in_checkout(
+    monkeypatch, tmp_path, restore_jax_cache_dir
+):
+    """Unset: the cache is `<checkout>/.jax_cache` — a fixed path (the
+    directory is part of the cache key), never a temp name, pid or time."""
+    import jax
+
+    monkeypatch.delenv(lifecycle.JAX_CACHE_DIR_ENV)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert lifecycle.DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert lifecycle.compile_cache_dir() == lifecycle.DEFAULT_COMPILE_CACHE_DIR
+    # armed against a stand-in so the test leaves the checkout alone
+    fixed = tmp_path / ".jax_cache"
+    monkeypatch.setattr(lifecycle, "DEFAULT_COMPILE_CACHE_DIR", str(fixed))
+    assert lifecycle.enable_compile_cache() == str(fixed)
+    assert fixed.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(fixed)
 
 
 def test_restarts_from_env(monkeypatch):
@@ -352,7 +382,7 @@ def test_initialize_passes_timeout_to_jax(monkeypatch):
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "h0,h1")
     monkeypatch.setenv(multihost.COORD_TIMEOUT_ENV, "17")
     monkeypatch.setattr(jax.distributed, "initialize", fake_initialize)
-    monkeypatch.setattr(multihost, "_distributed_is_initialized", lambda: False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
     assert multihost.initialize_multihost() is True
     assert captured["initialization_timeout"] == 17
     assert captured["num_processes"] == 2
@@ -370,7 +400,7 @@ def test_initialize_wraps_coordinator_failure(monkeypatch):
     monkeypatch.setenv("TPU_WORKER_ID", "1")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "h0,h1")
     monkeypatch.setattr(jax.distributed, "initialize", exploding_initialize)
-    monkeypatch.setattr(multihost, "_distributed_is_initialized", lambda: False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
     with pytest.raises(RuntimeError, match="multihost bring-up failed"):
         multihost.initialize_multihost()
 

@@ -98,10 +98,9 @@ def test_dryrun_real_r18_architecture_sharded():
     config (VERDICT r2 weak #4)."""
     import __graft_entry__ as graft
 
-    # conftest.py already forced the 8-device CPU mesh in this process, so
-    # the impl runs inline (no subprocess re-exec).
+    # conftest.py forced the 8-device CPU mesh in this process
     assert jax.device_count() >= 8
-    graft._dryrun_multichip_impl(8, preset="rtdetr_v2_r18vd")
+    graft.dryrun_multichip(8, preset="rtdetr_v2_r18vd")
 
 
 def test_dp2_serving_engine_fast_tier(tiny_model):

@@ -140,7 +140,10 @@ def test_peak_tflops_autodetect(monkeypatch):
     assert peak_tflops_for("TPU v5e") == 197.0
     assert peak_tflops_for("TPU v5p") == 459.0
     assert peak_tflops_for("TPU v4") == 275.0
-    assert peak_tflops_for("cpu") == 0.2
+    assert peak_tflops_for("TPU v6 lite") == 918.0
+    # no CPU row and no catch-all generation: an unknown device has no peak
+    assert peak_tflops_for("cpu") is None
+    assert peak_tflops_for("TPU v5") is None
     assert peak_tflops_for("weird-new-chip") is None
     assert peak_tflops_for(None) is None
     monkeypatch.setenv(perf_mod.PEAK_TFLOPS_ENV, "123.5")
@@ -384,9 +387,12 @@ def test_engine_dispatches_land_in_mfu_ledger(rtdetr_engine):
     rtdetr_engine.detect([img])
     snap = rtdetr_engine.metrics.snapshot()
     assert snap["device_kind"] == "cpu"
-    assert snap["peak_tflops"] == 0.2  # the CPU table entry
+    # a CPU run measures no MFU: no peak for it, so no number under the
+    # device metric's name
+    assert snap["peak_tflops"] is None
+    assert snap["mfu_pct"] == 0.0 and snap["useful_mfu_pct"] == 0.0
     assert snap["device_duty_cycle_pct"] > 0.0
-    assert snap["mfu_pct"] > 0.0  # cost-analysis FLOPs resolved
+    assert snap["perf_raw"]["flops"] > 0.0  # cost-analysis FLOPs resolved
     top = rtdetr_engine.metrics.perf.top_dispatches()
     assert top and top[0]["flops"] and top[0]["flops"] > 0
 

@@ -1,0 +1,234 @@
+"""The main path's kernels, compiled for a described v5e (no chip attached).
+
+The TPU's compiler is installed wherever libtpu is, and it compiles for a chip
+that is described and not attached. Interpret-mode tests cannot show what it
+shows: kernels that had passed every one of them were refused here for a
+primitive the Pallas TPU lowering lacks (`expm1`, ops/openvocab.py) and for
+more scalar memory than a kernel may prefetch (Deformable-DETR's hit table,
+below). A compile that passes is not a chip run: nothing executes, so these
+say nothing about results or times.
+
+Shapes are the published ones: the RT-DETRv2-R101 decoder's sampling at serving
+batch 8, the ViT towers' token counts (YOLOS-base 4396, OWLv2-B/16 3601), the
+OWL logit head at OWLv2-B/16 (3600 patches) and OWL-ViT-B/32 (576) against the
+22-amenity vocabulary. The whole R101 engine program per bucket (~30 s each)
+is under `-m slow`.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+import spotter_tpu.models.rtdetr as rtdetr_mod
+from spotter_tpu.models import layers
+from spotter_tpu.ops import msda
+from spotter_tpu.ops.openvocab import fused_class_logits
+
+
+@pytest.fixture(scope="module")
+def host_of_four():
+    """The four described chips of a v5e 2x2 host."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu here, or another process holds it
+        pytest.skip(f"cannot describe a v5e topology: {exc!r}")
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def chip(host_of_four):
+    """One described v5e chip, as a sharding for abstract arguments."""
+    return SingleDeviceSharding(host_of_four[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def as_deployed():
+    """Persistent cache off: a compile for a described chip is written to it
+    but cannot be read back without one, so the next would warn and compile
+    again. And matmul precision as a deployment has it: conftest pins
+    "highest" for the torch-parity tests, under which a bf16 program is not
+    what is served (and the splash kernel is refused: fp32 contraction
+    precision on bf16 operands, "Bad lhs type")."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache)
+    jax.config.update("jax_default_matmul_precision", precision)
+    compilation_cache.reset_cache()
+
+
+def compile_for(chip, fn, *shapes_dtypes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes_dtypes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def sampling_args(
+    batch, queries, spatial_shapes, dtype=jnp.bfloat16, heads=8, head_dim=32, points=4
+):
+    """(value, loc, attn) of one MSDA call."""
+    s = sum(h * w for h, w in spatial_shapes)
+    lp = len(spatial_shapes) * points
+    return (
+        ((batch, s, heads, head_dim), dtype),
+        ((batch, queries, heads, lp, 2), dtype),
+        ((batch, queries, heads, lp), dtype),
+    )
+
+
+# the two policies a replica serves under, with the MXU pass count
+# ops/msda.py derives from each at import (utils/precision.py)
+POLICIES = {
+    "bfloat16": (jnp.bfloat16, jax.lax.Precision.DEFAULT),
+    "float32": (jnp.float32, jax.lax.Precision.HIGHEST),
+}
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_onehot_msda_kernel_at_r101_decoder_shapes(chip, policy, monkeypatch):
+    # 640x640 -> strides 8/16/32; 300 queries, 8 heads of 32, 3 levels x 4 points
+    dtype, mxu_precision = POLICIES[policy]
+    monkeypatch.setattr(msda, "MSDA_MXU_PRECISION", mxu_precision)
+    shapes = ((80, 80), (40, 40), (20, 20))
+    fn = partial(
+        msda.deformable_sampling, spatial_shapes=shapes, num_points=4,
+        backend="pallas", presorted=True,
+    )
+    compiled = compile_for(chip, fn, *sampling_args(8, 300, shapes, dtype))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [4396, 3601], ids=["yolos-base", "owlv2-b16"])
+def test_splash_attention_at_vit_token_counts(chip, tokens):
+    qkv = ((8, tokens, 12, 64), jnp.bfloat16)
+    compiled = compile_for(chip, layers._splash_self_attention, qkv, qkv, qkv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("patches", [3600, 576], ids=["owlv2-b16", "owlvit-b32"])
+def test_owl_logit_kernel_at_published_sizes(chip, patches):
+    """The repaired head (exp in place of expm1) against the 22 amenities."""
+    fn = partial(fused_class_logits, query_mask=None)
+    compiled = compile_for(
+        chip, fn,
+        ((8, patches, 512), jnp.float32), ((22, 512), jnp.float32),
+        ((8, patches), jnp.float32), ((8, patches), jnp.float32),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="ROADMAP R1: at Deformable-DETR's published 800x1333 the one-hot "
+    "kernel's scalar-prefetched block-sparse hit table (ops/msda.py, "
+    "num_scalar_prefetch=1) outgrows SMEM at ~22k encoder queries: "
+    "RESOURCE_EXHAUSTED ... space=smem ... 'prefetched SMEM operand 0'. "
+    "Recorded, not fixed; it fails loudly at warm-up. When R1 lands and this "
+    "compiles, strict xfail turns the pass into a failure: drop the marker.",
+)
+def test_onehot_msda_kernel_at_deformable_detr_encoder_shapes(chip):
+    # 800x1333 -> four levels, every encoder token is a query (22,223 of them)
+    shapes = ((100, 167), (50, 84), (25, 42), (13, 21))
+    queries = sum(h * w for h, w in shapes)
+    fn = partial(
+        msda.deformable_sampling, spatial_shapes=shapes, num_points=4,
+        backend="pallas", presorted=True,
+    )
+    compile_for(chip, fn, *sampling_args(2, queries, shapes))
+
+
+def lower_engine_program(monkeypatch, bucket, batch_sharding, param_shardings, mesh=None):
+    """Lower the engine's own program (host-float ingest + forward +
+    postprocess) at one bucket for described devices. `auto` asks
+    `jax.default_backend()`, which is the CPU here, so the one sampling call
+    site is steered onto the kernel the chip would pick — after the build,
+    whose init RUNS on the CPU. The engine cannot be PLACED on devices that
+    are not attached: it is built on the CPU and, for a mesh, handed it."""
+    from spotter_tpu.engine.engine import InferenceEngine
+    from spotter_tpu.models import build_detector
+
+    built = build_detector("rtdetr_v2_r101vd")  # the tiny toy under TINY_ENV
+    monkeypatch.setattr(
+        rtdetr_mod, "deformable_sampling",
+        partial(msda.deformable_sampling, backend="pallas"),
+    )
+    engine = InferenceEngine(built, batch_buckets=(bucket,))
+    if mesh is not None:
+        engine.mesh = mesh
+        engine._jit_programs()
+    h, w = built.preprocess_spec.input_hw
+    params = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        built.params, param_shardings(built.params),
+    )
+    return engine._forward.lower(
+        params,
+        jax.ShapeDtypeStruct((bucket, h, w, 3), jnp.float32, sharding=batch_sharding),
+        jax.ShapeDtypeStruct((bucket, h, w), jnp.float32, sharding=batch_sharding),
+        jax.ShapeDtypeStruct((bucket, 2), jnp.float32, sharding=batch_sharding),
+    )
+
+
+def lower_tiny_engine_on(devices, dp, tp, monkeypatch):
+    """The tiny RT-DETR's bucket-4 program over a described dp x tp mesh."""
+    from spotter_tpu.models import zoo
+    from spotter_tpu.parallel.sharding import (
+        RTDETR_TP_RULES, data_sharding, param_shardings,
+    )
+
+    monkeypatch.setenv(zoo.TINY_ENV, "1")
+    mesh = Mesh(np.asarray(devices).reshape(dp, tp), ("dp", "tp"))
+    rules = RTDETR_TP_RULES if tp > 1 else ()
+    return lower_engine_program(
+        monkeypatch, 4, data_sharding(mesh),
+        lambda params: param_shardings(params, mesh, rules), mesh=mesh,
+    )
+
+
+def test_kernel_program_lowers_data_parallel_over_four_chips(host_of_four, monkeypatch):
+    """dp=4: the engine runs the one-chip program under shard_map, which is
+    the form a kernel-bearing program lowers in."""
+    lowered = lower_tiny_engine_on(host_of_four, dp=4, tp=1, monkeypatch=monkeypatch)
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+@pytest.mark.xfail(
+    strict=True,
+    raises=NotImplementedError,
+    reason="ROADMAP D8: with tp > 1 the SPMD partitioner places the "
+    "collectives, and it refuses a program that holds a Pallas kernel: "
+    "'Mosaic kernels cannot be automatically partitioned. Please wrap the "
+    "call in a shard_map.' So --serve-tp cannot serve a kernel-bearing family "
+    "on a TPU (it fails loudly at warm-up); only kernels-off "
+    "(SPOTTER_TPU_MSDA=xla) tensor-parallel serving lowers today.",
+)
+def test_kernel_program_lowers_tensor_parallel(host_of_four, monkeypatch):
+    lower_tiny_engine_on(host_of_four, dp=2, tp=2, monkeypatch=monkeypatch)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("bucket", [1, 2, 4, 8])
+def test_r101_engine_program_per_bucket(chip, bucket, monkeypatch):
+    """The seeded R101's engine program at every bucket of the default ladder."""
+    from spotter_tpu.models import zoo
+
+    monkeypatch.delenv(zoo.TINY_ENV, raising=False)  # earlier files may leave it set
+    lowered = lower_engine_program(
+        monkeypatch, bucket, chip,
+        lambda params: jax.tree_util.tree_map(lambda _: chip, params),
+    )
+    assert "tpu_custom_call" in lowered.compile().as_text()

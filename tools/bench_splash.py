@@ -11,7 +11,7 @@ Usage on the real chip:
       4608:384:2304:768 4608:512:2304:1152 4352:256:2176:2176
 (each config is s_pad:block_q:block_kv:block_kv_compute; s_pad must be a
 multiple of block_q and block_kv, all multiples of 128). Calibrate the
-session's fori_loop floor first (BASELINE.md round-4 anchors) if absolute
+session's fori_loop floor first (pre-round note, round 4, git history) if absolute
 numbers matter — deltas at the same loop count cancel it.
 """
 
