@@ -786,6 +786,12 @@ class MicroBatcher:
             self.engine.metrics.record_stage_samples(
                 obs.QUEUE_WAIT, queue_waits_ms
             )
+            # and the span table (`host_spans`): a wait, measured from the
+            # items' own stamps, so no `obs.span` object and no annotation
+            obs.record_span(
+                "batcher.queue_wait", sum(queue_waits_ms) / 1e3,
+                count=len(queue_waits_ms),
+            )
             self.engine.metrics.record_pack(
                 padding_waste_pct=plan.padding_waste_pct,
                 slack_ms=slack_ms,

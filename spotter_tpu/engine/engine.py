@@ -12,10 +12,10 @@ The engine is synchronous (one device stream); `MicroBatcher` feeds it from
 async request handlers.
 """
 
+import itertools
 import logging
 import os
 import threading
-import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
@@ -47,11 +47,13 @@ from spotter_tpu.obs.perf import sample_hbm_once
 from spotter_tpu.ops.preprocess import (
     DecodePool,
     PreprocessSpec,
-    batch_images_host,
-    batch_images_uint8,
+    decode_resize_uint8,
     device_preprocess_supported,
     device_rescale_normalize,
+    preprocess_image,
     shortest_edge_size,
+    stack_host,
+    stack_uint8,
 )
 
 logger = logging.getLogger(__name__)
@@ -89,6 +91,30 @@ class BuiltDetector:
     # normalized (Q, proj) float32 embeddings through the model's text
     # tower. None = closed-set family; the engine then rejects qset detects.
     text_encoder: Optional[Callable] = None
+
+
+@dataclass
+class _Batch:
+    """One batch on its way through the engine: stage -> upload -> dispatch
+    -> fetch. Its sequence number names it on every span it causes (the
+    request traces, the span table's annotations in a profiler capture),
+    and `stages` fills with each stage's seconds as that stage's span
+    closes."""
+
+    seq: int
+    n: int
+    bucket: int
+    qset: object = None
+    arrays: tuple = ()  # host arrays, then their device copies
+    outputs: tuple = ()
+    meta: Optional[dict] = None
+    stages: dict = field(default_factory=dict)
+    total: Optional[obs.span] = None  # engine.batch: staging start -> answers
+    device: Optional[obs.span] = None  # the device stage: dispatch -> on host
+
+    @property
+    def tags(self) -> dict:
+        return {"batch": self.seq, "bucket": self.bucket, "n": self.n}
 
 
 def _bitpattern_u32(x):
@@ -218,6 +244,7 @@ class InferenceEngine:
         # decode half stays outside the lock too, so decode keeps its
         # thread-level parallelism.
         self._h2d_lock = threading.Lock()
+        self._batch_seq = itertools.count(1)
         # Compile-provenance thread-local (ISSUE 10): warmup / traffic /
         # oom_downgrade / rebuild tag every compile-ledger entry with WHY
         # the program compiled.
@@ -228,12 +255,15 @@ class InferenceEngine:
         def apply_post(params, pixels, masks, target_sizes):
             args = (pixels, masks) if built.needs_mask else (pixels,)
             out = built.module.apply({"params": params}, *args, **built.apply_kwargs)
-            if built.postprocess == "sigmoid_topk":
-                kk = min(k, out["logits"].shape[1] * out["logits"].shape[2])
-                return sigmoid_topk_postprocess(
-                    out["logits"], out["pred_boxes"], target_sizes, k=kk
-                )
-            return post_fn(out["logits"], out["pred_boxes"], target_sizes)
+            # named scopes are op metadata only: they put the program's
+            # sections on a device trace's op names, and change no program
+            with jax.named_scope("postprocess"):
+                if built.postprocess == "sigmoid_topk":
+                    kk = min(k, out["logits"].shape[1] * out["logits"].shape[2])
+                    return sigmoid_topk_postprocess(
+                        out["logits"], out["pred_boxes"], target_sizes, k=kk
+                    )
+                return post_fn(out["logits"], out["pred_boxes"], target_sizes)
 
         if self.device_preprocess:
             spec = built.preprocess_spec
@@ -241,7 +271,10 @@ class InferenceEngine:
             # uint8 in, rescale/normalize/mask fused into the forward
             # program — the float pixel tensor only ever exists in HBM
             def forward(params, pixels_u8, valid_hw, target_sizes):
-                pixels, masks = device_rescale_normalize(pixels_u8, valid_hw, spec)
+                with jax.named_scope("preprocess"):
+                    pixels, masks = device_rescale_normalize(
+                        pixels_u8, valid_hw, spec
+                    )
                 return apply_post(params, pixels, masks, target_sizes)
 
         else:
@@ -264,18 +297,20 @@ class InferenceEngine:
                     {"params": params}, *args,
                     query_embeds=query_embeds, query_mask=query_mask,
                 )
-                return sigmoid_max_postprocess(
-                    out["logits"], out["pred_boxes"], target_sizes
-                )
+                with jax.named_scope("postprocess"):
+                    return sigmoid_max_postprocess(
+                        out["logits"], out["pred_boxes"], target_sizes
+                    )
 
             if self.device_preprocess:
                 spec_q = built.preprocess_spec
 
                 def forward_q(params, pixels_u8, valid_hw, target_sizes,
                               query_embeds, query_mask):
-                    pixels, masks = device_rescale_normalize(
-                        pixels_u8, valid_hw, spec_q
-                    )
+                    with jax.named_scope("preprocess"):
+                        pixels, masks = device_rescale_normalize(
+                            pixels_u8, valid_hw, spec_q
+                        )
                     return apply_post_q(
                         params, pixels, masks, target_sizes,
                         query_embeds, query_mask,
@@ -651,13 +686,17 @@ class InferenceEngine:
                 jax.ShapeDtypeStruct(a.shape, a.dtype)
                 for a in (first, second, sizes)
             )
-            t_c = time.monotonic()
-            jax.block_until_ready(self._forward(self.params, first, second, sizes))
-            if novel:
-                perf.compiles.record_compile(
-                    key, time.monotonic() - t_c, source
+            # set-up phases (the `setup_phases_s` dict of /metrics): the
+            # bucket's compile or cache load with its first run, then the
+            # FLOPs ledger's second lowering
+            with obs.span(f"setup.warmup.{key}") as compiled:
+                jax.block_until_ready(
+                    self._forward(self.params, first, second, sizes)
                 )
-                perf.flops_for(key, lambda a=absargs: self._flops_of(a))
+            if novel:
+                perf.compiles.record_compile(key, compiled.seconds, source)
+                with obs.span(f"setup.flops.{key}"):
+                    perf.flops_for(key, lambda a=absargs: self._flops_of(a))
 
     def _put(self, arr: np.ndarray):
         """Host array -> device(s), per-shard H2D overlap under a mesh.
@@ -752,9 +791,7 @@ class InferenceEngine:
         pending = None  # (dispatched_item, chunk_images)
         for chunk in chunks:
             try:
-                host = self._stage_host(chunk, canvas_hw, qset)
-                with self._h2d_lock:
-                    dispatched = self._dispatch(self._put_staged(host))
+                dispatched = self._launch(chunk, canvas_hw, qset)
             except Exception as exc:
                 # keep result order: finish the older in-flight chunk first,
                 # then recover (or fail) this one
@@ -813,21 +850,29 @@ class InferenceEngine:
         self, images: list[Image.Image], canvas_hw=None, qset=None
     ) -> list[list[dict]]:
         """Serial stage -> dispatch -> fetch for one chunk (<= max bucket)."""
-        host = self._stage_host(images, canvas_hw, qset)
-        with self._h2d_lock:
-            dispatched = self._dispatch(self._put_staged(host))
-        return self._finish(dispatched)
+        return self._finish(self._launch(images, canvas_hw, qset))
 
-    def _stage(self, images: list[Image.Image], canvas_hw=None, qset=None):
-        """Host staging: decode/preprocess, pad to the bucket, device_put.
+    def _launch(
+        self, images: list[Image.Image], canvas_hw=None, qset=None
+    ) -> _Batch:
+        """Stage one chunk on the host, upload it and dispatch its program.
+        The starvation clock (engine/metrics.py) counts the batch as
+        staging from here to the return of `_dispatch`, and as in flight
+        from there to its fetch in `_finish`."""
+        starvation = self.metrics.starvation
+        starvation.move(staging=+1)
+        try:
+            batch = self._stage_host(images, canvas_hw, qset)
+            self._upload_and_dispatch(batch)
+        except BaseException:
+            starvation.move(staging=-1)
+            raise
+        starvation.move(staging=-1, in_flight=+1)
+        return batch
 
-        Composition of `_stage_host` (decode half, runs outside the H2D
-        lock) and `_put_staged` (upload half) for callers that don't split
-        them.
-        """
-        return self._put_staged(self._stage_host(images, canvas_hw, qset))
-
-    def _stage_host(self, images: list[Image.Image], canvas_hw=None, qset=None):
+    def _stage_host(
+        self, images: list[Image.Image], canvas_hw=None, qset=None
+    ) -> _Batch:
         """Decode/preprocess half of staging: everything before the H2D.
 
         Device-preprocess mode produces uint8 pixels + a (B, 2) valid-region
@@ -836,46 +881,51 @@ class InferenceEngine:
         pool. `canvas_hw` (ragged, ISSUE 9) shrinks the shortest_edge pad
         target; pad rows always fill to whatever canvas the real rows got,
         so one batch is one static shape.
+
+        The `decode` stage is tiled by its children: `engine.preprocess_map`
+        (the decode pool's map, one `engine.preprocess_image` per image
+        inside it, on the pool's threads) and `engine.stack_pad` (the
+        caller's own copies: `np.stack`, then the pad to the bucket).
         """
-        t0 = time.monotonic()
-        faults.sleep_stage(obs.DECODE)  # slow_stage=decode:<ms> injection
         n = len(images)
-        bucket = self.bucket_for(n)
+        batch = _Batch(next(self._batch_seq), n, self.bucket_for(n), qset)
+        traces, tags = obs.batch_traces(), batch.tags
+        batch.total = obs.span("engine.batch", traces, **tags).start()
         spec = self.built.preprocess_spec
         if canvas_hw is not None and spec.mode != "shortest_edge":
             canvas_hw = None  # fixed/pad_square canvases ARE the signal
-        if self.device_preprocess:
-            pixels, valid, sizes = batch_images_uint8(
-                images, spec, pool=self._decode_pool, canvas_hw=canvas_hw
-            )
-            if bucket > n:  # pad batch to the static bucket size
-                pad = bucket - n
-                h, w = pixels.shape[1:3]
-                pixels = np.concatenate(
-                    [pixels, np.zeros((pad, *pixels.shape[1:]), pixels.dtype)]
-                )
-                valid = np.concatenate(
-                    [valid, np.tile(np.asarray([[h, w]], np.int32), (pad, 1))]
-                )
-                sizes = np.concatenate([sizes, np.ones((pad, 2), sizes.dtype)])
-            host_arrays = (pixels, valid, sizes)
-        else:
-            pixels, masks, sizes = batch_images_host(
-                images, spec, pool=self._decode_pool, canvas_hw=canvas_hw
-            )
-            if bucket > n:  # pad batch to the static bucket size
-                pad = bucket - n
-                pixels = np.concatenate(
-                    [pixels, np.zeros((pad, *pixels.shape[1:]), pixels.dtype)]
-                )
-                masks = np.concatenate(
-                    [masks, np.ones((pad, *masks.shape[1:]), masks.dtype)]
-                )
-                sizes = np.concatenate([sizes, np.ones((pad, 2), sizes.dtype)])
-            host_arrays = (pixels, masks, sizes)
-        return host_arrays, n, t0, time.monotonic(), self._perf_meta(
-            images, pixels, n, spec, qset
-        ), qset
+        per_image = decode_resize_uint8 if self.device_preprocess else preprocess_image
+
+        def one(image):
+            with obs.span("engine.preprocess_image", obs.NO_TRACE, annotate=True,
+                          cpu=True, batch=batch.seq):
+                return per_image(image, spec=spec, canvas_hw=canvas_hw)
+
+        # slow_stage=decode:<ms> lands inside (obs.span's fault seam)
+        with obs.span("engine.decode", traces, stage=obs.DECODE,
+                      annotate=True, **tags) as decode:
+            with obs.span("engine.preprocess_map", traces, annotate=True, **tags):
+                done = self._decode_pool.map(one, images)
+            with obs.span("engine.stack_pad", traces, annotate=True, **tags):
+                stack = stack_uint8 if self.device_preprocess else stack_host
+                pixels, second, sizes = stack(done)  # second: valid region, or masks
+                pad = batch.bucket - n  # pad batch to the static bucket size
+                if pad > 0:
+                    if self.device_preprocess:  # a pad row's valid region: the canvas
+                        fill = np.tile(
+                            np.asarray([pixels.shape[1:3]], np.int32), (pad, 1)
+                        )
+                    else:
+                        fill = np.ones((pad, *second.shape[1:]), second.dtype)
+                    pixels = np.concatenate(
+                        [pixels, np.zeros((pad, *pixels.shape[1:]), pixels.dtype)]
+                    )
+                    second = np.concatenate([second, fill])
+                    sizes = np.concatenate([sizes, np.ones((pad, 2), sizes.dtype)])
+                batch.arrays = (pixels, second, sizes)
+        batch.stages[obs.DECODE] = decode.seconds
+        batch.meta = self._perf_meta(images, batch.arrays[0], n, spec, qset)
+        return batch
 
     def _perf_meta(self, images, pixels, n: int, spec, qset=None) -> Optional[dict]:
         """Per-dispatch efficiency accounting inputs (ISSUE 10): the shape
@@ -903,13 +953,34 @@ class InferenceEngine:
             "valid_px": min(valid_px, padded_px),
         }
 
-    def _put_staged(self, host_item):
+    def _upload_and_dispatch(self, batch: _Batch) -> None:
+        """The `h2d` stage, then the dispatch. `_h2d_lock` is held across
+        the puts and the dispatch, so uploads stay ordered while `_finish`
+        (D2H) proceeds concurrently. The stage runs, as it always has, from
+        the end of staging to the end of the puts, so it holds the wait for
+        the lock: `engine.h2d_lock_wait` and `engine.put` tile it, and the
+        dispatch follows it, in no stage."""
+        traces, tags = obs.batch_traces(), batch.tags
+        with obs.span("engine.h2d", traces, stage=obs.H2D, annotate=True,
+                      **tags) as h2d:
+            with obs.span("engine.h2d_lock_wait", traces, annotate=True, **tags):
+                self._h2d_lock.acquire()
+            try:
+                with obs.span("engine.put", traces, annotate=True, **tags):
+                    self._put_staged(batch)
+            except BaseException:
+                self._h2d_lock.release()
+                raise
+        batch.stages[obs.H2D] = h2d.seconds
+        try:
+            self._dispatch(batch)
+        finally:
+            self._h2d_lock.release()
+
+    def _put_staged(self, batch: _Batch) -> None:
         """Upload half of staging: the async `_put`s (per-shard overlap
-        under a mesh) plus the H2D accounting. Callers hold `_h2d_lock`
-        across this + `_dispatch` so uploads stay ordered while `_finish`
-        (D2H) proceeds concurrently."""
-        host_arrays, n, t0, t_decode, meta, qset = host_item
-        faults.sleep_stage(obs.H2D)  # slow_stage=h2d:<ms> injection
+        under a mesh) plus the H2D accounting."""
+        host_arrays, n, qset = batch.arrays, batch.n, batch.qset
         staged = tuple(self._put(a) for a in host_arrays)
         if qset is not None:
             # the query matrix replicates (its leading axis is queries, not
@@ -920,12 +991,14 @@ class InferenceEngine:
             )
         self.metrics.record_h2d_bytes(sum(a.nbytes for a in host_arrays), n)
         self.metrics.set_decode_queue_depth(self._decode_pool.queue_depth())
-        return staged, n, t0, t_decode, time.monotonic(), meta, qset
+        batch.arrays = staged
 
-    def _dispatch(self, staged_item):
+    def _dispatch(self, batch: _Batch) -> None:
         """Async-dispatch the compiled forward; no host blocking (except a
-        novel shape's compile, which the compile ledger times — ISSUE 10)."""
-        staged, n, t0, t_decode, t_pre, meta, qset = staged_item
+        novel shape's compile, which the compile ledger times — ISSUE 10).
+        Opens the batch's `device` stage, which `_finish` closes."""
+        staged, n, meta, qset = batch.arrays, batch.n, batch.meta, batch.qset
+        traces, tags = obs.batch_traces(), batch.tags
         # fault seam: a dead-shard or device-OOM injection raises here with
         # the same status markers the real runtime would embed
         faults.on_engine_dispatch(n, [d.id for d in self.devices()])
@@ -937,17 +1010,23 @@ class InferenceEngine:
             absargs = tuple(
                 jax.ShapeDtypeStruct(a.shape, a.dtype) for a in staged
             )
-        t_c = time.monotonic()
-        if qset is not None:
-            outputs = self._forward_q(self.params, *staged)
-        else:
-            outputs = self._forward(self.params, *staged)
-        t_disp = time.monotonic()
+        with obs.span("engine.dispatch", traces, annotate=True, **tags) as call:
+            if qset is not None:
+                outputs = self._forward_q(self.params, *staged)
+            else:
+                outputs = self._forward(self.params, *staged)
+        batch.device = obs.span(
+            "engine.device", traces, stage=obs.DEVICE, **tags
+        ).start()
+        # the program holds its inputs for as long as it needs them; the
+        # batch must not, or a staged batch lives on the device until its
+        # answers are fetched
+        batch.arrays = ()
         if novel:
             # first call of a shape blocks on trace+compile; its wall time
             # IS the serving stall a recompile storm multiplies
             perf.compiles.record_compile(
-                meta["shape"], t_disp - t_c, self._current_source()
+                meta["shape"], call.seconds, self._current_source()
             )
         if meta is not None:
             fwd = self._forward_q if qset is not None else self._forward
@@ -958,54 +1037,56 @@ class InferenceEngine:
         # overlapping the next chunk's staging instead of its fetch
         for arr in outputs:
             arr.copy_to_host_async()
-        return outputs, n, t0, t_decode, t_pre, t_disp, meta, qset
+        batch.outputs = outputs
 
-    def _finish(self, dispatched_item) -> list[list[dict]]:
-        """Block on the fetch, threshold on host, record metrics."""
-        outputs, n, t0, t_decode, t_pre, t_disp, meta, qset = dispatched_item
-        faults.sleep_stage(obs.DEVICE)  # slow_stage=device:<ms> injection
-        scores, labels, boxes = jax.device_get(outputs)
-        t_dev = time.monotonic()
-        faults.sleep_stage(obs.POSTPROCESS)
-        # open-vocab dispatches label against THEIR vocabulary (padded query
-        # slots carry NEG_INF logits, so the argmax never lands on one)
-        id2label = qset.id2label if qset is not None else self.built.id2label
-        out = [
-            to_detections(
-                scores[j], labels[j], boxes[j], id2label, self.threshold
-            )
-            for j in range(n)
-        ]
-        # output-integrity chaos seam (ISSUE 17): sdc=<pct> perturbs this
-        # share of answers into plausible garbage — the hook is identity
-        # (one None check) when no plan is active
-        out = [
-            faults.corrupt_detections(dets, self.metrics.replica_id)
-            for dets in out
-        ]
-        t_post = time.monotonic()
-        # Stage vocabulary is obs.STAGES everywhere (ISSUE 7 satellite —
-        # /metrics, bench JSON, and trace spans previously disagreed on
-        # "preprocess"/"staging" vs the decode+h2d split from PR 3):
-        # decode = decode-pool host work, h2d = device_put enqueue (the two
-        # knobs the ingest pipeline tunes), device = dispatch ->
-        # data-on-host (under pipelining the next chunk's host staging runs
-        # inside this span, but so does this chunk's compute — measuring
-        # from t_pre would bill the neighbor's staging as device time).
-        stage_windows = [
-            (obs.DECODE, t0, t_decode),
-            (obs.H2D, t_decode, t_pre),
-            (obs.DEVICE, t_disp, t_dev),
-            (obs.POSTPROCESS, t_dev, t_post),
-        ]
-        # fan the batch's stage windows out to every traced request in it
-        obs.record_engine_spans(stage_windows)
+    def _finish(self, batch: _Batch) -> list[list[dict]]:
+        """Block on the fetch, threshold on host, record metrics.
+
+        Stage vocabulary is obs.STAGES everywhere (ISSUE 7 satellite):
+        decode = decode-pool host work and the caller's copies, h2d =
+        device_put enqueue (lock wait included), device = dispatch ->
+        data-on-host (under pipelining the next chunk's host staging runs
+        inside this span, but so does this chunk's compute — measuring from
+        the end of the puts would bill the neighbor's staging as device
+        time), postprocess = threshold and boxes. Each is a span that was
+        open while the stage ran; `batch.stages` holds their seconds."""
+        n, meta, qset = batch.n, batch.meta, batch.qset
+        traces, tags = obs.batch_traces(), batch.tags
+        try:
+            with obs.span("engine.device_wait", traces, annotate=True, **tags):
+                faults.sleep_stage(obs.DEVICE)  # slow_stage=device:<ms>
+                scores, labels, boxes = jax.device_get(batch.outputs)
+        finally:
+            batch.device.stop()
+            self.metrics.starvation.move(in_flight=-1)
+        batch.stages[obs.DEVICE] = batch.device.seconds
+        with obs.span("engine.postprocess", traces, stage=obs.POSTPROCESS,
+                      annotate=True, **tags) as post:
+            # open-vocab dispatches label against THEIR vocabulary (padded
+            # query slots carry NEG_INF logits, so the argmax never lands
+            # on one)
+            id2label = qset.id2label if qset is not None else self.built.id2label
+            out = [
+                to_detections(
+                    scores[j], labels[j], boxes[j], id2label, self.threshold
+                )
+                for j in range(n)
+            ]
+            # output-integrity chaos seam (ISSUE 17): sdc=<pct> perturbs
+            # this share of answers into plausible garbage — the hook is
+            # identity (one None check) when no plan is active
+            out = [
+                faults.corrupt_detections(dets, self.metrics.replica_id)
+                for dets in out
+            ]
+        batch.stages[obs.POSTPROCESS] = post.seconds
+        batch.total.stop()
         self.metrics.record_batch(
             n,
-            t_post - t0,
-            stages={name: t_end - t_start
-                    for name, t_start, t_end in stage_windows},
+            batch.total.seconds,
+            stages=batch.stages,
             trace_id=obs.batch_trace_id(),
+            bucket=batch.bucket,
         )
         if meta is not None:
             # device-efficiency ledger (ISSUE 10): this dispatch's device
@@ -1014,7 +1095,7 @@ class InferenceEngine:
             # top-K expensive-dispatch table joinable against the flight
             # recorder (/debug/perf -> /debug/traces).
             self.metrics.perf.record_dispatch(
-                device_s=t_dev - t_disp,
+                device_s=batch.device.seconds,
                 batch=n,
                 padded_px=meta.get("padded_px"),
                 valid_px=meta.get("valid_px"),

@@ -11,6 +11,7 @@ import threading
 import time
 from collections import deque
 
+from spotter_tpu.obs import trace as obs_trace
 from spotter_tpu.obs.perf import PerfLedger
 
 # Cumulative-histogram bucket bounds (ms) for batch latency — the
@@ -123,6 +124,73 @@ class ControlPlaneMetrics:
         }
 
 
+# Set-up phases are spans like any other (`obs.span("setup.weights_load")`):
+# the snapshot shows the rows under this prefix as one dict of seconds.
+SETUP_SPAN_PREFIX = "setup."
+
+
+def setup_phases_s(host_spans: dict | None = None) -> dict:
+    """phase -> seconds, from the span table: `imports` (process start to
+    bring-up), `weights_load`, `engine_place`, `warmup` (all of it; inside
+    it, per bucket, `warmup.<shape>`: compile or cache load with the first
+    run, and `flops.<shape>`: the FLOPs ledger's second lowering), `attest`,
+    `ready_probe`."""
+    if host_spans is None:
+        host_spans = obs_trace.host_spans_snapshot()
+    return {
+        name[len(SETUP_SPAN_PREFIX):]: round(row["wall_ms"] / 1e3, 3)
+        for name, row in host_spans.items()
+        if name.startswith(SETUP_SPAN_PREFIX)
+    }
+
+
+class StarvationClock:
+    """Why the chip had nothing to do (ISSUE 26): seconds in which the
+    engine had no program dispatched and not yet fetched, split by what the
+    host was doing meanwhile. `staging`: at least one batch between the
+    start of `_stage_host` and the return of `_dispatch` (the host was
+    preparing the chip's next work). `upstream`: nothing staging, and at
+    least one image inside `Detector._process_single_image` (in fetch, PIL
+    decode or the batcher's queue, or its reply being drawn and encoded).
+    With nothing anywhere the house is empty and no clock runs. Both are
+    lower bounds of the device's idle time: a result reaches the host a D2H
+    after the device ends. Counted by the engine and the detector at their
+    own boundaries, so the numbers exist in every run, traced or not."""
+
+    def __init__(self, clock=time.monotonic) -> None:
+        self._clock = clock
+        self._lock = threading.Lock()
+        self._in_flight = 0
+        self._staging = 0
+        self._upstream = 0
+        self._since = clock()
+        self._staging_s = 0.0
+        self._upstream_s = 0.0
+
+    def _settle(self) -> None:
+        """Book the time since the last change (caller holds the lock)."""
+        now = self._clock()
+        if self._in_flight == 0:
+            if self._staging > 0:
+                self._staging_s += now - self._since
+            elif self._upstream > 0:
+                self._upstream_s += now - self._since
+        self._since = now
+
+    def move(self, in_flight: int = 0, staging: int = 0, upstream: int = 0) -> None:
+        with self._lock:
+            self._settle()
+            self._in_flight += in_flight
+            self._staging += staging
+            self._upstream += upstream
+
+    def totals(self) -> tuple[float, float]:
+        """(starved while staging, starved on upstream), seconds so far."""
+        with self._lock:
+            self._settle()
+            return self._staging_s, self._upstream_s
+
+
 class Metrics:
     def __init__(self, window: int = 2048) -> None:
         self._lock = threading.Lock()
@@ -137,6 +205,11 @@ class Metrics:
         self._errors_total = 0
         self._batches_total = 0
         self._batch_sizes: deque[int] = deque(maxlen=window)
+        # per-bucket batch counts and the slots they ran (ISSUE 26): images
+        # over slots is the fill of the ladder, from the counters alone
+        self._bucket_batches: dict[int, int] = {}
+        self._slots_total = 0
+        self.starvation = StarvationClock()
         self._started = time.monotonic()
         # (timestamp, batch_size) ring for rate computation — snapshot() reads
         # it without mutating shared state, so concurrent scrapers don't
@@ -259,16 +332,23 @@ class Metrics:
         latency_s: float,
         stages: dict[str, float] | None = None,
         trace_id: str | None = None,
+        bucket: int | None = None,
     ) -> None:
         """`stages`: optional per-stage seconds keyed by the obs.STAGES
         vocabulary (decode/h2d/device/postprocess) — the breakdown
         SURVEY.md §5.1 calls for. `trace_id` (when the batch carried a
         traced request) becomes the exemplar on the latency-histogram
-        bucket this batch landed in."""
+        bucket this batch landed in. `bucket`: the ladder rung the batch
+        was padded to (the slots the program ran)."""
         latency_ms = latency_s * 1000.0
         with self._lock:
             self._images_total += batch_size
             self._batches_total += 1
+            if bucket is not None:
+                self._bucket_batches[bucket] = (
+                    self._bucket_batches.get(bucket, 0) + 1
+                )
+                self._slots_total += bucket
             self._batch_sizes.append(batch_size)
             self._latencies_ms.append(latency_ms)
             self._latency_sum_ms += latency_ms
@@ -562,6 +642,8 @@ class Metrics:
         # outside the metrics lock: the perf ledger locks itself, and
         # nesting the two here would be the only place the order matters
         perf_snap = self.perf.snapshot()
+        host_spans = obs_trace.host_spans_snapshot()
+        starved_staging_s, starved_upstream_s = self.starvation.totals()
         with self._lock:
             lats = sorted(self._latencies_ms)
             now = time.monotonic()
@@ -653,6 +735,16 @@ class Metrics:
                     "weights_digest": self._weights_digest,
                 },
                 "stage_ms_histogram": stage_hists,
+                # every `obs.span` of the process, by name (ISSUE 26): what
+                # the stages above are made of, and the detector's share
+                "host_spans": host_spans,
+                "setup_phases_s": setup_phases_s(host_spans),
+                "bucket_batches_total": {
+                    str(b): n for b, n in sorted(self._bucket_batches.items())
+                },
+                "slots_total": self._slots_total,
+                "starved_staging_s_total": round(starved_staging_s, 6),
+                "starved_upstream_s_total": round(starved_upstream_s, 6),
                 "padding_waste_pct": waste,
                 "slack_at_dispatch_ms": slack_summary,
                 "ragged_packs_total": self._ragged_packs_total,

@@ -2,6 +2,10 @@
 
 Three mechanisms, all opt-in and zero-cost when off:
 
+- `install_span_annotator()`: hands `jax.profiler.TraceAnnotation` to
+  `obs.span`, so the program's host spans (engine stages, the decode pool's
+  per-image work, the detector's decode and draw) appear in any capture
+  that traces the host.
 - `maybe_start_profiler_server()`: starts jax.profiler's gRPC server when
   `SPOTTER_TPU_PROFILER_PORT` is set, so TensorBoard / xprof can connect and
   capture live TPU traces from a serving pod.
@@ -41,6 +45,17 @@ def maybe_start_profiler_server() -> int | None:
             _server_started = True
             logger.info("jax profiler server listening on :%s", port)
     return int(port)
+
+
+def install_span_annotator() -> None:
+    """Make every annotated `obs.span` a `jax.profiler.TraceAnnotation`
+    while it runs, so that a capture with the host tracer at level 1 or
+    more holds the program's own spans on the device trace's clock. The
+    serving process calls this at start-up; `obs/trace.py` itself stays
+    free of jax."""
+    from spotter_tpu import obs
+
+    obs.set_annotator(jax.profiler.TraceAnnotation)
 
 
 _capture_lock = threading.Lock()

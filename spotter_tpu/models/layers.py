@@ -123,6 +123,7 @@ def flash_attention_enabled() -> bool:
     return _FLASH_ATTN_ENABLED and jax.default_backend() == "tpu"
 
 
+@jax.named_scope("flash_attention")  # the pad and transposes around the kernel too
 def flash_self_attention(q, k, v):
     """(B, S, H, hd) pre-scaled q/k/v -> (B, S, H, hd) via a Pallas TPU
     attention kernel (splash on bf16 tensors / flash on fp32 under the

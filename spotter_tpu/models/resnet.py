@@ -184,22 +184,23 @@ class ResNetBackbone(nn.Module):
         cfg = self.config
         act = cfg.hidden_act
         x = pixel_values.astype(self.dtype)
-        if cfg.style == "v1":
-            # Classic stem: single 7x7 s2 conv, then 3x3 s2 max pool.
-            x = ConvNorm(cfg.embedding_size, 7, 2, activation=act, dtype=self.dtype, name="stem0")(x)
-        elif S2D_STEM and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
-            # Deep stem, first conv via space-to-depth (exact rearrangement).
-            x = DeepStemS2DConv(
-                cfg.embedding_size // 2, activation=act, dtype=self.dtype, name="stem0"
-            )(x)
-            x = ConvNorm(cfg.embedding_size // 2, 3, 1, activation=act, dtype=self.dtype, name="stem1")(x)
-            x = ConvNorm(cfg.embedding_size, 3, 1, activation=act, dtype=self.dtype, name="stem2")(x)
-        else:
-            # Deep stem: 3x3 s2 -> 3x3 -> 3x3.
-            x = ConvNorm(cfg.embedding_size // 2, 3, 2, activation=act, dtype=self.dtype, name="stem0")(x)
-            x = ConvNorm(cfg.embedding_size // 2, 3, 1, activation=act, dtype=self.dtype, name="stem1")(x)
-            x = ConvNorm(cfg.embedding_size, 3, 1, activation=act, dtype=self.dtype, name="stem2")(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        with jax.named_scope("stem"):  # op metadata only
+            if cfg.style == "v1":
+                # Classic stem: single 7x7 s2 conv, then 3x3 s2 max pool.
+                x = ConvNorm(cfg.embedding_size, 7, 2, activation=act, dtype=self.dtype, name="stem0")(x)
+            elif S2D_STEM and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
+                # Deep stem, first conv via space-to-depth (exact rearrangement).
+                x = DeepStemS2DConv(
+                    cfg.embedding_size // 2, activation=act, dtype=self.dtype, name="stem0"
+                )(x)
+                x = ConvNorm(cfg.embedding_size // 2, 3, 1, activation=act, dtype=self.dtype, name="stem1")(x)
+                x = ConvNorm(cfg.embedding_size, 3, 1, activation=act, dtype=self.dtype, name="stem2")(x)
+            else:
+                # Deep stem: 3x3 s2 -> 3x3 -> 3x3.
+                x = ConvNorm(cfg.embedding_size // 2, 3, 2, activation=act, dtype=self.dtype, name="stem0")(x)
+                x = ConvNorm(cfg.embedding_size // 2, 3, 1, activation=act, dtype=self.dtype, name="stem1")(x)
+                x = ConvNorm(cfg.embedding_size, 3, 1, activation=act, dtype=self.dtype, name="stem2")(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
 
         hidden_states = [x]
         in_ch = cfg.embedding_size

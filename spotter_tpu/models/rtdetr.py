@@ -90,16 +90,18 @@ class EncoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, pos: Optional[jnp.ndarray]) -> jnp.ndarray:
-        attn_out = MultiHeadAttention(
-            self.embed_dim, self.num_heads, dtype=self.dtype, name="self_attn"
-        )(x, position_embeddings=pos)
-        x = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype, name="self_attn_layer_norm")(
-            x + attn_out
-        )
-        y = nn.Dense(self.ffn_dim, dtype=self.dtype, name="fc1")(x)
-        y = get_activation(self.activation)(y)
-        y = nn.Dense(self.embed_dim, dtype=self.dtype, name="fc2")(y)
-        return nn.LayerNorm(epsilon=self.eps, dtype=self.dtype, name="final_layer_norm")(x + y)
+        with jax.named_scope("attention"):
+            attn_out = MultiHeadAttention(
+                self.embed_dim, self.num_heads, dtype=self.dtype, name="self_attn"
+            )(x, position_embeddings=pos)
+            x = nn.LayerNorm(epsilon=self.eps, dtype=self.dtype, name="self_attn_layer_norm")(
+                x + attn_out
+            )
+        with jax.named_scope("mlp"):
+            y = nn.Dense(self.ffn_dim, dtype=self.dtype, name="fc1")(x)
+            y = get_activation(self.activation)(y)
+            y = nn.Dense(self.embed_dim, dtype=self.dtype, name="fc2")(y)
+            return nn.LayerNorm(epsilon=self.eps, dtype=self.dtype, name="final_layer_norm")(x + y)
 
 
 # RepVGG re-parameterization at trace time (the classic inference-time
@@ -296,29 +298,32 @@ class DecoderLayer(nn.Module):
     ) -> jnp.ndarray:
         cfg = self.config
         eps = cfg.layer_norm_eps
-        attn_out = MultiHeadAttention(
-            cfg.d_model, cfg.decoder_attention_heads, dtype=self.dtype, name="self_attn"
-        )(hidden_states, position_embeddings=position_embeddings,
-          attention_mask=self_attention_mask)
-        h = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="self_attn_layer_norm")(
-            hidden_states + attn_out
-        )
-        cross = DeformableAttention(
-            cfg.d_model,
-            cfg.decoder_attention_heads,
-            cfg.num_feature_levels,
-            cfg.decoder_n_points,
-            offset_scale=cfg.decoder_offset_scale,
-            method=cfg.decoder_method,
-            dtype=self.dtype,
-            presorted=self.presorted,
-            name="encoder_attn",
-        )(h, position_embeddings, encoder_hidden_states, reference_points, spatial_shapes)
-        h = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="encoder_attn_layer_norm")(h + cross)
-        y = nn.Dense(cfg.decoder_ffn_dim, dtype=self.dtype, name="fc1")(h)
-        y = get_activation(cfg.decoder_activation_function)(y)
-        y = nn.Dense(cfg.d_model, dtype=self.dtype, name="fc2")(y)
-        return nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="final_layer_norm")(h + y)
+        with jax.named_scope("attention"):
+            attn_out = MultiHeadAttention(
+                cfg.d_model, cfg.decoder_attention_heads, dtype=self.dtype, name="self_attn"
+            )(hidden_states, position_embeddings=position_embeddings,
+              attention_mask=self_attention_mask)
+            h = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="self_attn_layer_norm")(
+                hidden_states + attn_out
+            )
+        with jax.named_scope("cross_attention"):
+            cross = DeformableAttention(
+                cfg.d_model,
+                cfg.decoder_attention_heads,
+                cfg.num_feature_levels,
+                cfg.decoder_n_points,
+                offset_scale=cfg.decoder_offset_scale,
+                method=cfg.decoder_method,
+                dtype=self.dtype,
+                presorted=self.presorted,
+                name="encoder_attn",
+            )(h, position_embeddings, encoder_hidden_states, reference_points, spatial_shapes)
+            h = nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="encoder_attn_layer_norm")(h + cross)
+        with jax.named_scope("mlp"):
+            y = nn.Dense(cfg.decoder_ffn_dim, dtype=self.dtype, name="fc1")(h)
+            y = get_activation(cfg.decoder_activation_function)(y)
+            y = nn.Dense(cfg.d_model, dtype=self.dtype, name="fc2")(y)
+            return nn.LayerNorm(epsilon=eps, dtype=self.dtype, name="final_layer_norm")(h + y)
 
 
 class RTDetrDetector(nn.Module):
@@ -352,183 +357,186 @@ class RTDetrDetector(nn.Module):
         )(pixel_values)
         feats = [f.astype(self.dtype) for f in feats]
 
-        proj = [
-            ConvNorm(
-                cfg.encoder_hidden_dim, 1, 1, activation=None, eps=cfg.batch_norm_eps,
-                dtype=self.dtype, name=f"enc_proj{i}",
-            )(f)
-            for i, f in enumerate(feats)
-        ]
-
-        # --- AIFI: transformer encoder on selected (stride-32) levels ---
-        for i, enc_ind in enumerate(cfg.encode_proj_layers):
-            b, h, w, c = proj[enc_ind].shape
-            src = proj[enc_ind].reshape(b, h * w, c)
-            pos = jnp.asarray(
-                sincos_2d_position_embedding(
-                    w, h, cfg.encoder_hidden_dim, cfg.positional_encoding_temperature
-                ),
-                self.dtype,
-            )
-            for j in range(cfg.encoder_layers):
-                src = EncoderLayer(
-                    cfg.encoder_hidden_dim,
-                    cfg.encoder_attention_heads,
-                    cfg.encoder_ffn_dim,
-                    cfg.encoder_activation_function,
-                    cfg.layer_norm_eps,
-                    self.dtype,
-                    name=f"aifi{i}_layer{j}",
-                )(src, pos)
-            proj[enc_ind] = src.reshape(b, h, w, c)
-
-        # --- top-down FPN ---
-        hidden_channels = int(cfg.encoder_hidden_dim * cfg.hidden_expansion)
-        num_stages = len(cfg.encoder_in_channels) - 1
-        fpn = [proj[-1]]
-        for idx in range(num_stages):
-            backbone_fm = proj[num_stages - idx - 1]
-            top = ConvNorm(
-                cfg.encoder_hidden_dim, 1, 1, activation=cfg.activation_function,
-                eps=cfg.batch_norm_eps, dtype=self.dtype, name=f"lateral_conv{idx}",
-            )(fpn[-1])
-            fpn[-1] = top
-            up = jnp.repeat(jnp.repeat(top, 2, axis=1), 2, axis=2)  # 2x nearest
-            fused = jnp.concatenate([up, backbone_fm], axis=-1)
-            fpn.append(
-                CSPRepLayer(
-                    cfg.encoder_hidden_dim, hidden_channels, cfg.csp_num_blocks,
-                    cfg.activation_function, cfg.batch_norm_eps, self.dtype,
-                    name=f"fpn_block{idx}",
-                )(fused)
-            )
-        fpn = fpn[::-1]
-
-        # --- bottom-up PAN ---
-        pan = [fpn[0]]
-        for idx in range(num_stages):
-            down = ConvNorm(
-                cfg.encoder_hidden_dim, 3, 2, activation=cfg.activation_function,
-                eps=cfg.batch_norm_eps, dtype=self.dtype, name=f"downsample_conv{idx}",
-            )(pan[-1])
-            fused = jnp.concatenate([down, fpn[idx + 1]], axis=-1)
-            pan.append(
-                CSPRepLayer(
-                    cfg.encoder_hidden_dim, hidden_channels, cfg.csp_num_blocks,
-                    cfg.activation_function, cfg.batch_norm_eps, self.dtype,
-                    name=f"pan_block{idx}",
-                )(fused)
-            )
-
-        # --- decoder input projection + flatten ---
-        sources = [
-            ConvNorm(
-                cfg.d_model, 1, 1, activation=None, eps=cfg.batch_norm_eps,
-                dtype=self.dtype, name=f"dec_proj{i}",
-            )(p)
-            for i, p in enumerate(pan)
-        ]
-        for i in range(len(sources), cfg.num_feature_levels):
-            sources.append(
+        with jax.named_scope("encoder"):
+            proj = [
                 ConvNorm(
-                    cfg.d_model, 3, 2, padding=1, activation=None, eps=cfg.batch_norm_eps,
+                    cfg.encoder_hidden_dim, 1, 1, activation=None, eps=cfg.batch_norm_eps,
+                    dtype=self.dtype, name=f"enc_proj{i}",
+                )(f)
+                for i, f in enumerate(feats)
+            ]
+
+            # --- AIFI: transformer encoder on selected (stride-32) levels ---
+            for i, enc_ind in enumerate(cfg.encode_proj_layers):
+                b, h, w, c = proj[enc_ind].shape
+                src = proj[enc_ind].reshape(b, h * w, c)
+                pos = jnp.asarray(
+                    sincos_2d_position_embedding(
+                        w, h, cfg.encoder_hidden_dim, cfg.positional_encoding_temperature
+                    ),
+                    self.dtype,
+                )
+                for j in range(cfg.encoder_layers):
+                    src = EncoderLayer(
+                        cfg.encoder_hidden_dim,
+                        cfg.encoder_attention_heads,
+                        cfg.encoder_ffn_dim,
+                        cfg.encoder_activation_function,
+                        cfg.layer_norm_eps,
+                        self.dtype,
+                        name=f"aifi{i}_layer{j}",
+                    )(src, pos)
+                proj[enc_ind] = src.reshape(b, h, w, c)
+
+            # --- top-down FPN ---
+            hidden_channels = int(cfg.encoder_hidden_dim * cfg.hidden_expansion)
+            num_stages = len(cfg.encoder_in_channels) - 1
+            fpn = [proj[-1]]
+            for idx in range(num_stages):
+                backbone_fm = proj[num_stages - idx - 1]
+                top = ConvNorm(
+                    cfg.encoder_hidden_dim, 1, 1, activation=cfg.activation_function,
+                    eps=cfg.batch_norm_eps, dtype=self.dtype, name=f"lateral_conv{idx}",
+                )(fpn[-1])
+                fpn[-1] = top
+                up = jnp.repeat(jnp.repeat(top, 2, axis=1), 2, axis=2)  # 2x nearest
+                fused = jnp.concatenate([up, backbone_fm], axis=-1)
+                fpn.append(
+                    CSPRepLayer(
+                        cfg.encoder_hidden_dim, hidden_channels, cfg.csp_num_blocks,
+                        cfg.activation_function, cfg.batch_norm_eps, self.dtype,
+                        name=f"fpn_block{idx}",
+                    )(fused)
+                )
+            fpn = fpn[::-1]
+
+            # --- bottom-up PAN ---
+            pan = [fpn[0]]
+            for idx in range(num_stages):
+                down = ConvNorm(
+                    cfg.encoder_hidden_dim, 3, 2, activation=cfg.activation_function,
+                    eps=cfg.batch_norm_eps, dtype=self.dtype, name=f"downsample_conv{idx}",
+                )(pan[-1])
+                fused = jnp.concatenate([down, fpn[idx + 1]], axis=-1)
+                pan.append(
+                    CSPRepLayer(
+                        cfg.encoder_hidden_dim, hidden_channels, cfg.csp_num_blocks,
+                        cfg.activation_function, cfg.batch_norm_eps, self.dtype,
+                        name=f"pan_block{idx}",
+                    )(fused)
+                )
+
+            # --- decoder input projection + flatten ---
+            sources = [
+                ConvNorm(
+                    cfg.d_model, 1, 1, activation=None, eps=cfg.batch_norm_eps,
                     dtype=self.dtype, name=f"dec_proj{i}",
-                )(sources[-1])
+                )(p)
+                for i, p in enumerate(pan)
+            ]
+            for i in range(len(sources), cfg.num_feature_levels):
+                sources.append(
+                    ConvNorm(
+                        cfg.d_model, 3, 2, padding=1, activation=None, eps=cfg.batch_norm_eps,
+                        dtype=self.dtype, name=f"dec_proj{i}",
+                    )(sources[-1])
+                )
+
+            spatial_shapes = tuple((s.shape[1], s.shape[2]) for s in sources)
+            b = sources[0].shape[0]
+            source_flatten = jnp.concatenate(
+                [s.reshape(b, -1, cfg.d_model) for s in sources], axis=1
             )
 
-        spatial_shapes = tuple((s.shape[1], s.shape[2]) for s in sources)
-        b = sources[0].shape[0]
-        source_flatten = jnp.concatenate(
-            [s.reshape(b, -1, cfg.d_model) for s in sources], axis=1
-        )
+        with jax.named_scope("decoder"):
+            # --- encoder head: anchor scoring + top-k query selection ---
+            anchors_np, valid_np = generate_anchors(spatial_shapes, cfg.anchor_grid_size)
+            anchors = jnp.asarray(anchors_np, self.dtype)
+            valid_mask = jnp.asarray(valid_np, self.dtype)
 
-        # --- encoder head: anchor scoring + top-k query selection ---
-        anchors_np, valid_np = generate_anchors(spatial_shapes, cfg.anchor_grid_size)
-        anchors = jnp.asarray(anchors_np, self.dtype)
-        valid_mask = jnp.asarray(valid_np, self.dtype)
+            memory = valid_mask * source_flatten
+            output_memory = nn.Dense(cfg.d_model, dtype=self.dtype, name="enc_output_dense")(memory)
+            output_memory = nn.LayerNorm(
+                epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="enc_output_norm"
+            )(output_memory)
 
-        memory = valid_mask * source_flatten
-        output_memory = nn.Dense(cfg.d_model, dtype=self.dtype, name="enc_output_dense")(memory)
-        output_memory = nn.LayerNorm(
-            epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="enc_output_norm"
-        )(output_memory)
-
-        enc_class = nn.Dense(cfg.num_labels, dtype=self.dtype, name="enc_score_head")(
-            output_memory
-        )
-        enc_coord_logits = (
-            MLPHead(cfg.d_model, 4, 3, dtype=self.dtype, name="enc_bbox_head")(output_memory)
-            + anchors
-        )
-
-        # ops/topk.py: lax.top_k by default; SPOTTER_TPU_TOPK=bisect swaps in
-        # the sort-free radix path (identical result, for wider-S hardware)
-        _, topk_ind = fast_top_k(enc_class.max(-1), cfg.num_queries)
-        gather = lambda arr: jnp.take_along_axis(arr, topk_ind[..., None], axis=1)
-        reference_logits = gather(enc_coord_logits)
-        enc_topk_logits = gather(enc_class)
-        enc_topk_bboxes = nn.sigmoid(reference_logits.astype(jnp.float32))
-
-        if cfg.learn_initial_query:
-            target = self.param(
-                "query_embed", nn.initializers.normal(1.0), (cfg.num_queries, cfg.d_model)
+            enc_class = nn.Dense(cfg.num_labels, dtype=self.dtype, name="enc_score_head")(
+                output_memory
             )
-            target = jnp.broadcast_to(target, (b, cfg.num_queries, cfg.d_model)).astype(self.dtype)
-        else:
-            target = jax.lax.stop_gradient(gather(output_memory))
-
-        reference_logits = jax.lax.stop_gradient(reference_logits)
-
-        # Denoising groups (training) enter here as extra queries.
-        if decoder_input_queries is not None:
-            target = jnp.concatenate([decoder_input_queries, target], axis=1)
-            reference_logits = jnp.concatenate(
-                [decoder_input_ref_logits, reference_logits], axis=1
+            enc_coord_logits = (
+                MLPHead(cfg.d_model, 4, 3, dtype=self.dtype, name="enc_bbox_head")(output_memory)
+                + anchors
             )
 
-        # --- decoder with iterative refinement ---
-        # Box-refinement arithmetic stays fp32 even under bf16 compute: the
-        # sigmoid/inverse-sigmoid iteration across decoder layers would
-        # otherwise accumulate bf16 rounding into multi-pixel box drift
-        # (the heavy matmuls in DecoderLayer/MLPHead still run self.dtype).
-        ref = nn.sigmoid(reference_logits.astype(jnp.float32))
-        h = target
-        # Model-level locality presort (ops/msda.py presort_wanted): the six
-        # decoder layers share one spatial ordering of the queries, so sort
-        # ONCE here by the initial reference centers (layer sampling points
-        # cluster around them; later refinement moves boxes only slightly)
-        # instead of paying argsort + two q-row permutes inside every
-        # sampling op. Exact: queries are permutation-equivariant through
-        # full self-attention, and outputs are un-permuted below. Skipped
-        # when a self-attention mask is present (denoising training) —
-        # ordering would have to permute the mask too; the in-op sort
-        # handles that case unchanged.
-        presort = presort_wanted() and self_attention_mask is None
-        if presort:
-            sort_q, unsort_q = locality_presort(ref[..., :2])
-            h, ref = sort_q(h), sort_q(ref)
-        query_pos_head = MLPHead(
-            2 * cfg.d_model, cfg.d_model, 2, dtype=self.dtype, name="query_pos_head"
-        )
-        aux_logits, aux_boxes = [], []
-        for i in range(cfg.decoder_layers):
-            pos = query_pos_head(ref.astype(self.dtype))
-            h = DecoderLayer(
-                cfg, dtype=self.dtype, presorted=presort, name=f"decoder_layer{i}"
-            )(
-                h, pos, source_flatten, ref.astype(self.dtype), spatial_shapes,
-                self_attention_mask,
-            )
-            box_delta = MLPHead(cfg.d_model, 4, 3, dtype=self.dtype, name=f"bbox_head{i}")(h)
-            new_ref = nn.sigmoid(box_delta.astype(jnp.float32) + inverse_sigmoid(ref))
-            logits_i = nn.Dense(cfg.num_labels, dtype=self.dtype, name=f"class_head{i}")(h)
-            aux_logits.append(logits_i.astype(jnp.float32))
-            aux_boxes.append(new_ref)
-            ref = jax.lax.stop_gradient(new_ref)
+            # ops/topk.py: lax.top_k by default; SPOTTER_TPU_TOPK=bisect swaps in
+            # the sort-free radix path (identical result, for wider-S hardware)
+            _, topk_ind = fast_top_k(enc_class.max(-1), cfg.num_queries)
+            gather = lambda arr: jnp.take_along_axis(arr, topk_ind[..., None], axis=1)
+            reference_logits = gather(enc_coord_logits)
+            enc_topk_logits = gather(enc_class)
+            enc_topk_bboxes = nn.sigmoid(reference_logits.astype(jnp.float32))
 
-        if presort:
-            aux_logits = [unsort_q(a) for a in aux_logits]
-            aux_boxes = [unsort_q(a) for a in aux_boxes]
+            if cfg.learn_initial_query:
+                target = self.param(
+                    "query_embed", nn.initializers.normal(1.0), (cfg.num_queries, cfg.d_model)
+                )
+                target = jnp.broadcast_to(target, (b, cfg.num_queries, cfg.d_model)).astype(self.dtype)
+            else:
+                target = jax.lax.stop_gradient(gather(output_memory))
+
+            reference_logits = jax.lax.stop_gradient(reference_logits)
+
+            # Denoising groups (training) enter here as extra queries.
+            if decoder_input_queries is not None:
+                target = jnp.concatenate([decoder_input_queries, target], axis=1)
+                reference_logits = jnp.concatenate(
+                    [decoder_input_ref_logits, reference_logits], axis=1
+                )
+
+            # --- decoder with iterative refinement ---
+            # Box-refinement arithmetic stays fp32 even under bf16 compute: the
+            # sigmoid/inverse-sigmoid iteration across decoder layers would
+            # otherwise accumulate bf16 rounding into multi-pixel box drift
+            # (the heavy matmuls in DecoderLayer/MLPHead still run self.dtype).
+            ref = nn.sigmoid(reference_logits.astype(jnp.float32))
+            h = target
+            # Model-level locality presort (ops/msda.py presort_wanted): the six
+            # decoder layers share one spatial ordering of the queries, so sort
+            # ONCE here by the initial reference centers (layer sampling points
+            # cluster around them; later refinement moves boxes only slightly)
+            # instead of paying argsort + two q-row permutes inside every
+            # sampling op. Exact: queries are permutation-equivariant through
+            # full self-attention, and outputs are un-permuted below. Skipped
+            # when a self-attention mask is present (denoising training) —
+            # ordering would have to permute the mask too; the in-op sort
+            # handles that case unchanged.
+            presort = presort_wanted() and self_attention_mask is None
+            if presort:
+                sort_q, unsort_q = locality_presort(ref[..., :2])
+                h, ref = sort_q(h), sort_q(ref)
+            query_pos_head = MLPHead(
+                2 * cfg.d_model, cfg.d_model, 2, dtype=self.dtype, name="query_pos_head"
+            )
+            aux_logits, aux_boxes = [], []
+            for i in range(cfg.decoder_layers):
+                pos = query_pos_head(ref.astype(self.dtype))
+                h = DecoderLayer(
+                    cfg, dtype=self.dtype, presorted=presort, name=f"decoder_layer{i}"
+                )(
+                    h, pos, source_flatten, ref.astype(self.dtype), spatial_shapes,
+                    self_attention_mask,
+                )
+                with jax.named_scope("heads"):
+                    box_delta = MLPHead(cfg.d_model, 4, 3, dtype=self.dtype, name=f"bbox_head{i}")(h)
+                    new_ref = nn.sigmoid(box_delta.astype(jnp.float32) + inverse_sigmoid(ref))
+                    logits_i = nn.Dense(cfg.num_labels, dtype=self.dtype, name=f"class_head{i}")(h)
+                    aux_logits.append(logits_i.astype(jnp.float32))
+                    aux_boxes.append(new_ref)
+                ref = jax.lax.stop_gradient(new_ref)
+
+            if presort:
+                aux_logits = [unsort_q(a) for a in aux_logits]
+                aux_boxes = [unsort_q(a) for a in aux_boxes]
 
         return {
             "logits": aux_logits[-1],

@@ -95,9 +95,10 @@ class YolosLayer(nn.Module):
         normed = nn.LayerNorm(
             epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="layernorm_after"
         )(x)
-        ffn = QuantDense(cfg.intermediate_size, dtype=self.dtype, name="fc1")(normed)
-        ffn = get_activation(cfg.hidden_act)(ffn)
-        return x + QuantDense(cfg.hidden_size, dtype=self.dtype, name="fc2")(ffn)
+        with jax.named_scope("mlp"):
+            ffn = QuantDense(cfg.intermediate_size, dtype=self.dtype, name="fc1")(normed)
+            ffn = get_activation(cfg.hidden_act)(ffn)
+            return x + QuantDense(cfg.hidden_size, dtype=self.dtype, name="fc2")(ffn)
 
 
 class YolosDetector(nn.Module):
@@ -118,67 +119,70 @@ class YolosDetector(nn.Module):
         n_src = src_hw[0] * src_hw[1]
         t = cfg.num_detection_tokens
 
-        # row-dot patchify (layers.PatchEmbed): exact conv rewrite, ~2x on
-        # v5e for 3-channel patchify (pre-round note, round 4, git history)
-        x = PatchEmbed(
-            cfg.hidden_size, p, dtype=self.dtype, name="patch_projection"
-        )(pixel_values)
+        with jax.named_scope("embed"):
+            # row-dot patchify (layers.PatchEmbed): exact conv rewrite, ~2x on
+            # v5e for 3-channel patchify (pre-round note, round 4, git history)
+            x = PatchEmbed(
+                cfg.hidden_size, p, dtype=self.dtype, name="patch_projection"
+            )(pixel_values)
 
-        cls_token = self.param(
-            "cls_token", nn.initializers.zeros, (1, 1, cfg.hidden_size), jnp.float32
-        )
-        det_tokens = self.param(
-            "detection_tokens", nn.initializers.zeros, (1, t, cfg.hidden_size), jnp.float32
-        )
-        pos_table = self.param(
-            "position_embeddings",
-            nn.initializers.zeros,
-            (1, n_src + t + 1, cfg.hidden_size),
-            jnp.float32,
-        )
-        x = jnp.concatenate(
-            [
-                jnp.broadcast_to(cls_token.astype(self.dtype), (b, 1, cfg.hidden_size)),
-                x,
-                jnp.broadcast_to(det_tokens.astype(self.dtype), (b, t, cfg.hidden_size)),
-            ],
-            axis=1,
-        )
-
-        def split_pos(table):
-            return (
-                table[:, :1],
-                _interpolate_patch_pos(table[:, 1 : 1 + n_src], src_hw, (gh, gw)),
-                table[:, 1 + n_src :],
+            cls_token = self.param(
+                "cls_token", nn.initializers.zeros, (1, 1, cfg.hidden_size), jnp.float32
             )
-
-        pos = jnp.concatenate(split_pos(pos_table), axis=1)
-        x = x + pos.astype(self.dtype)
-
-        if cfg.use_mid_position_embeddings:
-            mid_table = self.param(
-                "mid_position_embeddings",
+            det_tokens = self.param(
+                "detection_tokens", nn.initializers.zeros, (1, t, cfg.hidden_size), jnp.float32
+            )
+            pos_table = self.param(
+                "position_embeddings",
                 nn.initializers.zeros,
-                (cfg.num_hidden_layers - 1, 1, n_src + t + 1, cfg.hidden_size),
+                (1, n_src + t + 1, cfg.hidden_size),
                 jnp.float32,
             )
-        for i in range(cfg.num_hidden_layers):
-            x = YolosLayer(cfg, dtype=self.dtype, name=f"layer{i}")(x)
-            if cfg.use_mid_position_embeddings and i < cfg.num_hidden_layers - 1:
-                mid = jnp.concatenate(split_pos(mid_table[i]), axis=1)
-                x = x + mid.astype(self.dtype)
+            x = jnp.concatenate(
+                [
+                    jnp.broadcast_to(cls_token.astype(self.dtype), (b, 1, cfg.hidden_size)),
+                    x,
+                    jnp.broadcast_to(det_tokens.astype(self.dtype), (b, t, cfg.hidden_size)),
+                ],
+                axis=1,
+            )
 
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="layernorm")(x)
+            def split_pos(table):
+                return (
+                    table[:, :1],
+                    _interpolate_patch_pos(table[:, 1 : 1 + n_src], src_hw, (gh, gw)),
+                    table[:, 1 + n_src :],
+                )
+
+            pos = jnp.concatenate(split_pos(pos_table), axis=1)
+            x = x + pos.astype(self.dtype)
+
+        with jax.named_scope("encoder"):
+            if cfg.use_mid_position_embeddings:
+                mid_table = self.param(
+                    "mid_position_embeddings",
+                    nn.initializers.zeros,
+                    (cfg.num_hidden_layers - 1, 1, n_src + t + 1, cfg.hidden_size),
+                    jnp.float32,
+                )
+            for i in range(cfg.num_hidden_layers):
+                x = YolosLayer(cfg, dtype=self.dtype, name=f"layer{i}")(x)
+                if cfg.use_mid_position_embeddings and i < cfg.num_hidden_layers - 1:
+                    mid = jnp.concatenate(split_pos(mid_table[i]), axis=1)
+                    x = x + mid.astype(self.dtype)
+
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=self.dtype, name="layernorm")(x)
         det_out = x[:, -t:]
 
-        # fp32 head outputs under bf16 compute (box precision at 640 px scale)
-        logits = MLPHead(
-            cfg.hidden_size, cfg.num_labels + 1, 3, dtype=self.dtype,
-            name="class_labels_classifier",
-        )(det_out)
-        boxes = nn.sigmoid(
-            MLPHead(cfg.hidden_size, 4, 3, dtype=self.dtype, name="bbox_predictor")(
-                det_out
-            ).astype(jnp.float32)
-        )
+        with jax.named_scope("heads"):
+            # fp32 head outputs under bf16 compute (box precision at 640 px scale)
+            logits = MLPHead(
+                cfg.hidden_size, cfg.num_labels + 1, 3, dtype=self.dtype,
+                name="class_labels_classifier",
+            )(det_out)
+            boxes = nn.sigmoid(
+                MLPHead(cfg.hidden_size, 4, 3, dtype=self.dtype, name="bbox_predictor")(
+                    det_out
+                ).astype(jnp.float32)
+            )
         return {"logits": logits.astype(jnp.float32), "pred_boxes": boxes}

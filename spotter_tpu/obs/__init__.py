@@ -37,6 +37,7 @@ from spotter_tpu.obs.trace import (  # noqa: F401
     ENGINE_STAGES,
     FETCH,
     H2D,
+    NO_TRACE,
     NETWORK,
     OTHER,
     POSTPROCESS,
@@ -47,11 +48,14 @@ from spotter_tpu.obs.trace import (  # noqa: F401
     TRACEPARENT_HEADER,
     Trace,
     batch_trace_id,
+    batch_traces,
     begin_trace,
     current_trace,
     new_request_id,
+    host_spans_snapshot,
     parse_traceparent,
-    record_engine_spans,
+    record_span,
+    set_annotator,
     set_batch_traces,
     set_current_trace,
     span,

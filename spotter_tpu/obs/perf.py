@@ -520,6 +520,14 @@ class PerfLedger:
                 "bytes_in_use": int(stats.get("bytes_in_use", 0) or 0),
                 "peak_bytes": int(stats.get("peak_bytes_in_use", 0) or 0),
                 "limit_bytes": int(stats.get("bytes_limit", 0) or 0),
+                # what the compiled programs reserve for their temporaries,
+                # which the two above leave out (3.19 GiB under YOLOS-base's
+                # bucket of 48, PERF.md section 5): a chip holds, at its
+                # peak, peak_bytes + peak_bytes_reserved
+                "bytes_reserved": int(stats.get("bytes_reserved", 0) or 0),
+                "peak_bytes_reserved": int(
+                    stats.get("peak_bytes_reserved", 0) or 0
+                ),
             }
 
     def ensure_hbm_device(self, device: str) -> None:
@@ -531,7 +539,10 @@ class PerfLedger:
         with self._lock:
             self._hbm.setdefault(
                 str(device),
-                {"bytes_in_use": 0, "peak_bytes": 0, "limit_bytes": 0},
+                {
+                    "bytes_in_use": 0, "peak_bytes": 0, "limit_bytes": 0,
+                    "bytes_reserved": 0, "peak_bytes_reserved": 0,
+                },
             )
 
     # -- views ------------------------------------------------------------

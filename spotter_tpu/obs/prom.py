@@ -44,6 +44,11 @@ _LABELED_KEYS = {
     # tenants{tenant="acme",stat="admits_total"} ... cardinality is capped
     # by the plane's top_k + "other" overflow bucket, never by scrape luck
     "tenants": ("tenant", "stat"),
+    # the host timeline (ISSUE 26): one row per span name, and the ladder's
+    # batch counts per rung
+    "host_spans": ("span", "stat"),
+    "setup_phases_s": ("phase",),
+    "bucket_batches_total": ("bucket",),
 }
 # keys whose dict values are {"p50": x, "p90": y, ...} quantile summaries
 # (the engine snapshot's slack_at_dispatch_ms, ISSUE 9) — rendered as a

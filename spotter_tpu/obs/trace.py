@@ -10,8 +10,15 @@ header plus an `X-Request-ID` the client can quote back.
 
 Span capture is a monotonic-clock read and a list append under the GIL; when
 no trace is active (flight recorder off, or a codepath outside a request)
-every helper is a None check — the production hot path pays nothing it can
-measure.
+no Span or Trace is allocated.
+
+`span` is the one way to time host work, and it has three sinks (ISSUE 26):
+the request `Trace` (as ever), a process-wide table `name -> count, wall_ms,
+cpu_ms` that `/metrics` serves as `host_spans` (on in every run, traced or
+not), and, for spans with no `await` inside, a profiler annotation that puts
+the span on the device trace's own clock. This module stays stdlib-only: the
+serving process hands the annotation factory in (`set_annotator`), so the
+supervisor never imports jax for it.
 
 Stage-name vocabulary: `STAGES` is the ONE list of stage names shared by
 trace spans, the Metrics stage histograms, and bench.py's per-stage JSON
@@ -79,23 +86,41 @@ def trace_stats() -> dict:
 
 class Span:
     """One timed stage inside a trace. Times are milliseconds relative to
-    the trace start, so a serialized trace is self-contained."""
+    the trace start, so a serialized trace is self-contained. A `detail`
+    span lies inside a stage span (`engine.stack_pad` inside `decode`): it
+    is shown, and left out of the per-stage totals, which would otherwise
+    count its time twice. `args` is what the span said of itself (the
+    engine's batch sequence number, bucket and image count)."""
 
-    __slots__ = ("name", "start_ms", "duration_ms")
+    __slots__ = ("name", "start_ms", "duration_ms", "detail", "args")
 
-    def __init__(self, name: str, start_ms: float, duration_ms: float) -> None:
+    def __init__(
+        self,
+        name: str,
+        start_ms: float,
+        duration_ms: float,
+        detail: bool = False,
+        args: dict | None = None,
+    ) -> None:
         global _spans_created
         self.name = name
         self.start_ms = start_ms
         self.duration_ms = duration_ms
+        self.detail = detail
+        self.args = args
         _spans_created += 1
 
     def to_dict(self) -> dict:
-        return {
+        out = {
             "name": self.name,
             "start_ms": round(self.start_ms, 3),
             "duration_ms": round(self.duration_ms, 3),
         }
+        if self.detail:
+            out["detail"] = True
+        if self.args:
+            out.update(self.args)
+        return out
 
 
 class Trace:
@@ -121,7 +146,7 @@ class Trace:
         # request hot path and the id only needs W3C's 8 random bytes
         self.span_id = os.urandom(8).hex()
         self.started_at = time.time()
-        self._t0 = time.monotonic()
+        self._t0 = _now()
         self.spans: list[Span] = []
         self.status = "ok"
         self.error: str | None = None
@@ -131,10 +156,20 @@ class Trace:
 
     # -- span capture --
 
-    def add_span(self, name: str, t_start: float, t_end: float) -> None:
+    def add_span(
+        self,
+        name: str,
+        t_start: float,
+        t_end: float,
+        detail: bool = False,
+        args: dict | None = None,
+    ) -> None:
         """Append a span from absolute monotonic timestamps."""
         self.spans.append(
-            Span(name, (t_start - self._t0) * 1e3, (t_end - t_start) * 1e3)
+            Span(
+                name, (t_start - self._t0) * 1e3, (t_end - t_start) * 1e3,
+                detail, args,
+            )
         )
 
     def add_span_ms(self, name: str, start_ms: float, duration_ms: float) -> None:
@@ -152,16 +187,18 @@ class Trace:
         late finisher cannot shrink an already-recorded total)."""
         with self._lock:
             if self.duration_ms is None:
-                self.duration_ms = (time.monotonic() - self._t0) * 1e3
+                self.duration_ms = (_now() - self._t0) * 1e3
             return self.duration_ms
 
     # -- serialization --
 
     def stage_totals(self) -> dict[str, float]:
-        """Per-name summed durations (ms) — the Server-Timing payload."""
+        """Per-name summed durations (ms) — the Server-Timing payload.
+        Detail spans lie inside stage spans and are left out."""
         totals: dict[str, float] = {}
         for s in list(self.spans):
-            totals[s.name] = totals.get(s.name, 0.0) + s.duration_ms
+            if not s.detail:
+                totals[s.name] = totals.get(s.name, 0.0) + s.duration_ms
         return totals
 
     def to_dict(self) -> dict:
@@ -253,29 +290,149 @@ def begin_trace(
     return trace
 
 
+# ---- the process-wide span table (the `host_spans` key of /metrics) ----
+
+_now = time.monotonic  # a module global, so a test can put a fake clock here
+_table_lock = threading.Lock()
+_table: dict[str, list] = {}  # name -> [count, wall_ms, cpu_ms]
+# `factory(name, **args)` -> a context manager that puts the span into a
+# running profiler capture; None until the serving process installs jax's
+_annotator = None
+
+
+# Pass as a span's `trace` to keep it off every request trace (table and
+# annotation only): the decode pool's per-image spans, 48 to a batch.
+NO_TRACE: tuple = ()
+
+
+def set_annotator(factory) -> None:
+    """Install (or, with None, remove) the profiler annotation factory:
+    `jax.profiler.TraceAnnotation` in the serving process
+    (engine/profiler.py). Outside a capture an annotation is one flag check."""
+    global _annotator
+    _annotator = factory
+
+
+def record_span(
+    name: str, wall_s: float, cpu_s: float = 0.0, count: int = 1
+) -> None:
+    """Add to the table without a `span` object: a wait that is measured
+    from two stamps the caller already has (the batcher's queue wait)."""
+    with _table_lock:
+        row = _table.get(name)
+        if row is None:
+            row = _table[name] = [0, 0.0, 0.0]
+        row[0] += count
+        row[1] += wall_s * 1e3
+        row[2] += cpu_s * 1e3
+
+
+def host_spans_snapshot() -> dict:
+    with _table_lock:
+        return {
+            name: {
+                "count": count,
+                "wall_ms": round(wall_ms, 3),
+                "cpu_ms": round(cpu_ms, 3),
+            }
+            for name, (count, wall_ms, cpu_ms) in _table.items()
+        }
+
+
+def reset_host_spans() -> None:
+    with _table_lock:
+        _table.clear()
+
+
 class span:
-    """`with span("fetch"):` — record one stage on the ambient trace (or an
-    explicit one). No active trace ⇒ no allocation, but the fault
-    harness's `slow_stage` injection still applies so SLO tests get
-    deterministic latency whether or not tracing captured it."""
+    """`with span("detector.pil_decode", annotate=True):` — time one piece
+    of host work. On exit it goes to the request trace (the ambient one, an
+    explicit one, or each of a batch's: pass the list), to the span table,
+    and, with `annotate`, it is a profiler annotation while it runs.
 
-    __slots__ = ("name", "trace", "_t0")
+    `stage`: the name from the stage vocabulary that the trace records
+    the span under (`engine.decode` is the engine's half of `decode`); a
+    span with none, whose own name is no stage either, is a detail inside
+    one. `annotate` is for spans with no `await` inside: an annotation
+    lives on its thread's stack, and an `await` would leave it open under
+    whatever runs next. `cpu` adds the thread's CPU time (decode-pool
+    work: wall over CPU says whether the pool ran in parallel). Further
+    keywords (`batch=`, `bucket=`, `n=`) go on the annotation and the
+    trace's span.
 
-    def __init__(self, name: str, trace: Trace | None = None) -> None:
+    No active trace ⇒ no Span allocated, but the fault harness's
+    `slow_stage` injection still applies so SLO tests get deterministic
+    latency whether or not tracing captured it.
+
+    `start()` / `stop()` are the `with` block taken apart, for the one
+    span that opens in one method and closes in another (the engine's
+    `device` stage: dispatch to data-on-host)."""
+
+    __slots__ = (
+        "name", "trace", "stage", "args", "seconds",
+        "_annotate", "_cpu", "_t0", "_c0", "_ann",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        trace=None,
+        *,
+        stage: str | None = None,
+        annotate: bool = False,
+        cpu: bool = False,
+        **args,
+    ) -> None:
         self.name = name
         self.trace = trace
+        self.stage = stage
+        self.args = args
+        self.seconds = 0.0
+        self._annotate = annotate
+        self._cpu = cpu
+        self._ann = None
+
+    def start(self) -> "span":
+        if self._annotate and _annotator is not None:
+            self._ann = _annotator(self.name, **self.args)
+            self._ann.__enter__()
+        if self._cpu:
+            self._c0 = time.thread_time()
+        self._t0 = _now()
+        return self
+
+    def stop(self) -> None:
+        t1 = _now()
+        self.seconds = t1 - self._t0
+        cpu_s = time.thread_time() - self._c0 if self._cpu else 0.0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        record_span(self.name, self.seconds, cpu_s)
+        tr = self.trace if self.trace is not None else _current.get()
+        if not tr:
+            return
+        name = self.stage or self.name
+        detail = name not in STAGES
+        args = self.args or None
+        if isinstance(tr, Trace):
+            tr.add_span(name, self._t0, t1, detail, args)
+            return
+        # a batch's traces, one entry per image: a stage span goes to each
+        # (an image's stages tile its own wait), a detail once per request
+        for one in dict.fromkeys(tr) if detail else tr:
+            one.add_span(name, self._t0, t1, detail, args)
 
     def __enter__(self) -> "span":
-        delay = faults.stage_delay_s(self.name)
+        self.start()
+        # inside the window, so an injected delay shows in the stage it names
+        delay = faults.stage_delay_s(self.stage or self.name)
         if delay > 0.0:
             time.sleep(delay)
-        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        tr = self.trace if self.trace is not None else _current.get()
-        if tr is not None:
-            tr.add_span(self.name, self._t0, time.monotonic())
+        self.stop()
 
 
 # ---- batch fan-out (engine worker thread -> per-request traces) ----
@@ -288,19 +445,16 @@ def set_batch_traces(traces: list) -> None:
     _batch_traces.set(traces or None)
 
 
+def batch_traces() -> list:
+    """What an engine-side `span` passes as its `trace`: the traces riding
+    in the current batch, one per traced image. Empty outside a traced
+    batch, and never the ambient trace: the worker thread's context is the
+    batcher's pump task's, which was born inside whatever request came
+    first."""
+    return _batch_traces.get() or []
+
+
 def batch_trace_id() -> str | None:
     """The exemplar trace id for this engine batch (first traced item)."""
     traces = _batch_traces.get()
     return traces[0].trace_id if traces else None
-
-
-def record_engine_spans(stages: list[tuple[str, float, float]]) -> None:
-    """Fan the engine's per-batch stage windows (absolute monotonic
-    (name, t_start, t_end) triples) out to every request trace riding in
-    the current batch. A no-op outside a traced batch."""
-    traces = _batch_traces.get()
-    if not traces:
-        return
-    for tr in traces:
-        for name, t_start, t_end in stages:
-            tr.add_span(name, t_start, t_end)

@@ -375,9 +375,17 @@ def batch_images_uint8(
     """Stack uint8-decoded images -> (pixels (B,H,W,3) u8, valid (B,2) i32,
     sizes (B,2) f32 [orig h,w])."""
     decode = partial(decode_resize_uint8, spec=spec, canvas_hw=canvas_hw)
-    decoded = pool.map(decode, images) if pool is not None else [
-        decode(img) for img in images
-    ]
+    return stack_uint8(
+        pool.map(decode, images) if pool is not None else [
+            decode(img) for img in images
+        ]
+    )
+
+
+def stack_uint8(decoded: list) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The caller's half of `batch_images_uint8`: per-image results of
+    `decode_resize_uint8` -> the batch's arrays. Apart from the map so that
+    the engine can time the copy by itself (`engine.stack_pad`)."""
     return (
         np.stack([d[0] for d in decoded]),
         np.asarray([d[1] for d in decoded], dtype=np.int32),
@@ -394,9 +402,17 @@ def batch_images_host(
     """`batch_images` through the DecodePool: same float output, parallel
     per-image host preprocess (the host path keeps the pool win too)."""
     process = partial(preprocess_image, spec=spec, canvas_hw=canvas_hw)
-    done = pool.map(process, images) if pool is not None else [
-        process(img) for img in images
-    ]
+    return stack_host(
+        pool.map(process, images) if pool is not None else [
+            process(img) for img in images
+        ]
+    )
+
+
+def stack_host(done: list) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The caller's half of `batch_images_host`: per-image results of
+    `preprocess_image` -> the batch's arrays (one thread copies every
+    image's float pixels and mask: 21.6 MB an image at 800x1344)."""
     return (
         np.stack([p for p, _, _ in done]),
         np.stack([m for _, m, _ in done]),

@@ -13,6 +13,7 @@ own practice of testing the undecorated class: test_serve.py:32).
 import logging
 import os
 
+from spotter_tpu import obs
 from spotter_tpu.engine.batcher import MicroBatcher
 from spotter_tpu.engine.engine import InferenceEngine, default_batch_buckets
 from spotter_tpu.models import build_detector
@@ -201,14 +202,18 @@ def build_detector_app(
         # (sharding.check_rules_cover).
         tp_rules = family_for(model_name).tp_rules if axes["tp"] > 1 else ()
 
-    built = build_detector(model_name)
-    engine = InferenceEngine(
-        built,
-        threshold=threshold,
-        batch_buckets=batch_buckets,
-        mesh=mesh,
-        tp_rules=tp_rules,
-    )
+    # set-up phases (`setup_phases_s` in /metrics): the checkpoint read and
+    # converted, then the params put on the device(s) and the programs jitted
+    with obs.span("setup.weights_load"):
+        built = build_detector(model_name)
+    with obs.span("setup.engine_place"):
+        engine = InferenceEngine(
+            built,
+            threshold=threshold,
+            batch_buckets=batch_buckets,
+            mesh=mesh,
+            tp_rules=tp_rules,
+        )
     # /healthz surfaces which knob produced the serving mesh (satellite 2)
     engine.mesh_source = mesh_source
     if warmup:

@@ -381,7 +381,20 @@ class AmenitiesDetector:
             what="image fetch",
         )
 
-    async def _process_single_image(
+    async def _process_single_image(self, url: str, *args, **kwargs) -> ImageResult:
+        """One image, entry to reply. The starvation clock counts it as
+        upstream work for as long as it is in here (in fetch, PIL decode
+        or the batcher's queue, or its reply being drawn and encoded): with
+        the chip idle and no batch staging, that is what the chip waits
+        for (`starved_upstream_s_total`)."""
+        starvation = self.engine.metrics.starvation
+        starvation.move(upstream=+1)
+        try:
+            return await self._process_image(url, *args, **kwargs)
+        finally:
+            starvation.move(upstream=-1)
+
+    async def _process_image(
         self,
         url: str,
         deadline: Deadline | None = None,
@@ -402,10 +415,12 @@ class AmenitiesDetector:
         # further down must agree with that decision for this request
         boost = brownout.threshold_boost_value() if brownout is not None else 0.0
         try:
-            with obs.span(obs.FETCH, trace):
+            # the stage spans carry the detector's own names in the span
+            # table; a wait (fetch) is no profiler annotation
+            with obs.span("detector.fetch", trace, stage=obs.FETCH):
                 image_bytes = await self._fetch_for_request(url, deadline, info)
 
-            with obs.span(obs.DECODE, trace):
+            with obs.span("detector.decode", trace, stage=obs.DECODE):
                 cache_key: str | None = None
                 raw_detections: list[dict] | None = None
                 annotated: dict | None = None
@@ -451,7 +466,8 @@ class AmenitiesDetector:
                     and boost == 0.0
                 )
                 if not use_annotated:
-                    with Image.open(BytesIO(image_bytes)) as img_raw:
+                    with obs.span("detector.pil_decode", trace, annotate=True), \
+                            Image.open(BytesIO(image_bytes)) as img_raw:
                         # decode-bomb guard: the header-declared pixel count
                         # is checked BEFORE convert() decodes anything
                         # (preprocess.py)
@@ -459,7 +475,8 @@ class AmenitiesDetector:
                         image = img_raw.convert("RGB")
 
             if use_annotated:
-                with obs.span(obs.POSTPROCESS, trace):
+                with obs.span("detector.annotated_hit", trace,
+                              stage=obs.POSTPROCESS, annotate=True):
                     return DetectionSuccessResult(
                         url=url,
                         detections=[
@@ -500,7 +517,10 @@ class AmenitiesDetector:
                     if d.get("score", 1.0) >= eff_threshold
                 ]
 
-            with obs.span(obs.POSTPROCESS, trace):
+            # draw, JPEG-encode, base64: inline on the event loop, so its
+            # summed time is the one loop's ceiling (detector_loop_ms.bulk)
+            with obs.span("detector.draw_encode", trace,
+                          stage=obs.POSTPROCESS, annotate=True):
                 draw = ImageDraw.Draw(image)
                 image_detections: list[DetectionResult] = []
                 for det in raw_detections:

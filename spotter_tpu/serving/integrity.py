@@ -54,6 +54,7 @@ import os
 import time
 from typing import Callable, Optional
 
+from spotter_tpu import obs
 from spotter_tpu.obs import compare
 from spotter_tpu.serving.lifecycle import INTEGRITY_EXIT_CODE
 from spotter_tpu.serving.overload import BULK
@@ -292,11 +293,14 @@ class IntegrityPlane:
         batcher. Runs on cold start, warm compile-cache restore, OOM
         downgrade, and degraded-dp rebuild (`source` says which)."""
         self.verifications_total += 1
-        t0 = time.monotonic()
-        reason = self.attestor.attest()
+        # two set-up phases (`setup_phases_s` in /metrics)
+        with obs.span("setup.attest") as attest:
+            reason = self.attestor.attest()
+        self.last_verify_s = attest.seconds
         if reason is None:
-            reason = await self.probe.run(self.batcher)
-        self.last_verify_s = time.monotonic() - t0
+            with obs.span("setup.ready_probe") as probe:
+                reason = await self.probe.run(self.batcher)
+            self.last_verify_s += probe.seconds
         if reason is None:
             logger.info(
                 "integrity verification passed (%s): attest+probe in %.3fs",
@@ -336,8 +340,6 @@ class IntegrityPlane:
         """Pin a flight-recorder trace so the post-exit dump says WHAT
         disagreed, not just that something did."""
         try:
-            from spotter_tpu import obs
-
             trace = obs.begin_trace(request_id=f"integrity-{source}")
             trace.set_error(f"integrity: {reason}")
             obs.get_recorder().record(trace)

@@ -100,6 +100,22 @@ INTEGRITY_EXIT_CODE = 86
 _PROCESS_START = time.monotonic()
 
 
+def process_age_s() -> float:
+    """Seconds since the kernel started this process: interpreter start and
+    every import before this module included, which `_PROCESS_START` leaves
+    out. From /proc (the start time in clock ticks since boot against the
+    boot clock); where that cannot be read, since this module's import."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the fields after the command's closing parenthesis; the start
+            # time is the 22nd of the line, the 20th of these
+            started_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        boot_now = time.clock_gettime(time.CLOCK_BOOTTIME)
+        return boot_now - started_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return time.monotonic() - _PROCESS_START
+
+
 def compile_cache_dir() -> str:
     """The persistent compile-cache directory this process uses: wherever
     `JAX_COMPILATION_CACHE_DIR` places it, else `<checkout>/.jax_cache`.

@@ -53,6 +53,8 @@ import tempfile
 import pydantic
 from aiohttp import web
 
+from spotter_tpu import obs
+from spotter_tpu.engine.metrics import setup_phases_s
 from spotter_tpu.obs import http as obs_http
 from spotter_tpu.obs import logs as obs_logs
 from spotter_tpu.ops import preprocess
@@ -236,13 +238,19 @@ def make_app(
 
     async def _bring_up(app: web.Application) -> None:
         loop = asyncio.get_running_loop()
+        # set-up phases, each a span under `setup.`: /metrics shows them as
+        # one dict (`setup_phases_s`) and readiness logs them in one line.
+        # The first is everything before bring-up began: interpreter start,
+        # imports, the HTTP surface.
+        obs.record_span("setup.imports", lifecycle.process_age_s())
         try:
             det = await loop.run_in_executor(
                 None, _build_detector_blocking, model_name
             )
             tracker.mark(lifecycle.WARMING)
             if warmup:
-                await loop.run_in_executor(None, det.engine.warmup)
+                with obs.span("setup.warmup"):
+                    await loop.run_in_executor(None, det.engine.warmup)
             app["detector"] = det
             det.engine.metrics.set_restarts(lifecycle.restarts_from_env())
             _stamp_identity(det)
@@ -281,7 +289,10 @@ def make_app(
                     _make_integrity_recheck(plane)
                 )
             ttr = tracker.mark_ready(det.engine.metrics)
-            logger.info("replica ready in %.1f s", ttr)
+            logger.info(
+                "replica ready in %.1f s; set-up phases (s): %s", ttr,
+                json.dumps(setup_phases_s()),
+            )
             if plane is not None:
                 await plane.start()
         except asyncio.CancelledError:  # server shutdown mid-bring-up
@@ -296,6 +307,7 @@ def make_app(
         # profiler server after the loop exists; tasks stored for cleanup
         from spotter_tpu.engine import profiler
 
+        profiler.install_span_annotator()
         profiler.maybe_start_profiler_server()
         if app["detector"] is None:
             app["bringup_task"] = asyncio.create_task(_bring_up(app))
@@ -408,27 +420,29 @@ def make_app(
             except Exception:
                 logger.exception("detect failed")
                 return done(web.Response(status=500, text="Internal server error"))
-            body = response.model_dump(exclude_none=True)
-            # binary wire format (ISSUE 11): `Accept: application/x-spotter-frame`
-            # negotiates the length-prefixed frame (raw JPEG segments, deflated
-            # header — no base64 tax). NOT negotiated -> the exact pre-existing
-            # json_response call, byte-identical on the wire (exclude_none: the
-            # `degraded` marker is absent unless a brownout concession shaped
-            # this response — schemas.py contract).
-            frame = wire.wants_frame(request.headers.get("Accept"))
-            if frame:
-                # corrupt_frame injection (ISSUE 14): while armed, one byte of
-                # the encoded frame is flipped AFTER the checksums were
-                # computed — the deterministic way to prove the edge CRC
-                # validator catches, counts, and replays corruption
-                resp = web.Response(
-                    body=faults.corrupt_frame_bytes(
-                        wire.encode_frame(body), det.engine.metrics.replica_id
-                    ),
-                    content_type=wire.FRAME_CONTENT_TYPE,
-                )
-            else:
-                resp = web.json_response(body)
+            # the reply built and dumped, inline on the event loop
+            with obs.span("app.serialize", trace, annotate=True):
+                body = response.model_dump(exclude_none=True)
+                # binary wire format (ISSUE 11): `Accept: application/x-spotter-frame`
+                # negotiates the length-prefixed frame (raw JPEG segments, deflated
+                # header — no base64 tax). NOT negotiated -> the exact pre-existing
+                # json_response call, byte-identical on the wire (exclude_none: the
+                # `degraded` marker is absent unless a brownout concession shaped
+                # this response — schemas.py contract).
+                frame = wire.wants_frame(request.headers.get("Accept"))
+                if frame:
+                    # corrupt_frame injection (ISSUE 14): while armed, one byte of
+                    # the encoded frame is flipped AFTER the checksums were
+                    # computed — the deterministic way to prove the edge CRC
+                    # validator catches, counts, and replays corruption
+                    resp = web.Response(
+                        body=faults.corrupt_frame_bytes(
+                            wire.encode_frame(body), det.engine.metrics.replica_id
+                        ),
+                        content_type=wire.FRAME_CONTENT_TYPE,
+                    )
+                else:
+                    resp = web.json_response(body)
             x_cache = wire.summarize_cache_outcomes(
                 (info.get("cache") or {}).values()
             )

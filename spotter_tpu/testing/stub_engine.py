@@ -147,60 +147,61 @@ class StubEngine:
         pass
 
     def detect(self, images):
-        # Mirror the real engine's stage-window accounting (obs.STAGES
-        # vocabulary, ISSUE 7): the stub's "device" window is its service
-        # sleep, the other engine stages are real-but-tiny, and the
-        # slow_stage fault injects into the same seams — so fleet/trace
-        # tests over stub replicas see the same span set (and the same
-        # /metrics stage histograms) the production engine emits.
+        # Mirror the real engine's stage spans (obs.STAGES vocabulary,
+        # ISSUE 7): the stub's "device" window is its service sleep, the
+        # other engine stages are real-but-tiny, and the slow_stage fault
+        # injects into the same seam (obs.span's) — so fleet/trace tests
+        # over stub replicas see the same span set (and the same /metrics
+        # stage histograms) the production engine emits.
         from spotter_tpu import obs
         from spotter_tpu.testing import faults
 
-        t0 = time.monotonic()
-        faults.sleep_stage(obs.DECODE)
-        t_decode = time.monotonic()
-        faults.sleep_stage(obs.H2D)
-        t_h2d = time.monotonic()
-        faults.sleep_stage(obs.DEVICE)
-        # gray-failure injection (ISSUE 14): a slow_replica plan makes THIS
-        # process's every engine call slower inside the device window —
-        # /healthz stays green while /detect latency grows, the signature
-        # the pool's outlier score must catch
-        delay_s = faults.replica_delay_s(self.metrics.replica_id)
-        if delay_s > 0:
-            time.sleep(delay_s)
-        if self.service_s > 0:
-            time.sleep(self.service_s)
-        t_dev = time.monotonic()
-        faults.sleep_stage(obs.POSTPROCESS)
-        # Detections are a deterministic FUNCTION OF INPUT CONTENT
-        # (ISSUE 17 bugfix): the old `list(self.detections)` was
-        # input-independent, so any diff-based test — shadow-lane verdicts,
-        # quorum comparisons, cache-poisoning checks — passed vacuously
-        # (every answer "agreed" because every answer was identical). Now
-        # each image's content hash perturbs score and box inside the
-        # comparator's tolerance-equivalence classes: same input -> same
-        # output on every honest replica with the same weights, different
-        # input -> measurably different output.
-        out = [self._detections_for(img) for img in images]
-        out = [
-            faults.corrupt_detections(dets, self.metrics.replica_id)
-            for dets in out
-        ]
-        t_post = time.monotonic()
-        stage_windows = [
-            (obs.DECODE, t0, t_decode),
-            (obs.H2D, t_decode, t_h2d),
-            (obs.DEVICE, t_h2d, t_dev),
-            (obs.POSTPROCESS, t_dev, t_post),
-        ]
-        obs.record_engine_spans(stage_windows)
+        n = len(images)
+        traces = obs.batch_traces()
+        stages = {}
+        total = obs.span("engine.batch", traces, n=n).start()
+        # an empty window each: the slow_stage fault sleeps inside it
+        for name, stage in (("engine.decode", obs.DECODE), ("engine.h2d", obs.H2D)):
+            with obs.span(name, traces, stage=stage) as sp:
+                pass
+            stages[stage] = sp.seconds
+        starvation = self.metrics.starvation
+        starvation.move(in_flight=+1)
+        with obs.span("engine.device", traces, stage=obs.DEVICE) as device:
+            # gray-failure injection (ISSUE 14): a slow_replica plan makes
+            # THIS process's every engine call slower inside the device
+            # window — /healthz stays green while /detect latency grows,
+            # the signature the pool's outlier score must catch
+            delay_s = faults.replica_delay_s(self.metrics.replica_id)
+            if delay_s > 0:
+                time.sleep(delay_s)
+            if self.service_s > 0:
+                time.sleep(self.service_s)
+        starvation.move(in_flight=-1)
+        stages[obs.DEVICE] = device.seconds
+        with obs.span("engine.postprocess", traces, stage=obs.POSTPROCESS) as post:
+            # Detections are a deterministic FUNCTION OF INPUT CONTENT
+            # (ISSUE 17 bugfix): the old `list(self.detections)` was
+            # input-independent, so any diff-based test — shadow-lane
+            # verdicts, quorum comparisons, cache-poisoning checks — passed
+            # vacuously (every answer "agreed" because every answer was
+            # identical). Now each image's content hash perturbs score and
+            # box inside the comparator's tolerance-equivalence classes:
+            # same input -> same output on every honest replica with the
+            # same weights, different input -> measurably different output.
+            out = [self._detections_for(img) for img in images]
+            out = [
+                faults.corrupt_detections(dets, self.metrics.replica_id)
+                for dets in out
+            ]
+        stages[obs.POSTPROCESS] = post.seconds
+        total.stop()
+        bucket = next(
+            (b for b in self.batch_buckets if n <= b), self.batch_buckets[-1]
+        )
         self.metrics.record_batch(
-            len(images),
-            t_post - t0,
-            stages={name: t_end - t_start
-                    for name, t_start, t_end in stage_windows},
-            trace_id=obs.batch_trace_id(),
+            n, total.seconds, stages=stages, trace_id=obs.batch_trace_id(),
+            bucket=bucket,
         )
         # Device-efficiency ledger (ISSUE 10): the stub's "device" window
         # is its service sleep; no FLOPs (no compiled program), so MFU
@@ -208,10 +209,10 @@ class StubEngine:
         # and `bench.py --perf-overhead` measures the ledger's true cost
         # on the hot path.
         self.metrics.perf.record_dispatch(
-            device_s=t_dev - t_h2d,
-            batch=len(images),
+            device_s=device.seconds,
+            batch=n,
             trace_id=obs.batch_trace_id(),
-            shape=f"stub:{len(images)}",
+            shape=f"stub:{n}",
         )
         return out
 
