@@ -35,6 +35,7 @@ from spotter_tpu.engine.errors import (
     classify_engine_exception,
 )
 from spotter_tpu.engine.metrics import Metrics
+from spotter_tpu.engine.staging import Slab, StagingSlabs
 from spotter_tpu.testing import faults
 from spotter_tpu.ops.postprocess import (
     sigmoid_max_postprocess,
@@ -52,8 +53,6 @@ from spotter_tpu.ops.preprocess import (
     device_rescale_normalize,
     preprocess_image,
     shortest_edge_size,
-    stack_host,
-    stack_uint8,
 )
 
 logger = logging.getLogger(__name__)
@@ -105,7 +104,8 @@ class _Batch:
     n: int
     bucket: int
     qset: object = None
-    arrays: tuple = ()  # host arrays, then their device copies
+    arrays: tuple = ()  # host arrays (views of `slab`), then their device copies
+    slab: Optional[Slab] = None  # leased until the outputs are on the host
     outputs: tuple = ()
     meta: Optional[dict] = None
     stages: dict = field(default_factory=dict)
@@ -407,6 +407,12 @@ class InferenceEngine:
             self.device = device or jax.devices()[0]
             self.params = jax.device_put(self.built.params, self.device)
             self._in_sharding = self.device
+        # the host staging buffers are sized by the ladder's largest rung,
+        # so a re-place starts a free-list of its own
+        self._slabs = StagingSlabs(
+            self.batch_buckets[-1], self.built.preprocess_spec,
+            self.device_preprocess, self.metrics,
+        )
         # What this engine runs on, checked and said once per placement;
         # the same block answers /healthz. Re-run on every re-place so a
         # degraded rebuild's narrower device set is reflected there and in
@@ -877,15 +883,17 @@ class InferenceEngine:
 
         Device-preprocess mode produces uint8 pixels + a (B, 2) valid-region
         tensor (3 B/px of H2D) instead of float pixels + a full mask
-        (16 B/px); either way the per-image host work runs on the decode
-        pool. `canvas_hw` (ragged, ISSUE 9) shrinks the shortest_edge pad
-        target; pad rows always fill to whatever canvas the real rows got,
-        so one batch is one static shape.
+        (16 B/px). Either way the batch's arrays are views of one leased
+        slab (engine/staging.py) and each decode-pool task writes its image
+        straight into its own row: the batch is written once. `canvas_hw`
+        (ragged, ISSUE 9) shrinks the shortest_edge pad target; pad rows
+        always fill to whatever canvas the real rows got, so one batch is
+        one static shape.
 
         The `decode` stage is tiled by its children: `engine.preprocess_map`
         (the decode pool's map, one `engine.preprocess_image` per image
-        inside it, on the pool's threads) and `engine.stack_pad` (the
-        caller's own copies: `np.stack`, then the pad to the bucket).
+        inside it, on the pool's threads) and `engine.stack_pad` (what is
+        left to the caller: the `bucket - n` pad rows and the sizes).
         """
         n = len(images)
         batch = _Batch(next(self._batch_seq), n, self.bucket_for(n), qset)
@@ -894,38 +902,50 @@ class InferenceEngine:
         spec = self.built.preprocess_spec
         if canvas_hw is not None and spec.mode != "shortest_edge":
             canvas_hw = None  # fixed/pad_square canvases ARE the signal
-        per_image = decode_resize_uint8 if self.device_preprocess else preprocess_image
+        h, w = spec.input_hw if canvas_hw is None else map(int, canvas_hw)
+        mask_rows = not (self.device_preprocess or self._slabs.mask_is_ones)
 
-        def one(image):
+        def one(job):  # -> (valid (h, w) or None, original (h, w))
+            j, image = job
             with obs.span("engine.preprocess_image", obs.NO_TRACE, annotate=True,
                           cpu=True, batch=batch.seq):
-                return per_image(image, spec=spec, canvas_hw=canvas_hw)
+                if self.device_preprocess:
+                    return decode_resize_uint8(
+                        image, spec, canvas_hw, out=pixels[j])[1:]
+                dst = (pixels[j], second[j] if mask_rows else None)
+                return None, preprocess_image(image, spec, canvas_hw, out=dst)[2]
 
         # slow_stage=decode:<ms> lands inside (obs.span's fault seam)
         with obs.span("engine.decode", traces, stage=obs.DECODE,
                       annotate=True, **tags) as decode:
+            # from here the batch holds the lease, and only `_finish` ends it
+            batch.slab = self._slabs.lease(batch.bucket, h, w)
+            pixels, second = batch.slab.views(batch.bucket, h, w)
             with obs.span("engine.preprocess_map", traces, annotate=True, **tags):
-                done = self._decode_pool.map(one, images)
+                done = self._decode_pool.map(one, list(enumerate(images)))
             with obs.span("engine.stack_pad", traces, annotate=True, **tags):
-                stack = stack_uint8 if self.device_preprocess else stack_host
-                pixels, second, sizes = stack(done)  # second: valid region, or masks
-                pad = batch.bucket - n  # pad batch to the static bucket size
-                if pad > 0:
-                    if self.device_preprocess:  # a pad row's valid region: the canvas
-                        fill = np.tile(
-                            np.asarray([pixels.shape[1:3]], np.int32), (pad, 1)
-                        )
-                    else:
-                        fill = np.ones((pad, *second.shape[1:]), second.dtype)
-                    pixels = np.concatenate(
-                        [pixels, np.zeros((pad, *pixels.shape[1:]), pixels.dtype)]
-                    )
-                    second = np.concatenate([second, fill])
-                    sizes = np.concatenate([sizes, np.ones((pad, 2), sizes.dtype)])
+                sizes = self._fill_pad_rows(pixels, second, done)
                 batch.arrays = (pixels, second, sizes)
         batch.stages[obs.DECODE] = decode.seconds
-        batch.meta = self._perf_meta(images, batch.arrays[0], n, spec, qset)
+        batch.meta = self._perf_meta(images, pixels, n, spec, qset)
         return batch
+
+    def _fill_pad_rows(self, pixels, second, done: list) -> np.ndarray:
+        """The caller's share of staging, after the pool's tasks have
+        written the real rows: the `bucket - n` pad rows (zero pixels; a
+        mask of ones, unless the slab's always is; the canvas as a pad
+        row's valid region), the real rows' valid regions, and the `(B, 2)`
+        sizes, which it returns. `done`: what each task returned."""
+        n = len(done)
+        sizes = np.ones((len(pixels), 2), np.float32)
+        sizes[:n] = [orig_hw for _, orig_hw in done]
+        pixels[n:] = 0
+        if self.device_preprocess:
+            second[:n] = [valid_hw for valid_hw, _ in done]
+            second[n:] = pixels.shape[1:3]
+        elif not self._slabs.mask_is_ones:
+            second[n:] = 1.0
+        return sizes
 
     def _perf_meta(self, images, pixels, n: int, spec, qset=None) -> Optional[dict]:
         """Per-dispatch efficiency accounting inputs (ISSUE 10): the shape
@@ -1043,7 +1063,8 @@ class InferenceEngine:
         """Block on the fetch, threshold on host, record metrics.
 
         Stage vocabulary is obs.STAGES everywhere (ISSUE 7 satellite):
-        decode = decode-pool host work and the caller's copies, h2d =
+        decode = decode-pool host work, each task writing its row of the
+        leased slab, and the caller's pad rows, h2d =
         device_put enqueue (lock wait included), device = dispatch ->
         data-on-host (under pipelining the next chunk's host staging runs
         inside this span, but so does this chunk's compute — measuring from
@@ -1059,6 +1080,12 @@ class InferenceEngine:
         finally:
             batch.device.stop()
             self.metrics.starvation.move(in_flight=-1)
+        # the outputs are on the host, so the program has consumed its
+        # inputs and nothing reads the slab any more: the lease ends here,
+        # and nowhere else (a batch that failed before this line drops its
+        # slab, which a pool task or a transfer may still be touching)
+        self._slabs.release(batch.slab)
+        batch.slab = None
         batch.stages[obs.DEVICE] = batch.device.seconds
         with obs.span("engine.postprocess", traces, stage=obs.POSTPROCESS,
                       annotate=True, **tags) as post:

@@ -209,6 +209,10 @@ class Metrics:
         # over slots is the fill of the ladder, from the counters alone
         self._bucket_batches: dict[int, int] = {}
         self._slots_total = 0
+        # host staging slabs (ISSUE 27): leases, and how many of them found
+        # the free-list empty and allocated; the rest reused a slab
+        self._slab_leases_total = 0
+        self._slab_allocs_total = 0
         self.starvation = StarvationClock()
         self._started = time.monotonic()
         # (timestamp, batch_size) ring for rate computation — snapshot() reads
@@ -422,6 +426,13 @@ class Metrics:
         """n poisonous items isolated to their own futures by bisect-retry."""
         with self._lock:
             self._poison_isolated_total += n
+
+    def record_slab_lease(self, allocated: bool) -> None:
+        """One batch leased a host staging slab (engine/staging.py);
+        `allocated`: none was free, so a new one was made."""
+        with self._lock:
+            self._slab_leases_total += 1
+            self._slab_allocs_total += bool(allocated)
 
     def record_batch_retry(self, n: int = 1) -> None:
         """A failed batch was split and retried (poison bisect or OOM downgrade)."""
@@ -743,6 +754,8 @@ class Metrics:
                     str(b): n for b, n in sorted(self._bucket_batches.items())
                 },
                 "slots_total": self._slots_total,
+                "staging_slab_leases_total": self._slab_leases_total,
+                "staging_slab_allocs_total": self._slab_allocs_total,
                 "starved_staging_s_total": round(starved_staging_s, 6),
                 "starved_upstream_s_total": round(starved_upstream_s, 6),
                 "padding_waste_pct": waste,

@@ -15,7 +15,6 @@ import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from functools import partial
 
 import numpy as np
 from PIL import Image
@@ -174,10 +173,24 @@ def _canvas_for(
     return ch, cw
 
 
+def _slot(out, shape: tuple[int, ...], dtype) -> np.ndarray:
+    """The caller's destination for one image's array, checked, or a new
+    array when it gave none (every byte of it is then written below)."""
+    if out is None:
+        return np.empty(shape, dtype=dtype)
+    if out.shape != shape or out.dtype != dtype:
+        raise ValueError(
+            f"destination {out.dtype}{out.shape} is not the "
+            f"{np.dtype(dtype)}{shape} this image stages into"
+        )
+    return out
+
+
 def preprocess_image(
     image: Image.Image,
     spec: PreprocessSpec,
     canvas_hw: tuple[int, int] | None = None,
+    out: tuple[np.ndarray, np.ndarray | None] | None = None,
 ) -> tuple[np.ndarray, np.ndarray, tuple[int, int]]:
     """PIL image -> (pixels NHWC-sans-N float32, pixel_mask (H, W) float32, orig (h, w)).
 
@@ -185,23 +198,39 @@ def preprocess_image(
     (non-pad) pixels, the analog of HF DETR's pixel_mask. `canvas_hw`
     (ragged batching, ISSUE 9) shrinks the shortest_edge pad target below
     the static bucket; ignored for modes whose canvas IS the signal.
+
+    `out` (in-place staging, ISSUE 27): `(pixels, mask)` destinations of the
+    canvas's shape, written in place and returned, so that a batch is
+    staged without a copy: the last arithmetic step writes the pixels
+    there, and every byte is what the arrays made without `out` hold. The
+    mask may be None where it is all ones (`fixed`, `pad_square`) and the
+    caller's already is.
     """
     check_image_pixels(image)
     orig_hw = (image.height, image.width)
+    out_px, mask = out if out is not None else (None, None)
+    normalized = spec.mean is not None and spec.std is not None
 
-    def rescale_normalize(a: np.ndarray) -> np.ndarray:
-        a = a * spec.rescale_factor
-        if spec.mean is not None and spec.std is not None:
-            a = (a - np.asarray(spec.mean, dtype=np.float32)) / np.asarray(
-                spec.std, dtype=np.float32
-            )
-        return a
+    def normalize(a: np.ndarray, dst: np.ndarray) -> None:
+        np.divide(
+            a - np.asarray(spec.mean, dtype=np.float32),
+            np.asarray(spec.std, dtype=np.float32),
+            out=dst,
+        )
+
+    def rescale_normalize(a: np.ndarray, dst: np.ndarray) -> None:
+        if normalized:
+            normalize(a * spec.rescale_factor, dst)
+        else:
+            np.multiply(a, spec.rescale_factor, out=dst)
 
     if spec.mode == "fixed":
         th, tw = spec.size
         resized = image.resize((tw, th), resample=spec.resample)
-        arr = rescale_normalize(np.asarray(resized, dtype=np.float32))
-        mask = np.ones((th, tw), dtype=np.float32)
+        arr = _slot(out_px, (th, tw, 3), np.float32)
+        rescale_normalize(np.asarray(resized, dtype=np.float32), arr)
+        if out is None:
+            mask = np.ones((th, tw), dtype=np.float32)
     elif spec.mode == "pad_square":
         # OWLv2: rescale to [0,1], pad bottom/right to square with 0.5 gray,
         # resize the PADDED square to `size`, then normalize — the exact HF
@@ -223,26 +252,33 @@ def preprocess_image(
         filtered = (
             ndi.gaussian_filter(padded, sigma, mode="mirror") if sigma.any() else padded
         )
-        out = ndi.zoom(
+        zoomed = ndi.zoom(
             filtered, 1.0 / factors, order=1, mode="mirror", grid_mode=True
         )
-        arr = np.clip(out, padded.min(), padded.max()).astype(np.float32)
-        if spec.mean is not None and spec.std is not None:
-            arr = (arr - np.asarray(spec.mean, dtype=np.float32)) / np.asarray(
-                spec.std, dtype=np.float32
-            )
-        mask = np.ones((th, tw), dtype=np.float32)
+        warped = np.clip(zoomed, padded.min(), padded.max()).astype(np.float32)
+        arr = _slot(out_px, (th, tw, 3), np.float32)
+        if normalized:
+            normalize(warped, arr)
+        else:
+            arr[...] = warped
+        if out is None:
+            mask = np.ones((th, tw), dtype=np.float32)
         orig_hw = (side, side)
     elif spec.mode == "shortest_edge":
         rh, rw = shortest_edge_size(orig_hw, spec.size[0], spec.size[1])
         resized = image.resize((rw, rh), resample=spec.resample)
         ph, pw = _canvas_for(spec, canvas_hw, (rh, rw))
+        arr = _slot(out_px, (ph, pw, 3), np.float32)
+        mask = _slot(mask, (ph, pw), np.float32)
         # Normalize BEFORE padding: pad pixels must be exactly 0 (the torch
         # DETR processor pads after normalization; checkpoints expect 0 pads).
-        arr = np.zeros((ph, pw, 3), dtype=np.float32)
-        arr[:rh, :rw] = rescale_normalize(np.asarray(resized, dtype=np.float32))
-        mask = np.zeros((ph, pw), dtype=np.float32)
+        # Only the pad margin is zeroed: the valid region is written once.
+        rescale_normalize(np.asarray(resized, dtype=np.float32), arr[:rh, :rw])
+        arr[rh:] = 0.0
+        arr[:rh, rw:] = 0.0
         mask[:rh, :rw] = 1.0
+        mask[rh:] = 0.0
+        mask[:rh, rw:] = 0.0
     else:
         raise ValueError(f"Unknown preprocess mode: {spec.mode}")
 
@@ -290,9 +326,12 @@ def device_preprocess_supported(spec: PreprocessSpec) -> bool:
 
 
 class DecodePool:
-    """Thread pool for host decode/resize (the only host work left under
-    device preprocess). PIL's resize and the numpy conversion release the
-    GIL, so threads scale until the memory bus does; workers default to
+    """Thread pool for the per-image host work of staging: decode/resize
+    (all that is left under device preprocess), and on the float path the
+    rescale and normalize, each task writing its image into its own row of
+    the batch's staging buffer (engine/staging.py). PIL's resize and the
+    numpy arithmetic release the GIL, so the tasks run side by side (5.3
+    wide on 13 shared cores, PERF.md section 5); workers default to
     SPOTTER_TPU_DECODE_WORKERS or a core-count heuristic. `queue_depth()`
     (submitted-but-unfinished items) feeds the /metrics gauge that shows
     when decode — not the device — is the binding constraint."""
@@ -341,6 +380,7 @@ def decode_resize_uint8(
     image: Image.Image,
     spec: PreprocessSpec,
     canvas_hw: tuple[int, int] | None = None,
+    out: np.ndarray | None = None,
 ) -> tuple[np.ndarray, tuple[int, int], tuple[int, int]]:
     """PIL image -> (uint8 (H, W, 3) in the static bucket, valid (h, w), orig (h, w)).
 
@@ -348,76 +388,29 @@ def decode_resize_uint8(
     filter and shortest-edge arithmetic as `preprocess_image` (golden parity
     depends on them) — rescale/normalize/mask move to the device.
     `canvas_hw` (ragged batching, ISSUE 9) shrinks the shortest_edge pad
-    target below the static bucket.
+    target below the static bucket. `out` (ISSUE 27): the canvas-shaped
+    destination the bytes are written to and that is returned, as in
+    `preprocess_image`.
     """
     check_image_pixels(image)
     orig_hw = (image.height, image.width)
     if spec.mode == "fixed":
         th, tw = spec.size
         resized = image.resize((tw, th), resample=spec.resample)
-        return np.asarray(resized, dtype=np.uint8), (th, tw), orig_hw
+        if out is None:
+            return np.asarray(resized, dtype=np.uint8), (th, tw), orig_hw
+        arr = _slot(out, (th, tw, 3), np.uint8)
+        arr[...] = np.asarray(resized, dtype=np.uint8)
+        return arr, (th, tw), orig_hw
     if spec.mode == "shortest_edge":
         rh, rw = shortest_edge_size(orig_hw, spec.size[0], spec.size[1])
         resized = image.resize((rw, rh), resample=spec.resample)
-        ph, pw = _canvas_for(spec, canvas_hw, (rh, rw))
-        arr = np.zeros((ph, pw, 3), dtype=np.uint8)
+        arr = _slot(out, (*_canvas_for(spec, canvas_hw, (rh, rw)), 3), np.uint8)
         arr[:rh, :rw] = np.asarray(resized, dtype=np.uint8)
+        arr[rh:] = 0
+        arr[:rh, rw:] = 0
         return arr, (rh, rw), orig_hw
     raise ValueError(f"device preprocess does not support mode: {spec.mode}")
-
-
-def batch_images_uint8(
-    images: list[Image.Image],
-    spec: PreprocessSpec,
-    pool: DecodePool | None = None,
-    canvas_hw: tuple[int, int] | None = None,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Stack uint8-decoded images -> (pixels (B,H,W,3) u8, valid (B,2) i32,
-    sizes (B,2) f32 [orig h,w])."""
-    decode = partial(decode_resize_uint8, spec=spec, canvas_hw=canvas_hw)
-    return stack_uint8(
-        pool.map(decode, images) if pool is not None else [
-            decode(img) for img in images
-        ]
-    )
-
-
-def stack_uint8(decoded: list) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The caller's half of `batch_images_uint8`: per-image results of
-    `decode_resize_uint8` -> the batch's arrays. Apart from the map so that
-    the engine can time the copy by itself (`engine.stack_pad`)."""
-    return (
-        np.stack([d[0] for d in decoded]),
-        np.asarray([d[1] for d in decoded], dtype=np.int32),
-        np.asarray([d[2] for d in decoded], dtype=np.float32),
-    )
-
-
-def batch_images_host(
-    images: list[Image.Image],
-    spec: PreprocessSpec,
-    pool: DecodePool | None = None,
-    canvas_hw: tuple[int, int] | None = None,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """`batch_images` through the DecodePool: same float output, parallel
-    per-image host preprocess (the host path keeps the pool win too)."""
-    process = partial(preprocess_image, spec=spec, canvas_hw=canvas_hw)
-    return stack_host(
-        pool.map(process, images) if pool is not None else [
-            process(img) for img in images
-        ]
-    )
-
-
-def stack_host(done: list) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The caller's half of `batch_images_host`: per-image results of
-    `preprocess_image` -> the batch's arrays (one thread copies every
-    image's float pixels and mask: 21.6 MB an image at 800x1344)."""
-    return (
-        np.stack([p for p, _, _ in done]),
-        np.stack([m for _, m, _ in done]),
-        np.asarray([hw for _, _, hw in done], dtype=np.float32),
-    )
 
 
 def device_rescale_normalize(pixels_u8, valid_hw, spec: PreprocessSpec):

@@ -11,6 +11,7 @@ DETR processor pads AFTER normalization). Runs the real jit on CPU.
 import numpy as np
 import pytest
 from PIL import Image
+from staging_reference import stack_reference
 
 from spotter_tpu.ops.preprocess import (
     DETR_SPEC,
@@ -19,11 +20,10 @@ from spotter_tpu.ops.preprocess import (
     DecodePool,
     PreprocessSpec,
     batch_images,
-    batch_images_host,
-    batch_images_uint8,
     decode_resize_uint8,
     device_preprocess_supported,
     device_rescale_normalize,
+    preprocess_image,
 )
 
 
@@ -35,7 +35,7 @@ def _img(h, w, seed=0):
 def _device_path(images, spec):
     import jax
 
-    pixels_u8, valid, sizes = batch_images_uint8(images, spec)
+    pixels_u8, valid, sizes = stack_reference(images, spec, uint8=True)
     fn = jax.jit(lambda p, v: device_rescale_normalize(p, v, spec))
     pixels, masks = fn(pixels_u8, valid)
     return np.asarray(pixels), np.asarray(masks), sizes
@@ -96,19 +96,31 @@ def test_pad_square_unsupported_and_raises():
         decode_resize_uint8(_img(32, 32), OWLV2_SPEC)
 
 
-def test_batch_images_host_matches_batch_images_with_pool():
-    """The pooled host path is the same numbers as the serial one."""
+def test_pooled_in_place_staging_matches_batch_images():
+    """Pool tasks that each write their own row of one array (how the
+    engine stages, ISSUE 27) give the same numbers as the serial loop."""
     images = [_img(40, 60, seed=s) for s in range(5)]
+    jobs = list(enumerate(images))
     pool = DecodePool(workers=4)
     try:
+        px = np.empty((5, 1333, 1333, 3), np.float32)
+        mask = np.empty((5, 1333, 1333), np.float32)
+        origs = pool.map(
+            lambda job: preprocess_image(
+                job[1], DETR_SPEC, out=(px[job[0]], mask[job[0]]))[2],
+            jobs,
+        )
         ref = batch_images(images, DETR_SPEC)
-        pooled = batch_images_host(images, DETR_SPEC, pool=pool)
-        for a, b in zip(ref, pooled):
+        for a, b in zip(ref, (px, mask, np.asarray(origs, np.float32))):
             np.testing.assert_array_equal(a, b)
-        u8_serial = batch_images_uint8(images, DETR_SPEC)
-        u8_pooled = batch_images_uint8(images, DETR_SPEC, pool=pool)
-        for a, b in zip(u8_serial, u8_pooled):
-            np.testing.assert_array_equal(a, b)
+        u8 = np.empty((5, 1333, 1333, 3), np.uint8)
+        valid = pool.map(
+            lambda job: decode_resize_uint8(job[1], DETR_SPEC, out=u8[job[0]])[1],
+            jobs,
+        )
+        u8_serial = stack_reference(images, DETR_SPEC, uint8=True)
+        np.testing.assert_array_equal(u8_serial[0], u8)
+        np.testing.assert_array_equal(u8_serial[1], np.asarray(valid, np.int32))
         assert pool.queue_depth() == 0  # backlog drains back to idle
     finally:
         pool.close()
@@ -132,6 +144,6 @@ def test_sizes_semantics_match_host():
     """target_sizes (original h, w) drive box rescale — identical either path."""
     images = [_img(123, 45, seed=7)]
     _, _, host_sizes = batch_images(images, RTDETR_SPEC)
-    _, _, dev_sizes = batch_images_uint8(images, RTDETR_SPEC)
+    _, _, dev_sizes = stack_reference(images, RTDETR_SPEC, uint8=True)
     np.testing.assert_array_equal(host_sizes, np.asarray([[123, 45]], np.float32))
     np.testing.assert_array_equal(dev_sizes.astype(np.float32), host_sizes)

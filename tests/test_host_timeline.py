@@ -14,7 +14,6 @@ import pytest
 from PIL import Image
 
 from spotter_tpu import obs
-from spotter_tpu.engine import engine as engine_mod
 from spotter_tpu.engine.engine import BuiltDetector, InferenceEngine
 from spotter_tpu.engine.metrics import Metrics, StarvationClock
 from spotter_tpu.obs import prom
@@ -201,22 +200,22 @@ def test_decode_and_h2d_children_tile_their_parents(fake_clock, monkeypatch):
     eng = _engine()
     eng.warmup()
     obs_trace.reset_host_spans()
-    pool_map, stack, put = eng._decode_pool.map, engine_mod.stack_host, eng._put
+    pool_map, fill, put = eng._decode_pool.map, eng._fill_pad_rows, eng._put
 
     def slow_map(fn, items):
         fake_clock.advance(3.0)
         return pool_map(fn, items)
 
-    def slow_stack(done):
+    def slow_fill(pixels, second, done):
         fake_clock.advance(0.5)
-        return stack(done)
+        return fill(pixels, second, done)
 
     def slow_put(arr):
         fake_clock.advance(0.25)
         return put(arr)
 
     monkeypatch.setattr(eng._decode_pool, "map", slow_map)
-    monkeypatch.setattr(engine_mod, "stack_host", slow_stack)
+    monkeypatch.setattr(eng, "_fill_pad_rows", slow_fill)
     monkeypatch.setattr(eng, "_put", slow_put)
     assert len(eng.detect(_imgs(3))) == 3
     ms = {name: row["wall_ms"] for name, row in obs.host_spans_snapshot().items()}

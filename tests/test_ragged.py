@@ -21,6 +21,7 @@ import time
 import numpy as np
 import pytest
 from PIL import Image
+from staging_reference import stack_reference
 
 os.environ["SPOTTER_TPU_TINY"] = "1"
 
@@ -29,7 +30,6 @@ from spotter_tpu.engine.metrics import Metrics
 from spotter_tpu.engine.scheduler import QueueItem, Scheduler
 from spotter_tpu.ops.preprocess import (
     PreprocessSpec,
-    batch_images_uint8,
     decode_resize_uint8,
     ragged_canvas_supported,
     shortest_edge_size,
@@ -165,7 +165,7 @@ def test_masked_region_invariance(detr_engine):
     in-jit mask zeroes them before the backbone sees anything)."""
     spec = detr_engine.built.preprocess_spec
     imgs = [_img(80, 60, seed=1), _img(40, 64, seed=2)]
-    pixels, valid, sizes = batch_images_uint8(imgs, spec)
+    pixels, valid, sizes = stack_reference(imgs, spec, uint8=True)
     garbage = pixels.copy()
     for j, img in enumerate(imgs):
         rh, rw = decode_resize_uint8(img, spec)[1]
