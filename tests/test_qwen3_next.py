@@ -16,6 +16,7 @@ seen (1e-5), and a wrong decay, mask, expert or weight reads 1e-2 and more.
 
 import asyncio
 import dataclasses
+import functools
 import os
 
 import jax
@@ -172,30 +173,100 @@ def test_delta_rule_kernel_in_interpret_mode_is_the_scan():
     np.testing.assert_allclose(kernel, scan, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", ["einsum", "pallas"])
-def test_routed_experts_never_drop_a_token(impl):
-    """Windows smaller than the routed rows, a skewed router (most tokens on
-    one expert), both implementations: every held assignment is computed."""
-    rng = np.random.default_rng(2)
-    m, d, inter, k = 90, 32, 16, 3
+def _per_token_loop(x, weights, experts, gate_up, down, offset):
+    """What `routed_experts` must give: each token's held choices, one by one."""
+    n_local, inter = down.shape[:2]
+    want = np.zeros(x.shape, np.float32)
+    for t in range(x.shape[0]):
+        for j in range(experts.shape[1]):
+            e = int(experts[t, j]) - offset
+            if 0 <= e < n_local:
+                h = x[t] @ gate_up[e]
+                want[t] += float(weights[t, j]) * ((h[:inter] / (1 + np.exp(-h[:inter])) * h[inter:]) @ down[e])
+    return want
+
+
+def _expert_layer(d, m=90, inter=16, seed=2):
+    rng = np.random.default_rng(seed)
+    wider = np.sqrt(d / 32)  # keeps the logits and the hidden values at the scale they have at 32
     x = rng.standard_normal((m, d)).astype(np.float32)
-    router = rng.standard_normal((d, 8)).astype(np.float32)
-    router[:, 5] += 3 * np.sign(router[:, 5])  # expert 5 draws a crowd
-    gate_up = rng.standard_normal((4, d, 2 * inter)).astype(np.float32) / 6
+    router = rng.standard_normal((d, 8)).astype(np.float32) / wider
+    router[:, 5] += 3 * np.sign(router[:, 5]) / wider  # expert 5 draws a crowd
+    gate_up = rng.standard_normal((4, d, 2 * inter)).astype(np.float32) / (6 * wider)
     down = rng.standard_normal((4, inter, d)).astype(np.float32) / 4
+    return x, router, gate_up, down
+
+
+# 32: the sums stay (tokens, d); 256: they are carried as (tokens, 2, 128)
+@pytest.mark.parametrize("d", [32, 256])
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_routed_experts_never_drop_a_token(impl, d):
+    """Windows smaller than the routed rows, a skewed router (most tokens on
+    one expert), both implementations, both layouts of the sums: every held
+    assignment is computed."""
+    k = 3
+    x, router, gate_up, down = _expert_layer(d)
     weights, experts = moe.route(x, router, k)
     got = moe.routed_experts(x, weights, experts, gate_up, down, offset=4, tile=8,
                              window_rows=32, impl=impl, interpret=True)
-    want = np.zeros((m, d), np.float32)
-    for t in range(m):
-        for j in range(k):
-            e = int(experts[t, j]) - 4
-            if 0 <= e < 4:
-                h = x[t] @ gate_up[e]
-                want[t] += float(weights[t, j]) * ((h[:inter] / (1 + np.exp(-h[:inter])) * h[inter:]) @ down[e])
-    np.testing.assert_allclose(got, want, atol=1e-4)
+    assert got.shape == x.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, _per_token_loop(x, weights, experts, gate_up, down, 4), atol=1e-4)
     counts = moe.held_tokens(experts.reshape(1, -1), 4, 4)
     assert int(counts.sum()) == int(((experts >= 4) & (experts < 8)).sum())
+
+
+@pytest.mark.parametrize("case", ["one_window", "partly_dead_last_window", "same_held_experts"])
+def test_tiled_sums_add_every_row_once(case):
+    """The tiled width (256) against the per-token loop: one window that holds
+    every padded row; many windows, the last partly dead; and tokens that all
+    choose the same held experts, so that one expert's rows span several
+    windows and every token is added to k times."""
+    k, tile, offset = 3, 8, 4
+    x, router, gate_up, down = _expert_layer(256)
+    m = x.shape[0]
+    weights, experts = moe.route(x, router, k)
+    window_rows = {"one_window": 4096, "partly_dead_last_window": 40, "same_held_experts": 64}[case]
+    if case == "same_held_experts":
+        experts = jnp.broadcast_to(jnp.array([6, 4, 7], jnp.int32), (m, k))
+    got = moe.routed_experts(x, weights, experts, gate_up, down, offset=offset, tile=tile,
+                             window_rows=window_rows, impl="einsum")
+    held = np.asarray(moe.held_tokens(experts.reshape(1, -1), offset, 4))[0]
+    padded = int((-(-held // tile) * tile).sum())
+    if case == "one_window":
+        assert padded <= window_rows
+    elif case == "partly_dead_last_window":
+        assert padded > 3 * window_rows and padded % window_rows  # several windows, a dead tail
+    else:
+        assert held.tolist() == [m, 0, m, m] and m > window_rows  # an expert spans windows
+    np.testing.assert_allclose(
+        got, _per_token_loop(x, weights, experts, gate_up, down, offset), atol=1e-4)
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize("d, row", [(2048, (16, 128)), (32, (32,))])
+def test_the_jitted_layer_carries_its_sums_in_the_layout_of_the_width(d, row):
+    """The layout is a matter of device time alone, so no CPU test of values
+    sees it go: read it from the jaxpr, traced with abstract inputs. At the
+    published width the window loop's float32 carry and the scatter-add's
+    operand are (tokens, 16, 128); at 32 they are (tokens, 32)."""
+    m, k, n_local, inter = 512, 10, 8, 64
+    args = (jax.ShapeDtypeStruct((m, d), jnp.bfloat16), jax.ShapeDtypeStruct((m, k), jnp.float32),
+            jax.ShapeDtypeStruct((m, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_local, d, 2 * inter), jnp.bfloat16),
+            jax.ShapeDtypeStruct((n_local, inter, d), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(functools.partial(moe.routed_experts, impl="einsum"))(*args)
+    (loop,) = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "while"]
+    carries = [v.aval.shape for v in loop.outvars if v.aval.dtype == jnp.float32]
+    operands = [eqn.invars[0].aval.shape for eqn in _all_eqns(loop.params["body_jaxpr"].jaxpr)
+                if eqn.primitive.name == "scatter-add"]
+    assert carries == [(m, *row)] and operands == [(m, *row)]
+    assert jaxpr.out_avals[0].shape == (m, d) and jaxpr.out_avals[0].dtype == jnp.float32
 
 
 def test_causal_gqa_kernel_in_interpret_mode():
