@@ -5,8 +5,8 @@ closed-set serving path never pays: at ViT-L scale one vocabulary encode is
 tens of milliseconds of device time. Vocabularies repeat heavily (a tenant
 reuses its label set on every image), so the resolver memoizes encoded query
 sets keyed `model|sha256(sorted queries)` (caching/keys.py) — a repeated
-vocabulary costs one dict lookup, and the bench's text-cache hit p50 vs miss
-p50 is the measured proof.
+vocabulary costs one dict lookup and no encode
+(`tests/test_openvocab.py::test_resolver_caches_and_pads`).
 
 The cached value is a `QuerySet`: labels in canonical (sorted) order, the
 normalized (Q_pad, proj) embedding matrix PADDED to a bucketed query count
@@ -71,7 +71,7 @@ class TextQueryResolver:
 
     `encoder` is `BuiltDetector.text_encoder` (list[str] -> (Q, proj)
     float32). `metrics` (engine Metrics) gets hit/miss counts and encode
-    wall times so the cache's win is observable in /metrics and the bench.
+    wall times so the cache's win is observable in /metrics.
     """
 
     def __init__(

@@ -63,6 +63,9 @@ from spotter_tpu.serving.overload import SLO
 RAGGED_ENV = "SPOTTER_TPU_RAGGED"
 RAGGED_STEP_ENV = "SPOTTER_TPU_RAGGED_STEP"
 DEFAULT_RAGGED_STEP = 128
+# the coarsest feature level of the shortest_edge families: a ResNet's
+# total stride is 32, and Deformable DETR adds a stride-2 level on top
+COARSEST_FEATURE_STRIDE = 64
 RAGGED_URGENT_MS_ENV = "SPOTTER_TPU_RAGGED_URGENT_MS"
 DEFAULT_RAGGED_URGENT_MS = 100.0
 
@@ -77,12 +80,34 @@ def ragged_enabled() -> bool:
 
 
 def ragged_step() -> int:
+    """The canvas grid of a deployment. It has to be a multiple of
+    `COARSEST_FEATURE_STRIDE`: the models take a token's validity from the
+    pixel mask by nearest sampling (`models/detr.py:
+    nearest_downsample_mask`, index `j * canvas // tokens`), so only on a
+    canvas that is a whole number of the coarsest level's strides does
+    token `j` read pixel `j * stride` whatever the canvas. Canvases on the
+    grid then give an image the same valid tokens, and so does the full
+    bucket where it is on the grid itself; on any other canvas a token at
+    the valid region's edge turns valid or invalid with the canvas, and the
+    same image answers differently by which batch it rode in: 25 px and
+    flipped labels where a canvas on the grid differs by rounding
+    (`tests/test_ragged.py::
+    test_ragged_canvas_parity_vs_per_bucket_reference`). DETR's published
+    bucket of 1333 is not on the grid (42 tokens, sampled every 31.7
+    pixels), so there the full bucket's own token edges lie up to 10
+    pixels from a sub-canvas's: ROADMAP R3."""
     raw = os.environ.get(RAGGED_STEP_ENV, "").strip()
     try:
         step = int(raw) if raw else DEFAULT_RAGGED_STEP
     except ValueError:
         raise ValueError(f"{RAGGED_STEP_ENV} must be an integer, got {raw!r}")
-    return max(1, step)
+    if step < 1 or step % COARSEST_FEATURE_STRIDE:
+        raise ValueError(
+            f"{RAGGED_STEP_ENV} must be a positive multiple of "
+            f"{COARSEST_FEATURE_STRIDE} (the coarsest feature level's "
+            f"stride), got {step}"
+        )
+    return step
 
 
 @dataclass
@@ -125,7 +150,7 @@ class QueueItem:
 class PackPlan:
     """One dispatch: the packed items, the padded canvas they stage into
     (None = the spec's static bucket, i.e. the pre-ragged behavior), and
-    the pack's padded-pixel waste for /metrics + bench."""
+    the pack's padded-pixel waste for /metrics."""
 
     items: list[QueueItem]
     canvas_hw: Optional[tuple[int, int]] = None
@@ -158,7 +183,7 @@ class Scheduler:
         self.urgent_ms = urgent_ms
         # only shortest_edge specs have a variable valid region; a spec-less
         # engine (stub/synthetic: no `.built`) is treated as fully ragged —
-        # its canvas is the items' own dims (the bench calibration case)
+        # its canvas is the items' own dims (the tests' recording engines)
         self.canvas_capable = spec is None or getattr(spec, "mode", None) == (
             "shortest_edge"
         )

@@ -33,7 +33,7 @@ paths import through `spotter_tpu.obs`):
 Everything is NaN-free by construction: an idle replica reports 0.0 for
 every rate/percentage gauge (acceptance: zero-traffic snapshots must be
 well-formed), and `SPOTTER_TPU_PERF_LEDGER=0` turns every record call
-into a no-op for the overhead A/B (`bench.py --perf-overhead`).
+into a no-op (`tests/test_perf.py::test_perf_ledger_disabled_is_noop`).
 """
 
 import logging

@@ -21,9 +21,9 @@ serving process hands the annotation factory in (`set_annotator`), so the
 supervisor never imports jax for it.
 
 Stage-name vocabulary: `STAGES` is the ONE list of stage names shared by
-trace spans, the Metrics stage histograms, and bench.py's per-stage JSON
-(ISSUE 7 satellite — `/metrics` said `preprocess` where bench said
-`staging` and neither matched the decode+h2d split from PR 3).
+trace spans and the Metrics stage histograms (ISSUE 7 satellite —
+`/metrics` said `preprocess` where another report said `staging` and
+neither matched the decode+h2d split from PR 3).
 """
 
 import contextvars
@@ -35,7 +35,7 @@ import time
 
 from spotter_tpu.testing import faults
 
-# ---- stage vocabulary (one list, used by spans, Metrics, and bench) ----
+# ---- stage vocabulary (one list, used by spans and Metrics) ----
 
 ROUTE = "route"          # edge hop: pool pick + router overhead
 FETCH = "fetch"          # detector: URL fetch (single-flight wait included)

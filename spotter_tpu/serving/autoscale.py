@@ -671,7 +671,7 @@ class AutoscalerBrain:
 
     def chips_desired(self) -> int:
         """Chip budget implied by current targets (tp×dp per member) — the
-        capacity-vs-static accounting `bench.py --multi-model` records."""
+        capacity a statically sized fleet is compared with."""
         return sum(
             self.controller.pools[name].spec.target_size
             * pool.chips_per_member

@@ -31,8 +31,8 @@ arXiv:2501.14417, is the blueprint for the serverless half):
   `SPOTTER_TPU_SCALE_TO_ZERO_S` drains and stops all members; the next
   classed request triggers a demand restore through the persistent compile
   cache (`lifecycle.compile_cache_dir()`), with `time_to_ready_s` measured
-  restore-trigger -> first member available and published in /metrics —
-  the <15 s (stubbed) gate `bench.py --preemption-storm` records.
+  restore-trigger -> first member available and published in /metrics
+  (`tests/test_fleet.py::test_scale_to_zero_and_demand_restore`).
 
 `make_fleet_app` is the HTTP surface (/detect with classification,
 /healthz, /livez, /metrics with `pool_size{pool,state}`,
@@ -547,8 +547,9 @@ class FleetController:
     def _apply_storm(self) -> None:
         """Injected preemption storm (SPOTTER_TPU_FAULTS=preempt_storm=N or
         faults.inject in-process): preempt up to N currently-available spot
-        members through their handles — the chaos entry point for
-        `bench.py --preemption-storm`."""
+        members through their handles — the chaos entry point of
+        `tests/test_fleet.py::
+        test_storm_drains_only_marked_member_slo_untouched`."""
         spot = self.pools.get(SPOT)
         now = time.monotonic()
         candidates = []

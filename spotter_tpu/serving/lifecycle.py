@@ -11,7 +11,7 @@ serverless serving viable). Three pieces:
   the `/startupz` endpoint, so a k8s startupProbe can distinguish "still
   compiling the bucket ladder" from "dead" and not kill a long warmup.
   `mark_ready()` records `time_to_ready_s` into the engine metrics — the
-  number `bench.py --failover` and warm-restart work optimize.
+  number warm-restart work optimizes (the benchmark's `setup_s` holds it).
 - `PreemptionWatcher`: SIGTERM plus an env-configured maintenance-event
   source (`SPOTTER_TPU_PREEMPTION_FILE`: a path whose appearance signals the
   event — fault-injectable from tests and chaos staging;

@@ -39,7 +39,8 @@ This module splits intent from actuation:
   scenario.
 
 `python -m spotter_tpu.serving.reconcile` is the standalone controller
-process `bench.py --controller-crash` kills and restarts: it stands by on
+process the controller chaos drills (`tests/test_reconcile.py::
+test_controller_chaos_row`) kill and restart: it stands by on
 the lease, loads-or-rebuilds the journal, adopts orphans, runs the fleet
 tick + reconcile loop + (resumable) rollout, and writes an atomic status
 JSON each tick for the drill to parse.

@@ -68,7 +68,8 @@ Membership is dynamic (`add_endpoint` / `remove_endpoint`): the fleet
 controller (serving/fleet.py) grows and shrinks pools as spot capacity
 churns and idle tiers scale to zero.
 
-`bench.py --failover` and `bench.py --gray-storm` drive this pool;
+`tests/test_failover.py` and the gray-failure matrix
+(`tests/test_grayfail.py`) drive this pool;
 `python -m spotter_tpu.serving.router` runs it as a tiny edge router.
 Counters surface in `snapshot()` (and the router's /metrics): ejections,
 soft ejections/restores, replays, hedges (+ budget exhaustions and loser

@@ -36,8 +36,8 @@ operation; this module is that operation for the spotter fleet:
   (`/debug/traces`, request id `rollout-rollback-*`) and bumps
   `rollouts_total{verdict="rolled_back"}`; zero client-visible failures
   is the contract the deployment chaos drills
-  (`testing/chaos_matrix.py::DEPLOY_MATRIX`, `bench.py --rollout-drill`)
-  enforce.
+  (`testing/chaos_matrix.py::DEPLOY_MATRIX`, run by
+  `tests/test_rollout.py`) enforce.
 - **Shadow lane**: with `SPOTTER_TPU_SHADOW_PCT` > 0 the router mirrors a
   deterministically-sampled share of live requests to the canary
   (fire-and-forget, responses DISCARDED — never client-visible) and

@@ -40,7 +40,7 @@ NOT hot-looping when the server crashes at import time. Policy:
 
 Each (re)start exports `SPOTTER_TPU_RESTARTS=<n>` to the child so
 `restarts_total` lands in the replica's /metrics, and rewrites `--pidfile`
-so harnesses (tests, bench.py --failover) can target the CURRENT child with
+so harnesses (`tests/test_failover.py`) can target the CURRENT child with
 preemption faults. SIGTERM to the supervisor forwards to the child and
 exits with the child's code — the pod-level preStop path stays intact.
 

@@ -13,10 +13,8 @@ fault thinning, counter-armed corruptions, a fixed URL cycle — so a
 scenario's invariants are exact assertions, not flaky thresholds.
 
 `GRAY_MATRIX` is the default matrix; `tests/test_grayfail.py` runs every
-row, and `bench.py --gray-storm` is the measured (timed, gated) sibling of
-the `gray-slow` row. Scenarios are cheap (~a second each): the point is
-that adding a new gray-failure shape is one dataclass literal, not a new
-harness.
+row. Scenarios are cheap (~a second each): the point is that adding a new
+gray-failure shape is one dataclass literal, not a new harness.
 
 ISSUE 15 adds the DEPLOYMENT half: `DeployScenario`/`DEPLOY_MATRIX` run a
 full versioned rollout (serving/rollout.py) over the same in-process
@@ -27,8 +25,7 @@ frames scoped to the canary via `faults.only_replica` / different
 detections for the shadow lane), live load the whole time. Bad deploys
 must AUTO-ROLLBACK with zero client-visible failures and a pinned
 flight-recorder trace; the good deploy must roll every member to v2 with
-zero failures. `tests/test_rollout.py` runs every row and
-`bench.py --rollout-drill` is the measured sibling.
+zero failures. `tests/test_rollout.py` runs every row.
 """
 
 import asyncio
@@ -264,16 +261,6 @@ def evaluate(sc: Scenario, report: dict) -> dict:
         else:
             raise ValueError(f"unknown invariant {key!r} in {sc.name}")
     return checks
-
-
-def run_matrix(scenarios: list[Scenario] | None = None) -> list[dict]:
-    """Run every scenario (fresh event loop each — total isolation);
-    returns the reports. Callers assert `all(r["ok"] for r in reports)`
-    and print the failing report for diagnosis."""
-    reports = []
-    for sc in scenarios if scenarios is not None else GRAY_MATRIX:
-        reports.append(asyncio.run(run_scenario(sc)))
-    return reports
 
 
 # ---------------------------------------------------------------------------
@@ -591,17 +578,6 @@ def evaluate_deploy(sc: DeployScenario, report: dict) -> dict:
     return checks
 
 
-def run_deploy_matrix(
-    scenarios: list[DeployScenario] | None = None,
-) -> list[dict]:
-    """Run every deployment drill (fresh event loop each); returns the
-    reports — same contract as `run_matrix`."""
-    reports = []
-    for sc in scenarios if scenarios is not None else DEPLOY_MATRIX:
-        reports.append(asyncio.run(run_deploy_scenario(sc)))
-    return reports
-
-
 # ---------------------------------------------------------------------------
 # controller chaos drills (ISSUE 16)
 
@@ -857,20 +833,9 @@ def _teardown_members(manifest_path: str) -> None:
                 pass
 
 
-def run_controller_scenario(
-    sc: ControllerScenario,
-    workdir: str,
-    on_ready=None,
-    on_converged=None,
-) -> dict:
+def run_controller_scenario(sc: ControllerScenario, workdir: str) -> dict:
     """Execute one controller chaos drill in `workdir`; returns the
-    report dict (see `evaluate_controller`).
-
-    `on_ready` fires once the harness serve members answer /startupz
-    (before the first controller starts); `on_converged` fires when the
-    scenario reaches its verdict (success or convergence timeout), BEFORE
-    teardown — the window where bench.py keeps client load flowing, so
-    teardown's deliberate mass-SIGTERM never counts as client failures."""
+    report dict (see `evaluate_controller`)."""
     import os as _os
     import time as _time
 
@@ -913,9 +878,6 @@ def run_controller_scenario(
             serve_members.append(spawn_v1())
         for m in serve_members:
             cluster.wait_ready(m.url)
-        if on_ready is not None:
-            on_ready()
-
         a = ControllerProc(sc_dir, state_dir, manifest_path, "ctrl-a",
                            ctl_args, faults_spec=sc.faults)
         controllers.append(a)
@@ -1014,8 +976,6 @@ def run_controller_scenario(
             for e in manifest.entries().values()
             if e.get("pool") == "serve" and _supervisor_alive(e)
         )
-        if on_converged is not None:
-            on_converged()
     except TimeoutError as exc:
         report["converged"] = False
         report["error"] = str(exc)
@@ -1023,8 +983,6 @@ def run_controller_scenario(
         report.setdefault("successor", controllers[-1].status()
                           if controllers else {})
         report.setdefault("serve_versions", [])
-        if on_converged is not None:
-            on_converged()
     finally:
         for ctl in controllers:
             ctl.shutdown()
@@ -1087,17 +1045,6 @@ def evaluate_controller(sc: ControllerScenario, report: dict) -> dict:
         else:
             raise ValueError(f"unknown invariant {key!r} in {sc.name}")
     return checks
-
-
-def run_controller_matrix(
-    workdir: str, scenarios: list[ControllerScenario] | None = None,
-) -> list[dict]:
-    """Run every controller chaos drill; returns the reports — same
-    contract as `run_matrix`."""
-    reports = []
-    for sc in scenarios if scenarios is not None else CONTROLLER_MATRIX:
-        reports.append(run_controller_scenario(sc, workdir))
-    return reports
 
 
 # ---------------------------------------------------------------------------
@@ -1392,17 +1339,6 @@ def evaluate_integrity(sc: IntegrityScenario, report: dict) -> dict:
     return checks
 
 
-def run_integrity_matrix(
-    scenarios: list[IntegrityScenario] | None = None,
-) -> list[dict]:
-    """Run every integrity drill (fresh event loop each); returns the
-    reports — same contract as `run_matrix`."""
-    reports = []
-    for sc in scenarios if scenarios is not None else INTEGRITY_MATRIX:
-        reports.append(asyncio.run(run_integrity_scenario(sc)))
-    return reports
-
-
 # ---------------------------------------------------------------------------
 # tenant-isolation tier (ISSUE 19): noisy-neighbor drills over the real
 # router edge with the TenantPlane armed
@@ -1690,17 +1626,6 @@ def evaluate_tenant(sc: TenantScenario, report: dict) -> dict:
     return checks
 
 
-def run_tenant_matrix(
-    scenarios: list[TenantScenario] | None = None,
-) -> list[dict]:
-    """Run every noisy-neighbor drill (fresh event loop each); returns
-    the reports — same contract as `run_matrix`."""
-    reports = []
-    for sc in scenarios if scenarios is not None else TENANT_MATRIX:
-        reports.append(asyncio.run(run_tenant_scenario(sc)))
-    return reports
-
-
 # ---------------------------------------------------------------------------
 # model-multiplexed autoscaling drills (ISSUE 20): per-model pools behind
 # the real fleet edge, sized by the AutoscalerBrain under scripted demand
@@ -1851,9 +1776,6 @@ SCALE_MATRIX = [
         },
     ),
 ]
-
-SCALE_MATRIX_FAST = [sc for sc in SCALE_MATRIX if not sc.crash]
-
 
 class _ScaleMember:
     """In-process managed member for the scale drills: a real aiohttp
@@ -2361,19 +2283,3 @@ def evaluate_scale(sc: ScaleScenario, report: dict) -> dict:
     return checks
 
 
-def run_scale_matrix(
-    scenarios: list[ScaleScenario] | None = None,
-    workdir: str | None = None,
-) -> list[dict]:
-    """Run every autoscaling drill (fresh event loop per in-process row);
-    returns the reports — same contract as `run_matrix`. Crash rows need
-    `workdir` for their controller subprocesses."""
-    reports = []
-    for sc in scenarios if scenarios is not None else SCALE_MATRIX:
-        if sc.crash:
-            if workdir is None:
-                raise ValueError(f"{sc.name} needs workdir for subprocesses")
-            reports.append(run_scale_crash_scenario(sc, workdir))
-        else:
-            reports.append(asyncio.run(run_scale_scenario(sc)))
-    return reports

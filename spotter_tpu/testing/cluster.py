@@ -1,7 +1,7 @@
 """Local multi-replica harness: supervised stub replicas as subprocesses.
 
-The failover acceptance test (tests/test_failover.py) and
-`bench.py --failover` need the same fixture: N REAL server processes (the
+The failover acceptance tests (tests/test_failover.py) and the controller
+drills need the same fixture: N REAL server processes (the
 standalone aiohttp runtime, stub engine, full lifecycle surface) each under
 the REAL supervisor, on localhost ports, killable mid-load — the CPU
 stand-in for a spot TPU fleet losing a host. This module is that fixture.

@@ -52,8 +52,8 @@ whole value on its next supervision tick via `take_preempt_storm()` and
 preempts N currently-ready spot members at once through their handles
 (maintenance file -> drain -> exit 83 -> supervisor restart). This is the
 normal failure mode of spot TPU capacity — a maintenance wave, not an
-independent crash — and the scenario `bench.py --preemption-storm` and the
-fleet chaos tests measure.
+independent crash — and the scenario the fleet chaos tests
+(`tests/test_fleet.py`) run.
 
 The overload tier (ISSUE 8) adds `overload_spike=N`: the adaptive
 admission limiter (serving/overload.py) consumes one per CONTROL TICK via
@@ -63,7 +63,7 @@ to its floor and (sustained past the arm window) walk the brownout ladder,
 all without generating real queue pressure.
 
 The gray-failure tier (ISSUE 14) adds the three injections the chaos
-matrix (testing/chaos_matrix.py) and `bench.py --gray-storm` compose:
+matrix (testing/chaos_matrix.py, run by `tests/test_grayfail.py`) composes:
 
 - `slow_replica=<ms>`: every engine call in THIS process sleeps that long
   first — a replica that still answers /healthz 200 but serves everything
@@ -94,7 +94,7 @@ chaos matrix (CONTROLLER_MATRIX) composes:
   rebuild-from-observation path instead of replaying damaged intent.
 
 The output-integrity tier (ISSUE 17) adds the three silent-data-corruption
-shapes the INTEGRITY_MATRIX and `bench.py --integrity-drill` compose:
+shapes the INTEGRITY_MATRIX (`tests/test_integrity.py`) composes:
 
 - `sdc=<pct>`: that percentage of this replica's engine answers get a
   deterministic "plausible garbage" perturbation (`corrupt_detections` at
@@ -113,7 +113,7 @@ shapes the INTEGRITY_MATRIX and `bench.py --integrity-drill` compose:
   quarantines the suspect compile-cache dir before the cold restart).
 
 The tenant-isolation tier (ISSUE 19) adds the two noisy-neighbor shapes
-the TENANT_MATRIX and `bench.py --tenant-storm` compose. Unlike the other
+the TENANT_MATRIX (`tests/test_tenancy.py`) composes. Unlike the other
 tiers these don't fire inside the serving path — they parameterize the
 drill's LOAD GENERATOR (the abusive client is the fault, not the server):
 

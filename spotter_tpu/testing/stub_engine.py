@@ -205,9 +205,7 @@ class StubEngine:
         )
         # Device-efficiency ledger (ISSUE 10): the stub's "device" window
         # is its service sleep; no FLOPs (no compiled program), so MFU
-        # stays 0 while duty-cycle and the top-dispatch table are real —
-        # and `bench.py --perf-overhead` measures the ledger's true cost
-        # on the hot path.
+        # stays 0 while duty-cycle and the top-dispatch table are real.
         self.metrics.perf.record_dispatch(
             device_s=device.seconds,
             batch=n,

@@ -95,8 +95,8 @@ INT8_ATTN = os.environ.get("SPOTTER_TPU_INT8_ATTN", "0").strip() != "0"
 # head_dim floor: QK^T contracts over head_dim, and a head_dim below ~32
 # lanes leaves the MXU contraction too shallow for the quantize/dequant
 # passes to pay off. 32 (not INT8_MIN_CH's 64) so the RT-DETR decoder's
-# 32-dim heads participate by default; `bench.py --int8-ablation` exists to
-# set this floor from data per deployment.
+# 32-dim heads participate by default. No reading on this chip sets it
+# (ROADMAP S8, D5).
 INT8_ATTN_MIN_HD = int(os.environ.get("SPOTTER_TPU_INT8_ATTN_MIN_HD", "32"))
 
 

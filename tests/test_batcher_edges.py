@@ -14,6 +14,7 @@ from spotter_tpu.engine.metrics import Metrics
 from spotter_tpu.serving.resilience import (
     CircuitBreaker,
     Deadline,
+    DeadlineExceededError,
     DrainingError,
     QueueFullError,
 )
@@ -150,8 +151,6 @@ def test_pump_skips_deadline_expired_entries():
     async def run():
         r1 = asyncio.create_task(batcher.submit(_img()))
         await asyncio.sleep(0.1)  # r1 wedged in engine
-        from spotter_tpu.serving.resilience import DeadlineExceededError
-
         with pytest.raises(DeadlineExceededError):
             await batcher.submit(_img(), deadline=Deadline.after(0.1))
         engine.release.set()
@@ -252,3 +251,55 @@ def test_splits_budget_bounds_isolation_depth():
     snap = engine.metrics.snapshot()
     assert snap["poison_isolated_total"] == 0
     assert snap["batch_retries_total"] == 1
+
+
+@pytest.mark.parametrize("deadline_s", [None, 0.05], ids=["no-deadline", "tight-deadline"])
+def test_burst_at_four_times_capacity_accounts_for_every_request(deadline_s):
+    """A burst of four times what the batcher can hold (queue + the pump's
+    hand + the engine), offered in one tick while the engine is wedged:
+    every request ends as accepted, shed or expired and none is lost; the
+    shed counter reads what the clients saw; and a request that expired in
+    the queue costs no device call."""
+    engine = BlockingEngine()
+    max_batch, max_queue = 2, 4
+    batcher = _batcher(
+        engine, max_batch=max_batch, max_in_flight=1, max_queue=max_queue
+    )
+    holds = max_queue + 2 * max_batch  # queue + pump's hand + in the engine
+    offered = 4 * holds
+
+    async def run():
+        async def one():
+            deadline = None if deadline_s is None else Deadline.after(deadline_s)
+            try:
+                return await batcher.submit(_img(), deadline=deadline)
+            except QueueFullError:
+                return "shed"
+            except DeadlineExceededError:
+                return "expired"
+
+        tasks = [asyncio.create_task(one()) for _ in range(offered)]
+        # past the tight deadline, with the engine still wedged
+        await asyncio.sleep(0.2)
+        engine.release.set()
+        results = await asyncio.gather(*tasks)
+        await batcher.stop()
+        return results
+
+    results = asyncio.run(run())
+    shed = results.count("shed")
+    expired = results.count("expired")
+    accepted = [r for r in results if r == DETS]
+    assert len(accepted) + shed + expired == offered
+    assert shed >= offered - holds  # nothing past capacity was let in
+    snap = engine.metrics.snapshot()
+    assert snap["shed_total"] == shed
+    assert snap["deadline_exceeded_total"] == expired
+    if deadline_s is None:
+        assert expired == 0 and 0 < len(accepted) <= holds
+        assert sum(engine.calls) == len(accepted)
+    else:
+        # whoever waited behind the wedged engine gave up and was skipped:
+        # the one batch that was in the engine already is all it ever saw
+        assert expired == offered - shed and not accepted
+        assert engine.calls == [max_batch]

@@ -555,3 +555,52 @@ def test_singleflight_waiter_cancellation_keeps_flight_alive():
         assert done.is_set()
 
     asyncio.run(run())
+
+
+# --- the duplicate-heavy workload, as counts ----------------------------------
+
+
+@pytest.mark.parametrize("cached", [True, False], ids=["cache-on", "cache-off"])
+def test_zipf_duplicates_cost_one_engine_image_per_distinct_picture(cached):
+    """Sixty requests over twelve pictures, Zipf-skewed from a seed, eight
+    at a time: with the tier on the engine is handed each distinct picture
+    once and every other request is a hit or rides a flight already in the
+    air; with it off the engine is handed all sixty."""
+    rng = np.random.default_rng(5)
+    weights = 1.0 / np.arange(1, 13) ** 1.1
+    picks = rng.choice(12, size=60, p=weights / weights.sum())
+    urls = [f"http://cdn/listing-{i}.jpg" for i in picks]
+    distinct = len(set(urls))
+    assert distinct < 30  # duplicate-heavy: over half the requests repeat
+
+    engine = FakeEngine(service_s=0.002)
+    client = CountingClient(
+        latency_s=0.001,
+        content_for=lambda url: _jpeg(int(url.rsplit("-", 1)[1][:-4]) * 20),
+    )
+    det = _detector(engine, client, cache=_cache(engine) if cached else None)
+
+    async def run():
+        sem = asyncio.Semaphore(8)
+
+        async def one(url):
+            async with sem:
+                resp = await det.detect({"image_urls": [url]})
+                assert isinstance(resp.images[0].detections, list)
+
+        await asyncio.gather(*(one(u) for u in urls))
+        await det.aclose()
+
+    asyncio.run(run())
+    snap = engine.metrics.snapshot()
+    if cached:
+        assert sum(engine.calls) == distinct
+        assert (
+            snap["cache_hits_total"] + snap["coalesced_submits_total"]
+            == len(urls) - distinct
+        )
+        assert snap["cache_entries"] == distinct
+    else:
+        assert sum(engine.calls) == len(urls)
+        assert sum(client.fetches.values()) == len(urls)
+        assert "cache_hits_total" not in snap or snap["cache_hits_total"] == 0

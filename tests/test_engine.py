@@ -101,6 +101,29 @@ def test_device_preprocess_matches_host_path(engine):
     assert dev_bpi > 0 and host_bpi / dev_bpi >= 3.5
 
 
+@pytest.mark.parametrize(
+    "device_preprocess, bytes_a_pixel, bytes_a_slot",
+    [(False, 16, 8), (True, 3, 16)],
+    ids=["host-float", "device-uint8"],
+)
+def test_a_full_bucket_ships_its_ingest_paths_bytes_a_pixel(
+    engine, device_preprocess, bytes_a_pixel, bytes_a_slot
+):
+    """What crosses to the device for a full bucket, counted: the host path
+    ships float32 pixels and a float32 mask (12 + 4 B a pixel) and the
+    sizes; the uint8 ingest ships the pixels as decoded (3 B a pixel), the
+    valid region and the sizes."""
+    eng = InferenceEngine(
+        engine.built, threshold=0.0, batch_buckets=(4,),
+        device_preprocess=device_preprocess,
+    )
+    eng.detect(_imgs(4))
+    h, w = engine.built.preprocess_spec.input_hw
+    snap = eng.metrics.snapshot()
+    assert snap["h2d_bytes_per_image"] == h * w * bytes_a_pixel + bytes_a_slot
+    assert snap["h2d_bytes_total"] == 4 * snap["h2d_bytes_per_image"]
+
+
 def test_device_preprocess_falls_back_for_pad_square():
     """OWLv2's pad_square spec can't defer its float warp to the device —
     the engine must quietly keep the host path rather than mis-normalize."""

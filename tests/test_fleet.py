@@ -278,6 +278,9 @@ def test_scale_to_zero_and_demand_restore():
         assert first_ttr is not None and first_ttr > 0
         # idle past the threshold: the pool drains to zero members
         await _wait(lambda: ctrl.snapshot()["pools"]["spot"]["scaled_to_zero"])
+        # the flag is set before the members' shutdowns, which run in an
+        # executor: wait for the one this asserts on
+        await _wait(lambda: m0.shutdowns >= 1)
         assert m0.shutdowns == 1
         snap = ctrl.snapshot()
         assert snap["pools"]["spot"]["size"] == 0

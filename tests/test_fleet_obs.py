@@ -400,6 +400,41 @@ def test_router_fleet_metrics_merge_and_prom_labels(monkeypatch):
     asyncio.run(run())
 
 
+def test_aggregator_switched_off_runs_nothing():
+    """Scrape interval 0 is the plane's off switch: the router starts no
+    scrape task, no member is ever scraped, `/metrics` carries no `fleet`
+    block, and the edge serves as before."""
+
+    async def run():
+        dets, servers, urls = await _stub_fleet(2)
+        pool = ReplicaPool(urls, health_interval_s=0.25)
+        agg = FleetAggregator(lambda: urls, interval_s=0.0)
+        app = make_router_app(pool, aggregator=agg)
+        async with TestClient(TestServer(app)) as client:
+            for i in range(4):
+                resp = await client.post(
+                    "/detect", json={"image_urls": [f"http://img/{i}.jpg"]}
+                )
+                assert resp.status == 200
+            await asyncio.sleep(0.05)  # a scrape task, had one started, ran
+            snap = json.loads(await (await client.get("/metrics")).read())
+            assert not agg.enabled and agg._task is None
+            assert agg.scrapes_total == 0 and agg.scrape_errors_total == 0
+            assert "fleet" not in snap
+            text = await (
+                await client.get("/metrics?format=prometheus")
+            ).text()
+            assert "spotter_tpu_fleet_" not in text
+        served = sum(d.engine.metrics.snapshot()["images_total"] for d in dets)
+        for server in servers:
+            await server.close()
+        for det in dets:
+            await det.aclose()
+        return served
+
+    assert asyncio.run(run()) == 4
+
+
 def test_debug_fleet_admin_gated(monkeypatch):
     async def run():
         dets, servers, urls = await _stub_fleet(1)

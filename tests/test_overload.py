@@ -730,3 +730,91 @@ def test_fetch_deadline_none_keeps_reference_retry_policy(monkeypatch):
         await det.batcher.stop()
 
     asyncio.run(run())
+
+
+# ------------------------------------------- the storm, as counts and states
+
+
+def test_burst_at_four_times_the_limit_slo_never_fails_bulk_absorbs_it():
+    """Four times the admission limit offered in one tick, a quarter of it
+    slo-class: every slo request is answered, the limiter sheds no slo
+    request, and what is refused or revoked is bulk, with a 429."""
+
+    async def run():
+        eng = StubEngine(service_ms=5.0)
+        lim = AdaptiveLimiter(
+            target_ms=10_000.0, floor=1, ceiling=4, interval_s=1e9,
+            metrics=eng.metrics,
+        )
+        b = MicroBatcher(
+            eng, max_batch=2, max_delay_ms=1.0, max_in_flight=1,
+            limiter=lim, brownout=None,
+        )
+        img = _img()
+        bulk = [asyncio.create_task(b.submit(img, cls=BULK)) for _ in range(12)]
+        slo = [asyncio.create_task(b.submit(img, cls=SLO)) for _ in range(4)]
+        slo_results = await asyncio.gather(*slo, return_exceptions=True)
+        bulk_results = await asyncio.gather(*bulk, return_exceptions=True)
+        await b.stop()
+        return lim, eng, slo_results, bulk_results
+
+    lim, eng, slo_results, bulk_results = asyncio.run(run())
+    assert all(isinstance(r, list) for r in slo_results), slo_results
+    refused = [r for r in bulk_results if not isinstance(r, list)]
+    assert refused and all(isinstance(r, AdmitLimitError) for r in refused)
+    assert all(r.status == 429 for r in refused)
+    assert lim.sheds_total[SLO] == 0
+    # the limit of 4 went to the first four bulk requests, the other eight
+    # were refused at the door, and each slo arrival then took a queued
+    # bulk request's place (a revocation counts among the bulk sheds)
+    assert lim.sheds_total[BULK] == len(refused) == 12
+    assert lim.revoked_total == 4
+    assert eng.metrics.snapshot()["admit_sheds_total"]["slo"] == 0
+
+
+def test_storm_on_the_clock_arms_two_rungs_and_comes_back_to_zero():
+    """The whole chain on the injected clock, no traffic and no sleeps: a
+    storm of over-target queue waits pins the limiter at its floor, the
+    default signal pair arms the ladder one rung at a time to at least two,
+    shedding holds it there, and once the storm ends idle ticks bring the
+    limit back up and the rung back to 0, every rung left as it was
+    entered."""
+    from spotter_tpu.engine.metrics import Metrics
+    from spotter_tpu.serving.overload import saturation_signals
+
+    clock = FakeClock()
+    metrics = Metrics()
+    lim = AdaptiveLimiter(
+        target_ms=50.0, floor=2, ceiling=16, increase=2.0, decrease=0.5,
+        interval_s=0.1, clock=clock, metrics=metrics,
+    )
+    saturated, hold = saturation_signals(lim, 400.0, metrics=metrics)
+    bc = BrownoutController(
+        saturated, arm_s=0.4, disarm_s=0.8, clock=clock, metrics=metrics,
+        hold=hold,
+    )
+    rungs = [bc.evaluate()]
+    for _ in range(30):  # 3.3 s of storm
+        clock.advance(0.11)
+        lim.observe(500.0)
+        metrics.record_admit_shed(BULK)
+        rungs.append(bc.evaluate())
+    assert lim.pinned_at_floor()
+    peak = max(rungs)
+    assert peak >= 2
+    # still shedding with the queue gone quiet: the rung holds
+    for _ in range(10):
+        clock.advance(0.11)
+        lim.tick()
+        metrics.record_admit_shed(BULK)
+        rungs.append(bc.evaluate())
+    assert rungs[-1] == peak
+    for _ in range(100):  # 11 s of calm
+        clock.advance(0.11)
+        lim.tick()
+        rungs.append(bc.evaluate())
+    assert rungs[-1] == 0 and lim.limit == 16
+    steps = [b - a for a, b in zip(rungs, rungs[1:]) if b != a]
+    assert set(steps) == {1, -1}  # one rung at a time, both ways
+    assert steps.count(1) == steps.count(-1) == peak  # no flap on the way
+    assert metrics.snapshot()["brownout_transitions_total"] == 2 * peak

@@ -3,9 +3,10 @@
 Three contracts, per the acceptance criteria:
 - masked-region invariance: padding pixels NEVER change detections (the
   uint8 valid-dims substrate zeroes them before the model sees anything);
-- mixed-bucket parity: a ragged (sub-bucket) canvas produces the same
-  detections as the per-bucket reference within score/box tolerance (conv
-  grid phase shifts at the canvas edge bound the residual);
+- mixed-bucket parity: a ragged (sub-bucket) canvas on the scheduler's
+  grid produces the per-bucket reference's detections, query for query, to
+  float32 rounding (a canvas off the grid changes which tokens are valid,
+  and `ragged_step` refuses a grid that allows one);
 - deadline-slack ordering: under a saturated queue an slo arrival enters
   the next dispatch ahead of older bulk work.
 
@@ -27,7 +28,11 @@ os.environ["SPOTTER_TPU_TINY"] = "1"
 
 from spotter_tpu.engine.batcher import MicroBatcher
 from spotter_tpu.engine.metrics import Metrics
-from spotter_tpu.engine.scheduler import QueueItem, Scheduler
+from spotter_tpu.engine.scheduler import (
+    COARSEST_FEATURE_STRIDE,
+    QueueItem,
+    Scheduler,
+)
 from spotter_tpu.ops.preprocess import (
     PreprocessSpec,
     decode_resize_uint8,
@@ -122,6 +127,38 @@ def test_priority_orders_slo_before_bulk_then_slack():
     assert order == [slo_tight, slo_loose, bulk_old]
 
 
+def test_ragged_packs_pad_fewer_pixels_than_fifo_on_a_skewed_mix():
+    """What the ragged policy is for, as a count: a Zipf-skewed mix of five
+    shapes drained four at a time. Both policies dispatch every item once;
+    FIFO pays the full bucket for every pack, the ragged packs pay their
+    snapped canvases: 143,360 padded pixels against 196,608 on this seed,
+    held here to a fifth fewer."""
+    shapes = [(80, 60), (160, 60), (60, 160), (60, 80), (64, 64)]
+    rng = np.random.default_rng(9)
+    weights = 1.0 / np.arange(1, len(shapes) + 1) ** 1.2
+    picks = rng.choice(len(shapes), size=48, p=weights / weights.sum())
+
+    buckets = (1, 2, 4)
+
+    def drain(ragged):
+        s = Scheduler(spec=TINY_DETR_SPEC, ragged=ragged, step=16)
+        buf = [
+            _item(*shapes[k], cls=BULK, t=float(i)) for i, k in enumerate(picks)
+        ]
+        seen, padded_px = [], 0
+        while buf:
+            plan = s.plan(buf, 4, buckets=buckets)
+            ch, cw = plan.canvas_hw or TINY_DETR_SPEC.pad_to
+            padded_px += s._padded_batch(len(plan.items), buckets) * ch * cw
+            seen += [id(it) for it in plan.items]
+        assert len(seen) == len(set(seen)) == len(picks)
+        return padded_px
+
+    fifo, ragged = drain(False), drain(True)
+    assert fifo == 12 * 4 * 64 * 64
+    assert ragged <= 0.8 * fifo, (ragged, fifo)
+
+
 def test_canvas_snap_caps_at_static_bucket():
     s = Scheduler(spec=TINY_DETR_SPEC, ragged=True, step=48)
     assert s._snap((50, 50)) == (64, 64)  # 48 -> 96 capped at bucket 64
@@ -183,24 +220,56 @@ def test_masked_region_invariance(detr_engine):
 
 
 def test_ragged_canvas_parity_vs_per_bucket_reference(detr_engine):
-    """Mixed-bucket parity: detections from a ragged (sub-bucket) canvas
-    match the per-bucket reference within score/box tolerance. The residual
-    is conv grid phase at the canvas edge (stride arithmetic over 48 vs 64
-    columns), bounded well below anything a staging bug (wrong mask, wrong
-    normalize, wrong pad fill) would produce."""
-    imgs = [_img(80, 60, seed=3), _img(96, 72, seed=4)]  # both -> (64, 48)
-    full = detr_engine.detect(imgs)
-    ragged = detr_engine.detect(imgs, canvas_hw=(64, 48))
+    """Mixed-bucket parity, as the served path makes canvases: a sub-bucket
+    canvas on the scheduler's default grid answers what the full bucket
+    answers, query for query.
+
+    The tiny DETR is built over a 256 x 256 bucket, so that a canvas on the
+    grid (a multiple of `COARSEST_FEATURE_STRIDE`) exists below the bucket;
+    its own 64 x 64 bucket is two tokens wide and has none. Two 400 x 150
+    images resize to 256 x 96, the scheduler snaps that to 256 x 128, and
+    both runs then see the same three columns of valid tokens: what is left
+    between them is where the backbone's zero padding starts, 32 pixels
+    beyond the valid region, and float32 rounding. (The engine's detections
+    at threshold 0 are its raw outputs: one row a query, in query order.)"""
+    import dataclasses
+
+    from spotter_tpu.engine.engine import InferenceEngine
+
+    built = detr_engine.built
+    spec = dataclasses.replace(
+        built.preprocess_spec, size=(192, 256), pad_to=(256, 256)
+    )
+    engine = InferenceEngine(
+        dataclasses.replace(built, preprocess_spec=spec),
+        threshold=0.0, batch_buckets=(2,), device_preprocess=True,
+    )
+    imgs = [_img(400, 150, seed=3), _img(400, 150, seed=4)]
+    valid_hw = shortest_edge_size((400, 150), *spec.size)
+    assert valid_hw == (256, 96)
+    canvas = Scheduler(spec=spec, ragged=True)._snap(valid_hw)
+    assert canvas == (256, 128)  # on the grid, and below the bucket
+
+    full = engine.detect(imgs)
+    ragged = engine.detect(imgs, canvas_hw=canvas)
     for a, b in zip(full, ragged):
-        assert len(a) == len(b)
+        # threshold 0 keeps every query, in query order: row i is query i
+        assert len(a) == len(b) > 0
+        assert [d["label"] for d in a] == [d["label"] for d in b]
         sa = np.asarray([d["score"] for d in a], np.float32)
         sb = np.asarray([d["score"] for d in b], np.float32)
-        # compare the score DISTRIBUTION sorted (rank flips between
-        # near-equal random-init scores are not a staging bug)
-        np.testing.assert_allclose(np.sort(sa), np.sort(sb), atol=0.12)
         ba = np.asarray([d["box"] for d in a], np.float32)
         bb = np.asarray([d["box"] for d in b], np.float32)
-        assert float(np.abs(np.sort(ba, 0) - np.sort(bb, 0)).max()) < 6.0
+        # Read on this tree (CPU, float32, matmuls at "highest"): scores
+        # apart by 1.1e-6 and boxes by 0.0026 px of a 400 x 150 image,
+        # which is rounding. A canvas off the grid (256 x 144, 256 x 120,
+        # 256 x 104) turns a fourth column of tokens valid: labels flip,
+        # scores move by 0.007 and boxes by 25-28 px; a staging defect (the
+        # image six pixels to one side) reads 0.008 and 11 px. Each bound
+        # stands well over a decade from both readings; 0.1 px is a tenth
+        # of the golden boxes' +-1 px contract.
+        np.testing.assert_allclose(sa, sb, atol=1e-4)
+        assert float(np.abs(ba - bb).max()) < 0.1
 
 
 def test_ragged_full_canvas_is_identical(detr_engine):
@@ -309,8 +378,14 @@ def test_ragged_env_arms_scheduler(monkeypatch):
     eng = PlainEngine()
     batcher = MicroBatcher(eng, max_batch=4)
     assert batcher.scheduler.ragged
+    assert batcher.scheduler.step % COARSEST_FEATURE_STRIDE == 0
     # plain-signature engine still never sees a canvas
     assert not batcher._engine_takes_canvas
+    # a grid the token masks do not share across canvases is refused where
+    # the step is read, not found later as an image that answers differently
+    monkeypatch.setenv("SPOTTER_TPU_RAGGED_STEP", "48")
+    with pytest.raises(ValueError, match="multiple of 64"):
+        MicroBatcher(PlainEngine(), max_batch=4)
 
 
 def test_padding_waste_and_slack_flow_to_metrics_and_prom():

@@ -323,6 +323,118 @@ def test_supervisor_alive_rejects_dead_and_zombie_pids():
 
 
 # ---------------------------------------------------------------------------
+# adoption and fencing at the actuation boundary, in one process: what the
+# crash drills (slow tier, real controllers) show end to end
+
+
+def test_successor_adopts_every_live_member_once_and_spawns_none(tmp_path):
+    """A controller that comes up over a manifest of three live members of
+    a pool of three adopts all three before it spawns, so its fenced
+    spawner never runs: 0 double-spawns. A dead supervisor's entry and a
+    pool it does not actuate are left alone, and asking twice adopts
+    nothing twice."""
+    import asyncio
+
+    from aiohttp import web
+    from aiohttp.test_utils import TestServer
+
+    from spotter_tpu.serving.fleet import FleetController, PoolSpec
+    from spotter_tpu.serving.reconcile import Reconciler
+
+    async def run():
+        async def healthz(request):
+            return web.json_response({})
+
+        servers = []
+        for _ in range(3):
+            app = web.Application()
+            app.router.add_get("/healthz", healthz)
+            server = TestServer(app)
+            await server.start_server()
+            servers.append(server)
+        urls = [f"http://{s.host}:{s.port}" for s in servers]
+        # a live process to stand for the members' supervisor
+        supervisor = subprocess.Popen(
+            [sys.executable, "-c", "import time; time.sleep(120)"]
+        )
+        corpse = subprocess.Popen([sys.executable, "-c", "pass"])
+        corpse.wait()
+        manifest = EndpointsManifest(str(tmp_path / "endpoints.json"))
+        for url in urls:
+            manifest.add(
+                url, pool="spot", version="v1", supervisor_pid=supervisor.pid
+            )
+        manifest.add(
+            "http://127.0.0.1:9", pool="spot", supervisor_pid=corpse.pid
+        )
+        manifest.add(
+            "http://127.0.0.1:10", pool="elsewhere",
+            supervisor_pid=supervisor.pid,
+        )
+        spawned = []
+
+        def spawner():
+            spawned.append(1)
+            raise AssertionError("a member was spawned beside a live one")
+
+        controller = FleetController(
+            [PoolSpec("spot", spawner=spawner, target_size=3)], tick_s=0.02,
+            pool_kwargs=dict(health_interval_s=0.05),
+        )
+        rec = Reconciler(
+            controller, StateStore.fresh(str(tmp_path / "state")),
+            manifest=manifest,
+        )
+        controller.fence = rec.fence
+        spec = controller.pools["spot"].spec
+        spec.spawner = rec.fenced_spawner(spec.spawner)
+        try:
+            assert rec.adopt_existing() == 3
+            assert rec.adopt_existing() == 0
+            await controller.start()
+            members = sorted(m.url for m in controller.pools["spot"].members)
+        finally:
+            await controller.stop(shutdown_members=False)
+            supervisor.kill()
+            supervisor.wait()
+            for server in servers:
+                await server.close()
+        return members, spawned, rec.metrics, urls
+
+    members, spawned, metrics, urls = asyncio.run(run())
+    assert members == sorted(urls)
+    assert spawned == []
+    assert metrics.adoptions_total == 3 and metrics.spawns_total == 0
+
+
+def test_deposed_leaders_spawn_is_refused_and_counted(tmp_path):
+    """The fencing check sits in front of every spawn: while it leads, a
+    controller's spawner runs and is counted; once a standby has taken the
+    lease at a higher epoch, the old leader's next spawn raises before the
+    spawner is called and books one fencing rejection."""
+    from spotter_tpu.serving.fleet import FleetController, PoolSpec
+    from spotter_tpu.serving.reconcile import Reconciler
+
+    path = str(tmp_path / "leader.lease")
+    old = LeaderLease(path, "old", ttl_s=10.0)
+    standby = LeaderLease(path, "standby", ttl_s=10.0)
+    assert old.try_acquire(now=100.0)
+    calls = []
+    rec = Reconciler(
+        FleetController([PoolSpec("spot", target_size=0)]),
+        StateStore.fresh(str(tmp_path / "state")), lease=old,
+    )
+    spawn = rec.fenced_spawner(lambda: calls.append(1) or "member")
+    assert spawn() == "member"
+    assert standby.try_acquire(now=120.0) and standby.epoch == old.epoch + 1
+    with pytest.raises(StaleLeaderError):
+        spawn()
+    assert calls == [1]
+    assert rec.metrics.spawns_total == 1
+    assert rec.metrics.fencing_rejections_total == 1
+
+
+# ---------------------------------------------------------------------------
 # rollout resume planning (tentpole part c, decision table)
 
 

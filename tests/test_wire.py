@@ -11,6 +11,7 @@ from io import BytesIO
 
 import httpx
 import numpy as np
+import pytest
 from aiohttp.test_utils import TestClient, TestServer
 from PIL import Image
 
@@ -517,6 +518,41 @@ def test_router_affinity_off_keeps_round_robin():
         await _stop_fleet(dets, servers)
 
     asyncio.run(run())
+
+
+@pytest.mark.parametrize("affinity", [True, False], ids=["affinity", "round-robin"])
+def test_fleet_hit_count_holds_under_affinity_and_decays_without(affinity):
+    """One URL asked for six times in turn of a fleet of three caches. Under
+    affinity it has one owner: the fleet computes it once and the other
+    five answers are hits, what one replica alone would give. Round-robin
+    hands it to each replica in turn, each computes it once for its own
+    cache, and only the second lap hits: the 1/N decay, as counts. (The
+    cache is keyed by content, so a hit still fetches: the last number is
+    which replicas were asked at all.)"""
+
+    async def run():
+        dets, servers, urls = await _start_fleet(3)
+        pool = ReplicaPool(urls, health_interval_s=0.2)
+        router_app = make_router_app(pool, affinity=affinity)
+        async with TestClient(TestServer(router_app)) as client:
+            for _ in range(6):
+                resp = await client.post(
+                    "/detect", json={"image_urls": [URLS[0]]}
+                )
+                assert resp.status == 200
+        computed = sum(d.engine.calls for d in dets)
+        hits = sum(
+            d.engine.metrics.snapshot()["cache_hits_total"] for d in dets
+        )
+        fetched = sorted(d.client.fetches for d in dets)
+        await _stop_fleet(dets, servers)
+        return computed, hits, fetched
+
+    computed, hits, fetched = asyncio.run(run())
+    if affinity:
+        assert (computed, hits, fetched) == (1, 5, [0, 0, 6])
+    else:
+        assert (computed, hits, fetched) == (3, 3, [2, 2, 2])
 
 
 def test_router_prometheus_exposition_carries_wire_gauges():
