@@ -21,11 +21,25 @@ gc the running sum of g inside the chunk and D_ij = exp(gc_i - gc_j), i >= j:
     o   = (exp(gc) q) S + lower(q k^T D) v'
     S  <- exp(gc_C) S + (exp(gc_C - gc) k)^T v'
 
-`chunk_step` is that mathematics for one head and one chunk. It is written
-once and runs in two places: inside the Pallas kernel on a TPU
-(`gated_delta_rule_kernel`, the custom call the device trace shows under
-that name), and under `vmap` and `lax.scan` in plain `jax.numpy` everywhere
-else (CPU tests). There is no interpret-mode fallback on a TPU.
+`chunk_step` is that mathematics for one chunk of the value heads a grid
+step of the kernel works on. It is written once and runs in two places:
+inside the Pallas kernel on a TPU (`gated_delta_rule_kernel`, the custom call
+the device trace shows under that name) over a step's 16 heads, and under
+`vmap` and `lax.scan` in plain `jax.numpy` everywhere else (CPU tests) a
+group of heads at a time. There is no interpret-mode fallback on a TPU.
+
+What the kernel's time is made of, and how the body is laid out for it (PR
+31; `tools/probe_delta_rule.py` times each part on the chip): the solve is a
+chain of ten products, each waiting for the one before, and a product's round
+trip through the MXU costs the same whether its operands are 64 or 128 wide,
+float32 or bfloat16. So (1) the two value heads of one key head go side by
+side in the 128 lanes for every quantity that is C x C: k k^T and q k^T are
+computed once a pair, the decay and the masks run on whole vregs, and the
+pair's two solves are one chain of ten products against block-diagonal
+operands; a key head that serves an odd number of value heads takes them one
+at a time. (2) Every kind of product is written for all the heads of a step
+before the next kind: the scheduler keeps close to the order it is given, and
+chains written one after the other run one after the other.
 
 Departures from transformers' `torch_chunk_gated_delta_rule`, none of them
 in the mathematics: T is formed by matrix products in float32 (a short
@@ -37,6 +51,7 @@ and accumulate in float32, the solve stays in float32.
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,67 +72,147 @@ def _dot(a, b, dims, dtype):
     )
 
 
-def _unit_lower_inverse(x, row, col):
-    """(I - X)^-1 for a strictly lower-triangular X (C, C), in float32, by
-    matrix products alone and without the growth a plain power series has.
-    Inside diagonal blocks of 8 the series I + X + ... + X^7 is short (its
-    terms stay within a few tens of X's scale); then neighbouring blocks are
-    merged, 8 -> 16 -> 32 -> ..., by the block formula: with T the inverse of
-    the block-diagonal part and E the entries that join two neighbours,
-    (I - X_blocks - E)^-1 = T + T E T. Every step multiplies true inverses of
-    sub-blocks, so what it computes stays as bounded as the answer is.
-    (A power series over all C tokens sums terms of size C-choose-C/2: on
-    tokens that resemble each other, an image's neighbouring patches, it
-    reads 1e30 where the answer is of order one.)"""
-    c = x.shape[0]
+def _lower_masks(c: int, width: int):
+    """(row, col) of every entry of width / c matrices (C, C) laid side by
+    side along the lanes: the column counts inside its own matrix."""
+    row = lax.broadcasted_iota(jnp.int32, (c, width), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (c, width), 1)
+    return row, jnp.where(lane >= c, lane - c, lane)
+
+
+def _side_by_side(cols, c: int):
+    """One column (C, 1) a head -> (C, n C): head h's value fills its own C
+    lanes. One head: the column itself (broadcasting does the rest)."""
+    if cols.shape[1] == 1:
+        return cols
+    lane = lax.broadcasted_iota(jnp.int32, (c, 2 * c), 1)
+    return jnp.where(lane < c, cols[:, 0:1], cols[:, 1:2])
+
+
+def _unit_lower_inverse(xs, row, col):
+    """(I - X)^-1 for each strictly lower-triangular X (C, C) of the list
+    `xs`, in float32, by matrix products alone and without the growth a plain
+    power series has. Inside diagonal blocks of 8 the series I + X + ... +
+    X^7 is short (its terms stay within a few tens of X's scale); then
+    neighbouring blocks are merged, 8 -> 16 -> 32 -> ..., by the block
+    formula: with T the inverse of the block-diagonal part and E the entries
+    that join two neighbours, (I - X_blocks - E)^-1 = T + T E T. Every step
+    multiplies true inverses of sub-blocks, so what it computes stays as
+    bounded as the answer is. (A power series over all C tokens sums terms of
+    size C-choose-C/2: on tokens that resemble each other, an image's
+    neighbouring patches, it reads 1e30 where the answer is of order one.)
+
+    An x may hold two such matrices side by side, [X1 | X2] (C, 2C): its
+    answer is then [T1 | T2], by the same ten products, each serving both:
+    the pair's products A1 B1 and A2 B2 are one product [A1 | A2]
+    blockdiag(B1, B2), 64 rows through a whole 128 x 128 tile at the served
+    chunk. And every step is written for all of `xs` before the next step:
+    a product here waits for the one before it (its round trip through the
+    MXU is what the solve costs, not its rows), the chip's scheduler keeps
+    close to the order the products are written in, and the chains of the
+    list are independent, so each fills the others' waits. `row`, `col`:
+    `_lower_masks`."""
+    c, width = xs[0].shape
+    if width == c:
+        def times(a, b):
+            return _dot(a, b, _NN, jnp.float32)
+    else:
+        at = lax.broadcasted_iota(jnp.int32, (width, width), 0)
+        lane = lax.broadcasted_iota(jnp.int32, (width, width), 1)
+        own = (at < c) == (lane < c)
+
+        def times(a, b):  # blockdiag: b twice down the sublanes, and a select
+            return _dot(a, jnp.where(own, jnp.concatenate([b, b], axis=0), 0.0), _NN, jnp.float32)
 
     def same_block(shift):
         return (row >> shift) == (col >> shift)
 
-    inner = jnp.where(same_block(3), x, 0.0)
-    t = jnp.where(row == col, 1.0, 0.0).astype(jnp.float32) + inner
-    power = inner
+    eye = jnp.where(row == col, 1.0, 0.0).astype(jnp.float32)
+    powers = [jnp.where(same_block(3), x, 0.0) for x in xs]
+    ts = [eye + inner for inner in powers]
     for _ in range(2):  # (I + X)(I + X^2)(I + X^4): X^8 = 0 inside a block of 8
-        power = _dot(power, power, _NN, jnp.float32)
-        t = t + _dot(t, power, _NN, jnp.float32)
+        powers = [times(power, power) for power in powers]
+        ts = [t + times(t, power) for t, power in zip(ts, powers)]
     shift = 3
     while (1 << shift) < c:
-        joins = jnp.where(same_block(shift + 1) & ~same_block(shift), x, 0.0)
-        t = t + _dot(_dot(t, joins, _NN, jnp.float32), t, _NN, jnp.float32)
+        joins = same_block(shift + 1) & ~same_block(shift)
+        reached = [times(t, jnp.where(joins, x, 0.0)) for t, x in zip(ts, xs)]
+        ts = [t + times(te, t) for t, te in zip(ts, reached)]
         shift += 1
-    return t
+    return ts
 
 
-def chunk_step(q, k, v, gc_col, gc_row, beta_col, state, mm):
-    """One head, one chunk. q, k: (C, dk); v: (C, dv); gc_col (C, 1) and
-    gc_row (1, C): the running sum of g inside the chunk, both ways round;
-    beta_col (C, 1); state (dk, dv) float32. `mm` is the operand type of the
-    large products. Returns (o (C, dv) float32, the state after the chunk)."""
-    c = q.shape[0]
-    row = lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    decay = jnp.where(row >= col, jnp.exp(jnp.minimum(gc_col - gc_row, 0.0)), 0.0)
-    x = jnp.where(row > col, -(beta_col * _dot(k, k, _NT, mm) * decay), 0.0)
-    t = _unit_lower_inverse(x, row, col)
-    grow = jnp.exp(gc_col)
-    u = _dot(t, beta_col * v, _NN, mm)
-    w = _dot(t, (beta_col * grow) * k, _NN, mm)
-    v_new = u - _dot(w, state, _NN, mm)
-    scores = jnp.where(row >= col, _dot(q, k, _NT, mm) * decay, 0.0)
-    out = _dot(grow * q, state, _NN, mm) + _dot(scores, v_new, _NN, mm)
-    g_last = gc_row[:, c - 1:c]
+class _Head(NamedTuple):
+    """One value head's part of a chunk step, after the solve."""
+    q: jax.Array  # (C, dk), its key head's
+    k: jax.Array
+    v: jax.Array  # (C, dv)
+    gc: jax.Array  # (C, 1)
+    beta: jax.Array  # (C, 1)
+    last: jax.Array  # (1, 1): gc of the chunk's last token
+    state: jax.Array  # (dk, dv)
+    t: jax.Array  # (C, C) in the served type
+    scores: jax.Array  # (C, C) in the served type
+
+
+def chunk_step(groups, mm):
+    """One chunk of several groups of value heads, a group being the n heads
+    (one, or a pair) that share a key head: `groups` lists, a group, (q, k,
+    v, gc_col, gc_row, beta_col, state). q, k: (C, dk); v: (C, n dv), the
+    heads' values side by side; gc_col (C, n) and gc_row (1, n C): the
+    running sum of g inside the chunk, both ways round; beta_col (C, n);
+    state (n, dk, dv) float32. `mm` is the operand type of the large
+    products. Every (C, C) quantity of a pair is one (C, 2C) array, head h in
+    lanes [h C, (h + 1) C): k k^T and q k^T are computed once, the decay, the
+    masks and the solve work on whole 128-lane rows at the served chunk. The
+    products that are dv wide stay a head's. Each kind of product is written
+    for every head before the next kind (`_unit_lower_inverse` says why).
+    Returns, a group, (o (C, n dv) float32, the state after the chunk)."""
+    c, n = groups[0][0].shape[0], groups[0][3].shape[1]
+    dv = groups[0][2].shape[1] // n
+    row, col = _lower_masks(c, n * c)
+    xs, scores = [], []
+    for q, k, _, gc_col, gc_row, beta_col, _ in groups:
+        decay = jnp.where(
+            row >= col, jnp.exp(jnp.minimum(_side_by_side(gc_col, c) - gc_row, 0.0)), 0.0)
+        # [k; q] [k; ..; k]^T: k k^T over q k^T, each already once a head along the lanes
+        kq = _dot(jnp.concatenate([k, q], axis=0), jnp.concatenate([k] * n, axis=0), _NT, mm)
+        xs.append(jnp.where(row > col, -(_side_by_side(beta_col, c) * kq[:c] * decay), 0.0))
+        scores.append(jnp.where(row >= col, kq[c:] * decay, 0.0).astype(mm))
+    ts = [t.astype(mm) for t in _unit_lower_inverse(xs, row, col)]
+    heads = [
+        _Head(q, k, v[:, h * dv:(h + 1) * dv], gc_col[:, h:h + 1], beta_col[:, h:h + 1],
+              gc_row[:, (h + 1) * c - 1:(h + 1) * c], state[h],
+              t[:, h * c:(h + 1) * c], score[:, h * c:(h + 1) * c])
+        for (q, k, v, gc_col, gc_row, beta_col, state), t, score in zip(groups, ts, scores)
+        for h in range(n)]
+    grows = [jnp.exp(h.gc) for h in heads]
+    us = [_dot(h.t, h.beta * h.v, _NN, mm) for h in heads]
+    ws = [_dot(h.t, (h.beta * grow) * h.k, _NN, mm) for h, grow in zip(heads, grows)]
+    v_news = [u - _dot(w, h.state, _NN, mm) for h, u, w in zip(heads, us, ws)]
+    outs = [_dot(grow * h.q, h.state, _NN, mm) + _dot(h.scores, v_new, _NN, mm)
+            for h, grow, v_new in zip(heads, grows, v_news)]
     # (1, 1) -> a row -> the state's rows: the chip's compiler broadcasts
     # along one axis at a time
-    keep = jnp.exp(jnp.broadcast_to(g_last, (1, state.shape[1])))
-    state = state * keep + _dot(jnp.exp(g_last - gc_col) * k, v_new, _TN, mm)
-    return out, state
+    states = [h.state * jnp.exp(jnp.broadcast_to(h.last, (1, dv)))
+              + _dot(jnp.exp(h.last - h.gc) * h.k, v_new, _TN, mm)
+              for h, v_new in zip(heads, v_news)]
+    return [(jnp.concatenate(outs[i:i + n], axis=1), jnp.stack(states[i:i + n]))
+            for i in range(0, len(heads), n)]
+
+
+def _side(rep: int) -> int:
+    """Value heads a chunk step takes side by side: neighbours 2j, 2j + 1
+    share their key head where each key head serves an even number."""
+    return 2 if rep % 2 == 0 else 1
 
 
 def _heads_per_block(hv: int, rep: int, dk: int) -> int:
-    """Value heads one grid step works on: enough that a block of q is a
-    whole number of 128-lane tiles, few enough that the unrolled body stays
-    small."""
-    for cand in (4, 8, 2, 1):
+    """Value heads one grid step works on: a block of q a whole number of
+    128-lane tiles, and up to 16 heads, whose independent chains of products
+    fill each other's waits (a layer at the bucket of 32 took 37.6 ms with 4
+    a step, 24.9 with 8, 19.8 with 16 and 20.0 with 32: PERF.md, PR 31)."""
+    for cand in (16, 8, 4, 2, 1):
         if hv % cand == 0 and cand % rep == 0 and (cand // rep) * dk % 128 == 0:
             return cand
     return hv
@@ -129,19 +224,26 @@ def _kernel(q_ref, k_ref, v_ref, col_ref, row_ref, o_ref, state_ref, *,
     def _():  # a new (image, head block): the state starts at zero
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    for h in range(heads):
-        kh = h // rep
-        out, state = chunk_step(
-            q_ref[0, :, kh * dk:(kh + 1) * dk],
-            k_ref[0, :, kh * dk:(kh + 1) * dk],
-            v_ref[0, :, h * dv:(h + 1) * dv],
-            col_ref[0, 0, :, h:h + 1],
-            row_ref[0, 0, 0, h:h + 1, :],
-            col_ref[0, 0, :, heads + h:heads + h + 1],
-            state_ref[h], mm,
-        )
-        state_ref[h] = state
-        o_ref[0, :, h * dv:(h + 1) * dv] = out.astype(o_ref.dtype)
+    n = _side(rep)
+    starts = range(0, heads, n)
+    results = chunk_step([(
+        q_ref[0, :, h // rep * dk:(h // rep + 1) * dk],
+        k_ref[0, :, h // rep * dk:(h // rep + 1) * dk],
+        v_ref[0, :, h * dv:(h + n) * dv],
+        col_ref[0, 0, :, h:h + n],
+        row_ref[0, 0, 0, h // n:h // n + 1, :],
+        col_ref[0, 0, :, heads + h:heads + h + n],
+        state_ref[h:h + n]) for h in starts], mm)
+    for h, (out, state) in zip(starts, results):
+        state_ref[h:h + n] = state
+        o_ref[0, :, h * dv:(h + n) * dv] = out.astype(o_ref.dtype)
+
+
+def _rows(gc, b: int, n_chunks: int, chunk: int, groups: int, side: int):
+    """gc (B, Tp, Hv) -> (B, groups, N, Hv / groups / side, side C): a chunk's
+    running sums as rows, the heads of one step side by side along the lanes."""
+    gc = gc.reshape(b, n_chunks, chunk, groups, -1, side)
+    return gc.transpose(0, 3, 1, 4, 5, 2).reshape(b, groups, n_chunks, -1, side * chunk)
 
 
 def _pallas(q, k, v, gc, beta, chunk: int, mm, interpret: bool):
@@ -149,6 +251,7 @@ def _pallas(q, k, v, gc, beta, chunk: int, mm, interpret: bool):
     b, tp, hk, dk = q.shape
     hv, dv = v.shape[2:]
     rep = hv // hk
+    side = _side(rep)
     hb = _heads_per_block(hv, rep, dk)
     nhb, n = hv // hb, tp // chunk
     # the per-token scalars in the two layouts the chunk needs them in: as
@@ -156,7 +259,7 @@ def _pallas(q, k, v, gc, beta, chunk: int, mm, interpret: bool):
     cols = jnp.concatenate(
         [gc.reshape(b, tp, nhb, hb), beta.reshape(b, tp, nhb, hb)], -1
     ).transpose(0, 2, 1, 3)  # (B, nhb, Tp, 2 hb)
-    rows = gc.reshape(b, n, chunk, nhb, hb).transpose(0, 3, 1, 4, 2)  # (B, nhb, N, hb, C)
+    rows = _rows(gc, b, n, chunk, nhb, side)  # (B, nhb, N, hb / side, side C)
     qk_spec = pl.BlockSpec((1, chunk, hb // rep * dk), lambda i, j, s: (i, s, j))
     v_spec = pl.BlockSpec((1, chunk, hb * dv), lambda i, j, s: (i, s, j))
     out = pl.pallas_call(
@@ -166,7 +269,7 @@ def _pallas(q, k, v, gc, beta, chunk: int, mm, interpret: bool):
         in_specs=[
             qk_spec, qk_spec, v_spec,
             pl.BlockSpec((1, 1, chunk, 2 * hb), lambda i, j, s: (i, j, s, 0)),
-            pl.BlockSpec((1, 1, 1, hb, chunk), lambda i, j, s: (i, j, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, hb // side, side * chunk), lambda i, j, s: (i, j, s, 0, 0)),
         ],
         out_specs=v_spec,
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
@@ -181,26 +284,28 @@ def _pallas(q, k, v, gc, beta, chunk: int, mm, interpret: bool):
 
 def _scan(q, k, v, gc, beta, chunk: int, mm):
     """The same chunks in plain jax.numpy: `chunk_step` under vmap over
-    images and heads, scanned over the chunks."""
+    images and the heads a step takes together, scanned over the chunks."""
     b, tp, hk, dk = q.shape
     hv, dv = v.shape[2:]
     n, rep = tp // chunk, hv // hk
+    side = _side(rep)
+    steps = hv // side
 
     def chunks(x):  # (B, Tp, H, d) -> (N, B, H, C, d)
         return x.reshape(b, n, chunk, *x.shape[2:]).transpose(1, 0, 3, 2, 4)
 
-    q, k = (jnp.repeat(chunks(x), rep, axis=2) for x in (q, k))
-    gc = gc.reshape(b, n, chunk, hv).transpose(1, 0, 3, 2)  # (N, B, H, C)
-    beta = beta.reshape(b, n, chunk, hv).transpose(1, 0, 3, 2)
-    step = jax.vmap(jax.vmap(functools.partial(chunk_step, mm=mm)))
+    q, k = (jnp.repeat(chunks(x), rep // side, axis=2) for x in (q, k))
+    v = chunks(v.reshape(b, tp, steps, side * dv))
+    col, beta = (x.reshape(b, n, chunk, steps, side).transpose(1, 0, 3, 2, 4) for x in (gc, beta))
+    row = _rows(gc, b, n, chunk, 1, side)[:, 0].transpose(1, 0, 2, 3)[..., None, :]
+    step = jax.vmap(jax.vmap(lambda *group: chunk_step([group], mm)[0]))
 
     def body(state, xs):
-        qi, ki, vi, gi, bi = xs
-        out, state = step(qi, ki, vi, gi[..., None], gi[..., None, :], bi[..., None], state)
+        out, state = step(*xs, state)
         return state, out
 
-    state = jnp.zeros((b, hv, dk, dv), jnp.float32)
-    _, out = lax.scan(body, state, (q, k, chunks(v), gc, beta))
+    state = jnp.zeros((b, steps, side, dk, dv), jnp.float32)
+    _, out = lax.scan(body, state, (q, k, v, col, row, beta))
     return out.transpose(1, 0, 3, 2, 4).reshape(b, tp, hv, dv).astype(v.dtype)
 
 

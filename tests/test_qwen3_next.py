@@ -142,10 +142,13 @@ def test_detector_end_to_end(params):
         np.testing.assert_allclose(got["pred_boxes"][i], want["pred_boxes"], atol=ATOL)
 
 
-@pytest.mark.parametrize("tokens_n,chunk", [(40, 16), (64, 64), (150, 64)])
-def test_chunked_delta_rule_against_token_by_token(tokens_n, chunk):
+# rep: the value heads a key head serves: 2 takes them as a pair, 1 and 3 one at a time
+@pytest.mark.parametrize("tokens_n,chunk,rep", [(40, 16, 2), (64, 64, 2), (150, 64, 2),
+                                                 (40, 16, 1), (150, 64, 3), (100, 16, 4)])
+def test_chunked_delta_rule_against_token_by_token(tokens_n, chunk, rep):
     rng = np.random.default_rng(tokens_n)
-    hk, hv, dk, dv = 2, 4, 16, 24
+    hk, dk, dv = 2, 16, 24
+    hv = hk * rep
     q, k = (rng.standard_normal((1, tokens_n, hk, dk)) for _ in range(2))
     q = q / np.linalg.norm(q, axis=-1, keepdims=True) * dk**-0.5
     k = k / np.linalg.norm(k, axis=-1, keepdims=True)
@@ -154,15 +157,50 @@ def test_chunked_delta_rule_against_token_by_token(tokens_n, chunk):
     beta = 1 / (1 + np.exp(-rng.standard_normal((1, tokens_n, hv))))
     f = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
     got = delta_rule.chunked_gated_delta_rule(f(q), f(k), f(v), f(g), f(beta), chunk=chunk)
-    want = ref.delta_rule(f(np.repeat(q[0], 2, 1)), f(np.repeat(k[0], 2, 1)), f(v[0]), f(g[0]), f(beta[0]))
+    want = ref.delta_rule(
+        f(np.repeat(q[0], rep, 1)), f(np.repeat(k[0], rep, 1)), f(v[0]), f(g[0]), f(beta[0]))
     np.testing.assert_allclose(got[0], want, atol=1e-5)
 
 
-def test_delta_rule_kernel_in_interpret_mode_is_the_scan():
+def _strictly_lower(kind, c, rng):
+    """The X of a chunk, as `chunk_step` forms it: -beta (k_i . k_j) decay
+    below the diagonal, of noise or of tokens that resemble each other."""
+    if kind == "random":
+        k = rng.standard_normal((c, 16))
+    else:
+        k = rng.standard_normal((1, 16)) + 0.02 * rng.standard_normal((c, 16))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    gc = np.cumsum(-rng.uniform(1e-3, 0.3, c))
+    x = -rng.uniform(0.5, 1.0, (c, 1)) * (k @ k.T) * np.exp(gc[:, None] - gc[None])
+    return np.tril(x, -1).astype(np.float32)
+
+
+@pytest.mark.parametrize("c", [16, 64])
+@pytest.mark.parametrize("kind", ["random", "resembling"])
+def test_the_inverse_of_a_pair_side_by_side_is_each_heads_own(kind, c):
+    """[X1 | X2] (C, 2C) through the ten products that serve both heads
+    gives [T1 | T2], the two (C, C) solves; and each is (I - X)^-1."""
+    rng = np.random.default_rng(c)
+    x1, x2 = _strictly_lower(kind, c, rng), _strictly_lower(kind, c, rng)
+    alone = delta_rule._unit_lower_inverse([x1, x2], *delta_rule._lower_masks(c, c))
+    (pair,) = delta_rule._unit_lower_inverse(
+        [np.concatenate([x1, x2], 1)], *delta_rule._lower_masks(c, 2 * c))
+    assert pair.shape == (c, 2 * c)
+    np.testing.assert_allclose(pair, np.concatenate(alone, 1), atol=1e-6)
+    for t, x in zip(alone, (x1, x2)):
+        want = np.linalg.inv(np.eye(c) - x.astype(np.float64))
+        np.testing.assert_allclose(t, want, atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("rep", [2, 1])
+def test_delta_rule_kernel_in_interpret_mode_is_the_scan(rep):
     """The Pallas kernel's body is `chunk_step`, the function the scan runs:
-    at the published head widths (128) the two agree to rounding."""
+    at the published head widths (128) the two agree to rounding, where a key
+    head's two value heads go side by side (rep 2, the published ratio) and
+    where each head goes alone (rep 1)."""
     rng = np.random.default_rng(1)
-    b, t, hk, hv, d = 1, 100, 2, 4, 128
+    b, t, hk, d = 1, 100, 2, 128
+    hv = hk * rep
     q, k = (rng.standard_normal((b, t, hk, d)).astype(np.float32) * d**-0.5 for _ in range(2))
     v = rng.standard_normal((b, t, hv, d)).astype(np.float32)
     g = -np.exp(rng.standard_normal((b, t, hv))).astype(np.float32)
@@ -503,12 +541,14 @@ def test_checkpoint_directory_loads_without_torch_and_caches(tmp_path, monkeypat
     assert np.isfinite(np.asarray(out["logits"])).all()
 
 
-def test_delta_rule_on_tokens_that_resemble_each_other():
+@pytest.mark.parametrize("impl", ["scan", "pallas"])
+def test_delta_rule_on_tokens_that_resemble_each_other(impl):
     """An image's neighbouring patches give nearly the same key: the chunk's
     triangular system is then far from the identity, and a power series over
     the whole chunk reads 1e30 where the answer is of order one (found on the
     chip, PR 28: the first served run answered nothing). The block-merged
-    inverse stays at rounding."""
+    inverse stays at rounding, in the scan and in the kernel's body
+    (interpret mode)."""
     rng = np.random.default_rng(0)
     t, hk, hv, d = 200, 1, 2, 32
     base = rng.standard_normal((1, 1, hk, d))
@@ -519,6 +559,7 @@ def test_delta_rule_on_tokens_that_resemble_each_other():
     v = rng.standard_normal((1, t, hv, d))
     g, beta = -np.full((1, t, hv), 1e-3), np.full((1, t, hv), 0.95)
     f = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-    got = delta_rule.chunked_gated_delta_rule(f(q), f(k), f(v), f(g), f(beta), chunk=64)
+    got = delta_rule.chunked_gated_delta_rule(
+        f(q), f(k), f(v), f(g), f(beta), chunk=64, impl=impl, interpret=True)
     want = ref.delta_rule(f(np.repeat(q[0], 2, 1)), f(np.repeat(k[0], 2, 1)), f(v[0]), f(g[0]), f(beta[0]))
     np.testing.assert_allclose(got[0], want, atol=2e-5)

@@ -29,7 +29,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import spotter_tpu.models.rtdetr as rtdetr_mod
 from spotter_tpu.models import layers
-from spotter_tpu.ops import msda
+from spotter_tpu.ops import delta_rule, msda
 from spotter_tpu.ops.openvocab import fused_class_logits
 
 
@@ -117,6 +117,17 @@ def test_splash_attention_at_vit_token_counts(chip, tokens):
     qkv = ((8, tokens, 12, 64), jnp.bfloat16)
     compiled = compile_for(chip, layers._splash_self_attention, qkv, qkv, qkv)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_delta_rule_kernel_at_qwen3_next_shapes(chip):
+    """One Gated DeltaNet layer's call at the bucket of 8: 4300 tokens, 16 key
+    and 32 value heads of 128, bfloat16 (a key head's two value heads side by
+    side in the lanes: slices at lane 64 and a (128, 128) select a product)."""
+    fn = partial(delta_rule.chunked_gated_delta_rule, impl="pallas")
+    qk, scalars = ((8, 4300, 16, 128), jnp.bfloat16), ((8, 4300, 32), jnp.float32)
+    compiled = compile_for(chip, fn, qk, qk, ((8, 4300, 32, 128), jnp.bfloat16), scalars, scalars)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_delta_rule_kernel" in text
 
 
 @pytest.mark.parametrize("patches", [3600, 576], ids=["owlv2-b16", "owlvit-b32"])
