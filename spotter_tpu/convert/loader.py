@@ -20,6 +20,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    Lfm2MoeDetConfig,
     OwlViTConfig,
     Qwen3NextDetConfig,
     RTDetrConfig,
@@ -323,3 +324,9 @@ def load_qwen3_next_det(model_name: str) -> tuple[Qwen3NextDetConfig, dict]:
     from spotter_tpu.convert.qwen3_next_rules import convert_qwen3_next
 
     return load_config_and_safetensors(model_name, Qwen3NextDetConfig, convert_qwen3_next)
+
+
+def load_lfm2_moe_det(model_name: str) -> tuple[Lfm2MoeDetConfig, dict]:
+    from spotter_tpu.convert.lfm2_moe_rules import convert_lfm2_moe
+
+    return load_config_and_safetensors(model_name, Lfm2MoeDetConfig, convert_lfm2_moe)

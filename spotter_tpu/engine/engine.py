@@ -66,7 +66,7 @@ REBUILD_GATE_WAIT_S = 300.0
 
 # Per-image counters a module may return beside "logits" and "pred_boxes";
 # the engine fetches them with the detections and adds them to /metrics.
-PROGRAM_COUNTERS = ("moe_expert_tokens", "moe_assignments")
+PROGRAM_COUNTERS = ("moe_expert_tokens", "moe_assignments", "moe_bias_moved")
 
 POSTPROCESS_KINDS = {
     "sigmoid_topk": sigmoid_topk_postprocess,      # RT-DETR family
@@ -1114,8 +1114,7 @@ class InferenceEngine:
                 for dets in out
             ]
             if counters:  # already on the host: the fetch above brought them
-                expert_tokens, assignments = counters
-                self.metrics.record_moe(expert_tokens[:n], assignments[:n])
+                self.metrics.record_moe(*(counter[:n] for counter in counters))
         batch.stages[obs.POSTPROCESS] = post.seconds
         batch.total.stop()
         self.metrics.record_batch(

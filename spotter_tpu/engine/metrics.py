@@ -211,7 +211,7 @@ class Metrics:
         self._slots_total = 0
         # routed-expert layers (PR 28): what the program counted of its own
         # routing, per batch, over the images the batch really held
-        self._moe = {"assignments": 0, "assignments_local": 0,
+        self._moe = {"assignments": 0, "assignments_local": 0, "bias_moved": 0,
                      "expert_tokens_max": 0, "expert_tokens_mean": 0.0}
         # host staging slabs (ISSUE 27): leases, and how many of them found
         # the free-list empty and allocated; the rest reused a slab
@@ -334,10 +334,12 @@ class Metrics:
         # record a no-op while keeping the snapshot keys present.
         self.perf = PerfLedger()
 
-    def record_moe(self, expert_tokens, assignments) -> None:
+    def record_moe(self, expert_tokens, assignments, bias_moved=None) -> None:
         """`expert_tokens`: (images, layers, held experts), the tokens of each
         image that each held expert of each layer took; `assignments`:
-        (images, layers), each image's tokens times k. Per batch and layer the
+        (images, layers), each image's tokens times k; `bias_moved` (images,
+        layers), from a router that chooses with a bias: the selections that
+        are not among the k best of the unbiased scores. Per batch and layer the
         fullest held expert's tokens and the mean over the held experts are
         added up, so that their quotient over a window is the imbalance the
         grouped product saw."""
@@ -345,6 +347,8 @@ class Metrics:
         with self._lock:
             self._moe["assignments"] += int(assignments.sum())
             self._moe["assignments_local"] += int(per_layer.sum())
+            if bias_moved is not None:
+                self._moe["bias_moved"] += int(bias_moved.sum())
             self._moe["expert_tokens_max"] += int(per_layer.max(axis=-1).sum())
             self._moe["expert_tokens_mean"] += float(per_layer.mean(axis=-1).sum())
 
@@ -774,6 +778,7 @@ class Metrics:
                 "slots_total": self._slots_total,
                 "moe_assignments_total": self._moe["assignments"],
                 "moe_assignments_local_total": self._moe["assignments_local"],
+                "moe_bias_moved_total": self._moe["bias_moved"],
                 "moe_expert_tokens_max_total": self._moe["expert_tokens_max"],
                 "moe_expert_tokens_mean_total": round(self._moe["expert_tokens_mean"], 3),
                 "staging_slab_leases_total": self._slab_leases_total,

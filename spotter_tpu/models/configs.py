@@ -661,3 +661,66 @@ class Qwen3NextDetConfig:
             id2label=tuple(sorted((int(k), v) for k, v in hf.get("id2label", {}).items())),
             **{k: hf[k] for k in names if k in hf},
         )
+
+
+@dataclass(frozen=True)
+class Lfm2MoeDetConfig:
+    """LFM2-MoE's decoder layers as a detector body (`lfm2_moe_det`): the
+    language model's widths under their published keys (`lfm2_moe`'s
+    config.json), the detector's seams under YOLOS's. Every layer is held
+    whole: `num_experts` is both the router's width and the experts held
+    (`models/lfm2_moe.py`)."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_hidden_layers: int = 6
+    layer_types: tuple[str, ...] = ("conv", "conv", "full_attention", "conv", "conv", "conv")
+    num_dense_layers: int = 2
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    conv_L_cache: int = 3
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    norm_topk_prob: bool = True
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    image_size: tuple[int, int] = (800, 1344)
+    patch_size: int = 16
+    num_channels: int = 3
+    num_detection_tokens: int = 100
+    num_labels: int = 91
+    id2label: tuple[tuple[int, str], ...] = ()
+
+    @property
+    def id2label_dict(self) -> dict[int, str]:
+        return dict(self.id2label)
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def num_tokens(self) -> int:
+        h, w = self.image_size
+        return (h // self.patch_size) * (w // self.patch_size) + self.num_detection_tokens
+
+    @classmethod
+    def from_hf(cls, hf: dict) -> "Lfm2MoeDetConfig":
+        """From a checkpoint's config.json as a dict: the installed
+        transformers has no class for this model_type."""
+        names = {f.name for f in fields(cls)} - {"id2label", "image_size", "layer_types"}
+        cfg = cls(
+            image_size=tuple(hf["image_size"]),
+            layer_types=tuple(hf["layer_types"]),
+            id2label=tuple(sorted((int(k), v) for k, v in hf.get("id2label", {}).items())),
+            **{k: hf[k] for k in names if k in hf},
+        )
+        if len(cfg.layer_types) != cfg.num_hidden_layers:
+            raise ValueError(f"{len(cfg.layer_types)} layer_types for "
+                             f"{cfg.num_hidden_layers} layers")
+        if hf.get("head_dim", cfg.head_dim) != cfg.head_dim:
+            raise ValueError(f"head_dim {hf['head_dim']} is not hidden_size / heads = {cfg.head_dim}")
+        return cfg

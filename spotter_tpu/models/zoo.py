@@ -33,6 +33,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    Lfm2MoeDetConfig,
     OwlViTConfig,
     OwlViTTextConfig,
     OwlViTVisionConfig,
@@ -45,6 +46,7 @@ from spotter_tpu.models.conditional_detr import ConditionalDetrDetector
 from spotter_tpu.models.dab_detr import DabDetrDetector
 from spotter_tpu.models.deformable_detr import DeformableDetrDetector
 from spotter_tpu.models.detr import DetrDetector
+from spotter_tpu.models.lfm2_moe import Lfm2MoeDetector
 from spotter_tpu.models.owlvit import OwlViTDetector
 from spotter_tpu.models.qwen3_next import Qwen3NextDetector
 from spotter_tpu.models.yolos import YolosDetector
@@ -294,24 +296,16 @@ def hold_matrices_in(params: dict, dtype) -> dict:
     return jax.tree_util.tree_map(cast, params)
 
 
-def _build_qwen3_next_det(model_name: str) -> BuiltDetector:
-    """Qwen3-Next's decoder layers as a detector body, served in YOLOS's
-    form: the same warp to the checkpoint's `image_size`, the same softmax
-    postprocess. This family's 0.97 B parameters are held in the policy's
-    type (a float32 tree cast at every use would stream twice the bytes a
-    deployment does): the tree is cast once, here."""
-    if os.environ.get(TINY_ENV):
-        cfg = tiny_qwen3_next_det_config()
-        params = _init_random(Qwen3NextDetector(cfg), cfg.image_size)
-        logger.info("Built tiny random qwen3_next_det for %s (%s)", model_name, TINY_ENV)
-    else:
-        from spotter_tpu.convert.loader import load_qwen3_next_det
-
-        cfg, params = load_qwen3_next_det(model_name)
+def _held_detector(model_name: str, module_cls, cfg, params) -> BuiltDetector:
+    """A decoder body served in YOLOS's form: the same warp to the
+    checkpoint's `image_size`, the same softmax postprocess. Its matrices (a
+    billion parameters and more) are held in the policy's type (a float32
+    tree cast at every use would stream twice the bytes a deployment does):
+    the tree is cast once, here."""
     dtype = backbone_dtype()  # the body is the model, as in _build_yolos
     return BuiltDetector(
         model_name=model_name,
-        module=Qwen3NextDetector(cfg, dtype=dtype),
+        module=module_cls(cfg, dtype=dtype),
         params=hold_matrices_in(params, dtype),
         preprocess_spec=PreprocessSpec(
             mode="fixed", size=cfg.image_size, mean=IMAGENET_MEAN, std=IMAGENET_STD
@@ -320,6 +314,51 @@ def _build_qwen3_next_det(model_name: str) -> BuiltDetector:
         id2label=cfg.id2label_dict,
         num_top_queries=cfg.num_detection_tokens,
     )
+
+
+def _build_qwen3_next_det(model_name: str) -> BuiltDetector:
+    """Qwen3-Next's decoder layers as a detector body (0.97 B parameters at
+    one chip's share of the experts)."""
+    if os.environ.get(TINY_ENV):
+        cfg = tiny_qwen3_next_det_config()
+        params = _init_random(Qwen3NextDetector(cfg), cfg.image_size)
+        logger.info("Built tiny random qwen3_next_det for %s (%s)", model_name, TINY_ENV)
+    else:
+        from spotter_tpu.convert.loader import load_qwen3_next_det
+
+        cfg, params = load_qwen3_next_det(model_name)
+    return _held_detector(model_name, Qwen3NextDetector, cfg, params)
+
+
+def tiny_lfm2_moe_det_config(num_labels: int = 80) -> Lfm2MoeDetConfig:
+    return Lfm2MoeDetConfig(
+        hidden_size=32,
+        intermediate_size=48,
+        moe_intermediate_size=16,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        num_experts=8,
+        num_experts_per_tok=2,
+        image_size=(32, 48),
+        patch_size=8,
+        num_detection_tokens=5,
+        num_labels=num_labels,
+        id2label=tuple(coco_id2label_80().items()),
+    )
+
+
+def _build_lfm2_moe_det(model_name: str) -> BuiltDetector:
+    """LFM2-MoE's decoder layers as a detector body (1.6 B parameters at six
+    layers, every expert held)."""
+    if os.environ.get(TINY_ENV):
+        cfg = tiny_lfm2_moe_det_config()
+        params = _init_random(Lfm2MoeDetector(cfg), cfg.image_size)
+        logger.info("Built tiny random lfm2_moe_det for %s (%s)", model_name, TINY_ENV)
+    else:
+        from spotter_tpu.convert.loader import load_lfm2_moe_det
+
+        cfg, params = load_lfm2_moe_det(model_name)
+    return _held_detector(model_name, Lfm2MoeDetector, cfg, params)
 
 
 def tiny_owlvit_config() -> OwlViTConfig:
@@ -683,6 +722,11 @@ register(ModelFamily(
     # is in the checkpoint's config), and parallel/sharding.py has no such axis
     name="qwen3_next_det", matches=("qwen3-next-det", "qwen3_next_det"),
     build=_build_qwen3_next_det,
+))
+register(ModelFamily(
+    # no tp rules, as above: every layer is held whole on its chip
+    name="lfm2_moe_det", matches=("lfm2-moe-det", "lfm2_moe_det"),
+    build=_build_lfm2_moe_det,
 ))
 register(
     # plain DETR (+ Table-Transformer, a pre-norm DETR with identical keys)

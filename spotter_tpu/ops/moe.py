@@ -1,8 +1,9 @@
 """A routed-expert layer for a chip that holds a share of the experts.
 
 The router is the whole model's: every token is scored against all E experts
-(softmax over E in float32), takes its k best, and the k probabilities are
-renormalised to sum to one, as published. This chip holds the experts
+in float32 (softmax over E, or a sigmoid of each logit), takes its k best (by
+score, or by score plus a selection bias that does not enter the weight), and
+the k scores are renormalised to sum to one, as published. This chip holds the experts
 `[offset, offset + n_local)`. It computes, for each token, the terms of the
 experts it holds,
 
@@ -67,23 +68,53 @@ _TILE_N = 512
 _LANES = 128
 
 
-def route(x, router, top_k: int, normalise: bool = True):
-    """x: (M, d); router: (d, E). Returns (weights (M, k) float32, experts
-    (M, k) int32): each token's k most probable experts of all E."""
+def router_scores(x, router, scoring: str = "softmax"):
+    """x: (M, d); router: (d, E). Each token's score for every expert, (M, E)
+    float32: the softmax over E of the router's logits, or their sigmoid."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    weights, experts = _top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return jax.nn.softmax(logits, axis=-1) if scoring == "softmax" else jax.nn.sigmoid(logits)
+
+
+def select(scores, top_k: int, normalise: bool = True, bias=None, eps: float = 0.0,
+           scale: float = 1.0):
+    """scores: (M, E) from `router_scores`. Returns (weights (M, k) float32, experts
+    (M, k) int32): each token's k best experts and its scores for them,
+    renormalised to sum to one (over `+ eps`, where a source adds one) and
+    scaled. `bias` (E,) enters the choice and not the weight: the k best by
+    `scores + bias`, weighed by `scores`."""
+    if bias is None:
+        weights, experts = _top_k(scores, top_k)
+    else:
+        _, experts = _top_k(scores + bias.astype(jnp.float32), top_k, floor=-jnp.inf)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if normalise:
-        weights = weights / weights.sum(-1, keepdims=True)
-    return weights, experts
+        total = weights.sum(-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
+    return weights * scale if scale != 1.0 else weights, experts
 
 
-def _top_k(probs, k: int):
+def route(x, router, top_k: int, normalise: bool = True, scoring: str = "softmax",
+          bias=None, eps: float = 0.0, scale: float = 1.0):
+    """x: (M, d); router: (d, E). Returns (weights (M, k) float32, experts
+    (M, k) int32): `select` over `router_scores`. The defaults are the one router
+    PR 28 had: each token's k most probable experts of all E."""
+    return select(router_scores(x, router, scoring), top_k, normalise, bias, eps, scale)
+
+
+def moved_by_bias(scores, experts):
+    """How many of each token's selections (`experts`, (M, k), chosen with a
+    bias) are not among the k best of the unbiased `scores`: (M,) int32."""
+    _, plain = _top_k(scores, experts.shape[-1])
+    return (experts[..., :, None] != plain[..., None, :]).all(-1).sum(-1, dtype=jnp.int32)
+
+
+def _top_k(probs, k: int, floor: float = -1.0):
     """`lax.top_k`'s answer (descending, the lower index first among equals)
     by k passes of max-and-mask. On a TPU `lax.top_k` over E = 512 sorts the
     whole row: 37 ms a layer at 137600 tokens, a tenth of the step (device
     trace, PR 28); k = 10 passes over the same array read it ten times and
-    sort nothing."""
+    sort nothing. `floor` masks a taken entry: under every value of `probs`."""
     e = probs.shape[-1]
     ids = lax.broadcasted_iota(jnp.int32, probs.shape, probs.ndim - 1)
     values, indices = [], []
@@ -92,7 +123,7 @@ def _top_k(probs, k: int):
         index = jnp.min(jnp.where(probs == best, ids, e), axis=-1, keepdims=True)
         values.append(best)
         indices.append(index)
-        probs = jnp.where(ids == index, -1.0, probs)
+        probs = jnp.where(ids == index, floor, probs)
     return jnp.concatenate(values, -1), jnp.concatenate(indices, -1)
 
 

@@ -236,15 +236,21 @@ def _expert_layer(d, m=90, inter=16, seed=2):
 
 
 # 32: the sums stay (tokens, d); 256: they are carried as (tokens, 2, 128)
+# the router PR 28 had, and PR 33's: sigmoid scores, a bias on the choice alone
+ROUTERS = {"softmax": {}, "sigmoid_biased": {
+    "scoring": "sigmoid", "bias": np.linspace(-0.3, 0.3, 8, dtype=np.float32), "eps": 1e-6}}
+
+
+@pytest.mark.parametrize("router_kind", ROUTERS)
 @pytest.mark.parametrize("d", [32, 256])
 @pytest.mark.parametrize("impl", ["einsum", "pallas"])
-def test_routed_experts_never_drop_a_token(impl, d):
+def test_routed_experts_never_drop_a_token(impl, d, router_kind):
     """Windows smaller than the routed rows, a skewed router (most tokens on
-    one expert), both implementations, both layouts of the sums: every held
-    assignment is computed."""
+    one expert), both implementations, both layouts of the sums, both
+    routers: every held assignment is computed."""
     k = 3
     x, router, gate_up, down = _expert_layer(d)
-    weights, experts = moe.route(x, router, k)
+    weights, experts = moe.route(x, router, k, **ROUTERS[router_kind])
     got = moe.routed_experts(x, weights, experts, gate_up, down, offset=4, tile=8,
                              window_rows=32, impl=impl, interpret=True)
     assert got.shape == x.shape and got.dtype == jnp.float32
@@ -307,13 +313,15 @@ def test_the_jitted_layer_carries_its_sums_in_the_layout_of_the_width(d, row):
     assert jaxpr.out_avals[0].shape == (m, d) and jaxpr.out_avals[0].dtype == jnp.float32
 
 
-def test_causal_gqa_kernel_in_interpret_mode():
+# Qwen3-Next's shape class (a lane tile and more a head) and LFM2's (half a lane tile, four to a group)
+@pytest.mark.parametrize("h, kv, hd", [(4, 2, 128), (8, 2, 64)])
+def test_causal_gqa_kernel_in_interpret_mode(h, kv, hd):
     """The splash kernel's multi-query form under a causal mask, as the TPU
     path calls it, against eager attention (interpret mode, CPU)."""
     from spotter_tpu.models.layers import causal_gqa_attention
 
     rng = np.random.default_rng(4)
-    b, s, h, kv, hd = 1, 200, 4, 2, 128
+    b, s = 1, 200
     q = rng.standard_normal((b, s, h, hd)).astype(np.float32) * hd**-0.5
     k, v = (rng.standard_normal((b, s, kv, hd)).astype(np.float32) for _ in range(2))
     got = causal_gqa_attention(q, k, v, interpret=True)
@@ -325,12 +333,15 @@ def test_causal_gqa_kernel_in_interpret_mode():
     np.testing.assert_allclose(got, np.einsum("bhqk,bkhd->bqhd", probs, vv), atol=2e-5)
 
 
-@pytest.mark.parametrize("name", ["qwen3-next-det-ep8", "/ckpt/qwen3_next_det_ep8-0123abcd",
-                                   "org/Qwen3_Next_Det"])
-def test_registry_resolves_the_family(name):
+@pytest.mark.parametrize("name, family", [
+    ("qwen3-next-det-ep8", "qwen3_next_det"), ("/ckpt/qwen3_next_det_ep8-0123abcd", "qwen3_next_det"),
+    ("org/Qwen3_Next_Det", "qwen3_next_det"), ("lfm2-moe-det-pp4", "lfm2_moe_det"),
+    ("/root/repo/.bench_work/checkpoints/lfm2_moe_det_pp4-0123abcd", "lfm2_moe_det"),
+    ("org/LFM2_MoE_Det", "lfm2_moe_det")])
+def test_registry_resolves_the_family(name, family):
     from spotter_tpu.models.registry import family_for
 
-    assert family_for(name).name == "qwen3_next_det"
+    assert family_for(name).name == family
 
 
 def test_registry_keeps_yolos_and_rtdetr():

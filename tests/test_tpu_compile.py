@@ -11,8 +11,9 @@ say nothing about results or times.
 Shapes are the published ones: the RT-DETRv2-R101 decoder's sampling at serving
 batch 8, the ViT towers' token counts (YOLOS-base 4396, OWLv2-B/16 3601), the
 OWL logit head at OWLv2-B/16 (3600 patches) and OWL-ViT-B/32 (576) against the
-22-amenity vocabulary. The whole R101 engine program per bucket (~30 s each)
-is under `-m slow`.
+22-amenity vocabulary, LFM2-8B-A1B's expert and attention shapes and its six
+served layers whole at the bucket of 8 (~55 s). The whole R101 engine program
+per bucket (~30 s each) is under `-m slow`.
 """
 
 import os
@@ -29,7 +30,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import spotter_tpu.models.rtdetr as rtdetr_mod
 from spotter_tpu.models import layers
-from spotter_tpu.ops import delta_rule, msda
+from spotter_tpu.ops import delta_rule, moe, msda
 from spotter_tpu.ops.openvocab import fused_class_logits
 
 
@@ -128,6 +129,61 @@ def test_delta_rule_kernel_at_qwen3_next_shapes(chip):
     compiled = compile_for(chip, fn, qk, qk, ((8, 4300, 32, 128), jnp.bfloat16), scalars, scalars)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "gated_delta_rule_kernel" in text
+
+
+@pytest.mark.parametrize("k, n", [(2048, 3584), (1792, 2048)], ids=["gate_up", "down"])
+def test_expert_matmul_kernel_at_lfm2_moe_shapes(chip, k, n):
+    """A window's two grouped products at d 2048, I 1792 with all 32 experts
+    held: 8192 rows in 64 tiles, each against its own expert's (K, N) matrix in
+    blocks 512 wide (whether they fit VMEM at K 1792 only this compile says)."""
+    fn = partial(moe.expert_matmul, tile=moe.ROW_TILE, impl="pallas")
+    tiles = ((moe.WINDOW_ROWS // moe.ROW_TILE,), jnp.int32)
+    compiled = compile_for(chip, fn, ((moe.WINDOW_ROWS, k), jnp.bfloat16),
+                           ((32, k, n), jnp.bfloat16), tiles, tiles)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "expert_matmul_kernel" in text
+
+
+def test_causal_gqa_kernel_at_lfm2_moe_shapes(chip):
+    """The attention layer's call at the bucket of 8: 4300 tokens, 32 query
+    heads over 8 key-value heads, 64 wide: half a lane tile a head."""
+    q, kv = ((8, 4300, 32, 64), jnp.bfloat16), ((8, 4300, 8, 64), jnp.bfloat16)
+    text = compile_for(chip, layers.causal_gqa_attention, q, kv, kv).as_text()
+    assert "tpu_custom_call" in text and "splash_mqa_fwd" in text
+    # the shape benchmarks/kernels/causal_gqa_attention.py reads the bucket from
+    assert "bf16[8,8,4,4608,64]" in text
+
+
+def test_lfm2_moe_program_at_the_bucket_of_8(chip, monkeypatch):
+    """The six served layers at the published widths, bfloat16 held, one
+    bucket: every kernel inside one program the chip's compiler accepts, in
+    the memory of one chip. `jax.default_backend()` is the CPU here, so the
+    two call sites that ask it are steered onto the kernels the chip runs."""
+    import json
+    import pathlib
+
+    from spotter_tpu.models import lfm2_moe
+    from spotter_tpu.models.configs import Lfm2MoeDetConfig
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = Lfm2MoeDetConfig.from_hf(json.loads(
+        (root / "benchmarks" / "configs" / "lfm2_moe_det_pp4.json").read_text()))
+    monkeypatch.setattr(lfm2_moe, "flash_attention_enabled", lambda: True)
+    monkeypatch.setattr(moe, "routed_experts", partial(moe.routed_experts, impl="pallas"))
+    module = lfm2_moe.Lfm2MoeDetector(cfg, dtype=jnp.bfloat16)
+    h, w = cfg.image_size
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, h, w, 3), jnp.float32))["params"])
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes)) == 1_610_819_936
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim >= 2 else jnp.float32, sharding=chip), shapes)
+    pixels = jax.ShapeDtypeStruct((8, h, w, 3), jnp.float32, sharding=chip)
+    compiled = jax.jit(lambda p, x: module.apply({"params": p}, x)).lower(params, pixels).compile()
+    text = compiled.as_text()
+    assert "expert_matmul_kernel" in text and "splash_mqa_fwd" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16 * 2**30
 
 
 @pytest.mark.parametrize("patches", [3600, 576], ids=["owlv2-b16", "owlvit-b32"])
