@@ -205,20 +205,74 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer(params, tokens):
     np.testing.assert_allclose(total, ref.sparse_moe(p, x, CFG), atol=ATOL)
 
 
+def _silu(a):
+    return a / (1 + np.exp(-a))
+
+
 @pytest.mark.parametrize("k, n", [(2048, 3584), (1792, 2048)], ids=["gate_up", "down"])
 def test_expert_matmul_kernel_in_interpret_mode_at_the_published_expert_width(k, n):
-    """The two products of a window at d 2048, I 1792: three row tiles of
-    128, two experts, one tile dead; the kernel's blocks (512 wide) divide
-    3584 and 2048."""
+    """The two calls of a window at d 2048, I 1792 in the forms it makes them:
+    gate | up with the SwiGLU on the way out, (rows, 1792); the down product
+    with each row's weight, written as (rows, 16, 128). Three row tiles of
+    128, two experts, one tile dead, a live tile whose last rows are dead."""
     rng = np.random.default_rng(k)
     x = rng.standard_normal((384, k)).astype(np.float32)
     w = rng.standard_normal((3, k, n)).astype(np.float32) / np.sqrt(k)
-    tile_expert, tile_live = np.array([2, 0, 1], np.int32), np.array([1, 1, 0], np.int32)
-    got = moe.expert_matmul(x, w, tile_expert, tile_live, 128, impl="pallas", interpret=True)
-    want = moe.expert_matmul(x, w, tile_expert, tile_live, 128, impl="einsum")
+    tile_expert, tile_live = np.array([2, 0, 1], np.int32), np.array([128, 100, 0], np.int32)
+    form = {"swiglu": True} if n == 3584 else {"row_weight": rng.uniform(0.1, 1, 384).astype(np.float32)}
+    got = moe.expert_matmul(x, w, tile_expert, tile_live, 128, impl="pallas", interpret=True, **form)
+    want = moe.expert_matmul(x, w, tile_expert, tile_live, 128, impl="einsum", **form)
     np.testing.assert_allclose(got, want, atol=1e-4)
-    np.testing.assert_allclose(got[:128], x[:128] @ w[2], atol=1e-4)
+    if n == 3584:
+        assert got.shape == (384, 1792)
+        np.testing.assert_allclose(got[:128], _silu(x[:128] @ w[2, :, :1792]) * (x[:128] @ w[2, :, 1792:]), atol=1e-4)
+    else:
+        assert got.shape == (384, 16, 128)
+        np.testing.assert_allclose(np.asarray(got[:128]).reshape(128, n),
+                                   (x[:128] @ w[2]) * form["row_weight"][:128, None], atol=1e-4)
+        assert np.asarray(got[128:228]).any() and not np.asarray(got[228:256]).any()
+        plain = moe.expert_matmul(x, w, tile_expert, tile_live, 128, impl="pallas", interpret=True)
+        np.testing.assert_allclose(plain[:256], np.concatenate([x[:128] @ w[2], x[128:256] @ w[0]]), atol=1e-4)
     assert not np.asarray(got[256:]).any()
+
+
+@pytest.mark.parametrize("inter", [1792, 512], ids=["lfm2", "qwen3_next"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_first_call_does_its_swiglu_on_the_way_out(inter, dtype):
+    """K 2048 against both families' expert widths (the column block is 256 at
+    either: 512 does not divide 1792): `silu(x @ gate) * (x @ up)` in float32,
+    cast once to the type asked for; the kernel, the einsum form and the
+    equation agree, and a dead tile comes back zero."""
+    rng = np.random.default_rng(inter)
+    x = jnp.asarray(rng.standard_normal((256, 2048)), dtype)
+    w = jnp.asarray(rng.standard_normal((2, 2048, 2 * inter)) / np.sqrt(2048), dtype)
+    tile_expert, tile_live = np.array([1, 0], np.int32), np.array([128, 0], np.int32)
+    got = moe.expert_matmul(x, w, tile_expert, tile_live, 128, out_dtype=dtype, swiglu=True,
+                            impl="pallas", interpret=True)
+    einsum = moe.expert_matmul(x, w, tile_expert, tile_live, 128, out_dtype=dtype, swiglu=True, impl="einsum")
+    assert got.shape == (256, inter) and got.dtype == dtype == einsum.dtype
+    x32, w32 = np.asarray(x, np.float32), np.asarray(w[1], np.float32)
+    want = _silu(x32[:128] @ w32[:, :inter]) * (x32[:128] @ w32[:, inter:])
+    close = {"atol": 1e-4} if dtype == jnp.float32 else {"rtol": 2**-7, "atol": 1e-3}  # one rounding to 8 bits
+    np.testing.assert_allclose(np.asarray(got[:128], np.float32), want, **close)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(einsum, np.float32), **close)
+    assert not np.asarray(got[128:], np.float32).any()
+
+
+@pytest.mark.parametrize("impl", ["einsum", "pallas"])
+def test_a_dead_row_inside_a_live_tile_is_exactly_zero_when_its_product_is_inf(impl):
+    """The weighted call masks with a `where`, not with a weight of zero:
+    PR 28's first served run answered NaN, and `inf * 0` is one."""
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((16, 128)).astype(np.float32)
+    x[5:8] = np.inf  # dead rows of the first tile: whatever the gather left there
+    w = rng.standard_normal((2, 128, 256)).astype(np.float32)
+    got = np.asarray(moe.expert_matmul(
+        x, w, np.array([1, 0], np.int32), np.array([5, 8], np.int32), 8,
+        row_weight=np.zeros(16, np.float32) + 0.5, impl=impl, interpret=True))
+    assert got.shape == (16, 2, 128) and np.isfinite(got).all()
+    assert not got[5:8].any() and got[:5].all() and got[8:].all()
+    np.testing.assert_allclose(got[:5].reshape(5, 256), 0.5 * (x[:5] @ w[1]), atol=1e-4)
 
 
 def _median_box_gap(module_dtype, params, pixels):

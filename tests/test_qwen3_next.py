@@ -313,6 +313,61 @@ def test_the_jitted_layer_carries_its_sums_in_the_layout_of_the_width(d, row):
     assert jaxpr.out_avals[0].shape == (m, d) and jaxpr.out_avals[0].dtype == jnp.float32
 
 
+def test_a_window_searches_nothing_and_reads_no_table_of_the_experts():
+    """A tile belongs to one expert, so the plan is made by the tile once a
+    layer: the window loop's body holds no nested `while` (the search for each
+    row's expert) and no gather from a table of `n_local` entries (a row's
+    padded start, count, first assignment). What it still gathers: the sorted
+    assignments, the tokens' rows, their weights (and, in the einsum form, the
+    tiles' matrices)."""
+    m, k, n_local, inter, d = 512, 10, 8, 64, 256
+    args = (jax.ShapeDtypeStruct((m, d), jnp.bfloat16), jax.ShapeDtypeStruct((m, k), jnp.float32),
+            jax.ShapeDtypeStruct((m, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_local, d, 2 * inter), jnp.bfloat16),
+            jax.ShapeDtypeStruct((n_local, inter, d), jnp.bfloat16))
+    jaxpr = jax.make_jaxpr(functools.partial(moe.routed_experts, impl="einsum"))(*args)
+    (loop,) = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "while"]
+    body = list(_all_eqns(loop.params["body_jaxpr"].jaxpr))
+    assert not [eqn for eqn in body if eqn.primitive.name == "while"]
+    gathered = [eqn.invars[0].aval.shape for eqn in body if eqn.primitive.name == "gather"]
+    assert sorted(gathered) == sorted([(m * k,), (m * k,), (m, d), (n_local, d, 2 * inter), (n_local, inter, d)])
+    assert [eqn.primitive.name for eqn in body].count("dynamic_slice") == 3  # the window's tiles of the plan
+
+
+def _plan_by_row(counts, start, tile, rows):
+    """The plan a row at a time, as PR 29's window made it: each padded row's
+    expert by a search over the padded ends, then its place and whether it is
+    live from the experts' tables."""
+    padded = -(-counts // tile) * tile
+    padded_end = np.cumsum(padded)
+    r = np.arange(rows)
+    e = np.minimum(np.searchsorted(padded_end, r, side="right"), len(counts) - 1)
+    within = r - (padded_end - padded)[e]
+    live = (within < counts[e]) & (r < padded_end[-1])
+    return e, np.where(live, start[e] + within, 0), live
+
+
+@pytest.mark.parametrize("counts", [
+    [70, 3, 1, 2], [9, 0, 0, 14], [16, 8, 24, 5], [5, 6, 0, 0], [0, 0, 0, 0]],
+    ids=["skewed", "experts_with_no_rows", "ends_exactly_on_a_tile", "a_dead_last_window", "nothing_held"])
+def test_the_plan_by_the_tile_is_the_plan_by_the_row(counts):
+    """`_plan_by_tile` against the per-row plan written out in numpy, over
+    whole windows of 4 tiles of 8 rows to the worst case's end."""
+    tile, per_window = 8, 4
+    counts = np.asarray(counts, np.int32)
+    start = (np.cumsum(counts) - counts).astype(np.int32)
+    worst = -(-(int(counts.sum()) + len(counts) * (tile - 1)) // tile)
+    tiles = -(-(worst + 9) // per_window) * per_window  # and windows past every live row
+    expert, first, live_rows = map(np.asarray, moe._plan_by_tile(counts, start, tile, tiles))
+    e, source, live = _plan_by_row(counts, start, tile, tiles * tile)
+    lane = np.arange(tile)
+    np.testing.assert_array_equal((lane < live_rows[:, None]).reshape(-1), live)
+    np.testing.assert_array_equal(np.where(live, (first[:, None] + lane).reshape(-1), 0), source)
+    np.testing.assert_array_equal(expert[live_rows > 0], e[::tile][live_rows > 0])
+    assert expert.min() >= 0 and expert.max() < len(counts)
+    assert live.sum() == counts.sum() and not live_rows[int((-(-counts // tile)).sum()):].any()
+
+
 # Qwen3-Next's shape class (a lane tile and more a head) and LFM2's (half a lane tile, four to a group)
 @pytest.mark.parametrize("h, kv, hd", [(4, 2, 128), (8, 2, 64)])
 def test_causal_gqa_kernel_in_interpret_mode(h, kv, hd):

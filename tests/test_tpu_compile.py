@@ -131,17 +131,29 @@ def test_delta_rule_kernel_at_qwen3_next_shapes(chip):
     assert "tpu_custom_call" in text and "gated_delta_rule_kernel" in text
 
 
-@pytest.mark.parametrize("k, n", [(2048, 3584), (1792, 2048)], ids=["gate_up", "down"])
-def test_expert_matmul_kernel_at_lfm2_moe_shapes(chip, k, n):
-    """A window's two grouped products at d 2048, I 1792 with all 32 experts
-    held: 8192 rows in 64 tiles, each against its own expert's (K, N) matrix in
-    blocks 512 wide (whether they fit VMEM at K 1792 only this compile says)."""
-    fn = partial(moe.expert_matmul, tile=moe.ROW_TILE, impl="pallas")
+@pytest.mark.parametrize("experts, k, n, form, result", [
+    (32, 2048, 3584, {"swiglu": True, "out_dtype": jnp.bfloat16}, "bf16[8192,1792]"),
+    (32, 1792, 2048, {}, "f32[8192,16,128]"),
+    (64, 2048, 1024, {"swiglu": True, "out_dtype": jnp.bfloat16}, "bf16[8192,512]"),
+], ids=["gate_up", "down", "gate_up_qwen3_next"])
+def test_expert_matmul_kernel_at_lfm2_moe_shapes(chip, experts, k, n, form, result):
+    """A window's two grouped products in the forms it makes them, at d 2048,
+    I 1792 with all 32 experts held: 8192 rows in 64 tiles, each against its
+    own expert's matrix. Gate | up in two blocks 256 wide with the SwiGLU on
+    the way out, written in the served type; the down product with each row's
+    weight, written in the sums' layout (blocks 1024 wide at K 1792: whether
+    they fit VMEM, and whether Mosaic turns (8, 128) tiles of rows into a
+    row's (8, 128) tile of column blocks, only this compile says). And the
+    first call at Qwen3-Next's I 512 against 64 held experts."""
+    def fn(x, w, tile_expert, tile_live, *row_weight):
+        return moe.expert_matmul(x, w, tile_expert, tile_live, moe.ROW_TILE, impl="pallas",
+                                 row_weight=row_weight[0] if row_weight else None, **form)
+
     tiles = ((moe.WINDOW_ROWS // moe.ROW_TILE,), jnp.int32)
-    compiled = compile_for(chip, fn, ((moe.WINDOW_ROWS, k), jnp.bfloat16),
-                           ((32, k, n), jnp.bfloat16), tiles, tiles)
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text and "expert_matmul_kernel" in text
+    weights = [] if form else [((moe.WINDOW_ROWS,), jnp.float32)]
+    text = compile_for(chip, fn, ((moe.WINDOW_ROWS, k), jnp.bfloat16), ((experts, k, n), jnp.bfloat16),
+                       tiles, tiles, *weights).as_text()
+    assert "tpu_custom_call" in text and "expert_matmul_kernel" in text and result in text
 
 
 def test_causal_gqa_kernel_at_lfm2_moe_shapes(chip):
