@@ -20,6 +20,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    KimiLinearDetConfig,
     Lfm2MoeDetConfig,
     OwlViTConfig,
     Qwen3NextDetConfig,
@@ -330,3 +331,9 @@ def load_lfm2_moe_det(model_name: str) -> tuple[Lfm2MoeDetConfig, dict]:
     from spotter_tpu.convert.lfm2_moe_rules import convert_lfm2_moe
 
     return load_config_and_safetensors(model_name, Lfm2MoeDetConfig, convert_lfm2_moe)
+
+
+def load_kimi_linear_det(model_name: str) -> tuple[KimiLinearDetConfig, dict]:
+    from spotter_tpu.convert.kimi_linear_rules import convert_kimi_linear
+
+    return load_config_and_safetensors(model_name, KimiLinearDetConfig, convert_kimi_linear)

@@ -66,7 +66,7 @@ REBUILD_GATE_WAIT_S = 300.0
 
 # Per-image counters a module may return beside "logits" and "pred_boxes";
 # the engine fetches them with the detections and adds them to /metrics.
-PROGRAM_COUNTERS = ("moe_expert_tokens", "moe_assignments", "moe_bias_moved")
+PROGRAM_COUNTERS = ("moe_expert_tokens", "moe_assignments", "moe_bias_moved", "kda_gate_spread")
 
 POSTPROCESS_KINDS = {
     "sigmoid_topk": sigmoid_topk_postprocess,      # RT-DETR family
@@ -253,6 +253,7 @@ class InferenceEngine:
         # oom_downgrade / rebuild tag every compile-ledger entry with WHY
         # the program compiled.
         self._compile_src = threading.local()
+        self._counter_names: tuple = ()  # the counters this program returns, set at its trace
         post_fn = POSTPROCESS_KINDS[built.postprocess]
         k = built.num_top_queries
 
@@ -261,7 +262,8 @@ class InferenceEngine:
             out = built.module.apply({"params": params}, *args, **built.apply_kwargs)
             # what a program counts of itself (a routed-expert layer's
             # tokens per expert) rides back behind the detections, per image
-            counters = tuple(out[name] for name in PROGRAM_COUNTERS if name in out)
+            self._counter_names = tuple(name for name in PROGRAM_COUNTERS if name in out)
+            counters = tuple(out[name] for name in self._counter_names)
             # named scopes are op metadata only: they put the program's
             # sections on a device trace's op names, and change no program
             with jax.named_scope("postprocess"):
@@ -1114,7 +1116,8 @@ class InferenceEngine:
                 for dets in out
             ]
             if counters:  # already on the host: the fetch above brought them
-                self.metrics.record_moe(*(counter[:n] for counter in counters))
+                self.metrics.record_program_counters(
+                    {name: counter[:n] for name, counter in zip(self._counter_names, counters)})
         batch.stages[obs.POSTPROCESS] = post.seconds
         batch.total.stop()
         self.metrics.record_batch(

@@ -213,6 +213,9 @@ class Metrics:
         # routing, per batch, over the images the batch really held
         self._moe = {"assignments": 0, "assignments_local": 0, "bias_moved": 0,
                      "expert_tokens_max": 0, "expert_tokens_mean": 0.0}
+        # per-channel gates of a delta-rule mixer (PR 35): how far apart a
+        # head's channels decay, summed over the heads counted beside it
+        self._kda = {"gate_spread": 0.0, "gate_heads": 0}
         # host staging slabs (ISSUE 27): leases, and how many of them found
         # the free-list empty and allocated; the rest reused a slab
         self._slab_leases_total = 0
@@ -333,6 +336,18 @@ class Metrics:
         # misses = bad). `SPOTTER_TPU_PERF_LEDGER=0` makes every perf
         # record a no-op while keeping the snapshot keys present.
         self.perf = PerfLedger()
+
+    def record_program_counters(self, counters: dict) -> None:
+        """What a program counted of itself, by the name it returned it under
+        (`engine.PROGRAM_COUNTERS`), over the images a batch really held."""
+        if "moe_expert_tokens" in counters:
+            self.record_moe(counters["moe_expert_tokens"], counters["moe_assignments"],
+                            counters.get("moe_bias_moved"))
+        spread = counters.get("kda_gate_spread")
+        if spread is not None:  # (images, layers, heads): a token-mean of max - min of -g
+            with self._lock:
+                self._kda["gate_spread"] += float(spread.sum())
+                self._kda["gate_heads"] += int(spread.size)
 
     def record_moe(self, expert_tokens, assignments, bias_moved=None) -> None:
         """`expert_tokens`: (images, layers, held experts), the tokens of each
@@ -781,6 +796,8 @@ class Metrics:
                 "moe_bias_moved_total": self._moe["bias_moved"],
                 "moe_expert_tokens_max_total": self._moe["expert_tokens_max"],
                 "moe_expert_tokens_mean_total": round(self._moe["expert_tokens_mean"], 3),
+                "kda_gate_spread_total": round(self._kda["gate_spread"], 4),
+                "kda_gate_heads_total": self._kda["gate_heads"],
                 "staging_slab_leases_total": self._slab_leases_total,
                 "staging_slab_allocs_total": self._slab_allocs_total,
                 "starved_staging_s_total": round(starved_staging_s, 6),

@@ -724,3 +724,84 @@ class Lfm2MoeDetConfig:
         if hf.get("head_dim", cfg.head_dim) != cfg.head_dim:
             raise ValueError(f"head_dim {hf['head_dim']} is not hidden_size / heads = {cfg.head_dim}")
         return cfg
+
+
+@dataclass(frozen=True)
+class KimiLinearDetConfig:
+    """Kimi Linear's decoder layers as a detector body (`kimi_linear_det`):
+    the language model's widths under their published keys (`kimi_linear`'s
+    config.json; its `linear_attn_config` group flattened to `linear_*` and
+    the two layer lists, which count layers from 1 as published), the
+    detector's seams under YOLOS's. `num_routed_experts` is the router's
+    width, `num_experts` how many of them this chip holds, from
+    `expert_offset` on (`models/kimi_linear.py`, `ops/moe.py`)."""
+
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 5
+    kda_layers: tuple[int, ...] = (1, 2, 3, 5)
+    full_attn_layers: tuple[int, ...] = (4,)
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    linear_head_dim: int = 128
+    linear_num_heads: int = 32
+    linear_conv_kernel: int = 4
+    gate_low_rank_dim: int = 128
+    rms_norm_eps: float = 1e-5
+    num_routed_experts: int = 256
+    num_experts: int = 64
+    expert_offset: int = 0
+    num_experts_per_token: int = 8
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.446
+    image_size: tuple[int, int] = (800, 1344)
+    patch_size: int = 16
+    num_channels: int = 3
+    num_detection_tokens: int = 100
+    num_labels: int = 91
+    id2label: tuple[tuple[int, str], ...] = ()
+
+    @property
+    def id2label_dict(self) -> dict[int, str]:
+        return dict(self.id2label)
+
+    @property
+    def num_tokens(self) -> int:
+        h, w = self.image_size
+        return (h // self.patch_size) * (w // self.patch_size) + self.num_detection_tokens
+
+    def layer_kind(self, i: int) -> str:
+        """Layer i, counted from 0: "kda" or "mla"."""
+        return "kda" if i + 1 in self.kda_layers else "mla"
+
+    @classmethod
+    def from_hf(cls, hf: dict) -> "KimiLinearDetConfig":
+        """From a checkpoint's config.json as a dict: the installed
+        transformers has no class for this model_type."""
+        names = {f.name for f in fields(cls)} - {"id2label", "image_size"}
+        linear = hf["linear_attn_config"]
+        if hf.get("num_routed_experts") is None:
+            hf = {**hf, "num_routed_experts": hf["num_experts"]}
+        cfg = cls(
+            image_size=tuple(hf["image_size"]),
+            id2label=tuple(sorted((int(k), v) for k, v in hf.get("id2label", {}).items())),
+            **{**{k: hf[k] for k in names if k in hf},
+               "kda_layers": tuple(linear["kda_layers"]),
+               "full_attn_layers": tuple(linear["full_attn_layers"]),
+               "linear_head_dim": linear["head_dim"], "linear_num_heads": linear["num_heads"],
+               "linear_conv_kernel": linear["short_conv_kernel_size"]},
+        )
+        layers = sorted(cfg.kda_layers + cfg.full_attn_layers)
+        if layers != list(range(1, cfg.num_hidden_layers + 1)):
+            raise ValueError(f"kda_layers and full_attn_layers name {layers}, "
+                             f"not each of {cfg.num_hidden_layers} layers once")
+        for key, want in (("q_lora_rank", None), ("mla_use_nope", True), ("num_shared_experts", 1),
+                          ("moe_router_activation_func", "sigmoid"), ("num_expert_group", 1)):
+            if hf.get(key, want) != want:
+                raise ValueError(f"{key} {hf[key]!r} is not served: this family computes {want!r}")
+        return cfg

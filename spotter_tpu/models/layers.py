@@ -745,3 +745,44 @@ def grid_sample_bilinear_nhwc(value: jnp.ndarray, grid: jnp.ndarray) -> jnp.ndar
     top = v00 * (1 - wx) + v01 * wx
     bot = v10 * (1 - wx) + v11 * wx
     return top * (1 - wy) + bot * wy
+
+
+@jax.named_scope("causal_attention")
+def causal_latent_attention(q, k, v, interpret: bool = False):
+    """Causal multi-head attention whose value width is not its key width
+    (latent attention expanded a head: keys of 192, values of 128): q, k (B,
+    S, H, dqk), q pre-scaled; v (B, S, H, dv) -> (B, S, H, dv). The splash
+    kernel's multi-head form (`splash_mha_fwd_no_residuals` on a device
+    trace) with `head_dim_v` the values' own, once per image under vmap, under
+    a causal mask, so the blocks above the diagonal are never visited. Below
+    `causal_gqa_attention`, whose lines stay where they are. S is padded to a
+    multiple of the block as there."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as _sk,
+    )
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as _sm,
+    )
+
+    b, s, h, _ = q.shape
+    blk = min(_CAUSAL_BLOCK, -(-s // 128) * 128)
+    s_pad = -(-s // blk) * blk
+    bs = _sk.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk,
+    )
+    kernel = _sk.make_splash_mha_single_device(
+        mask=_sm.MultiHeadMask([_sm.CausalMask((s_pad, s_pad))] * h),
+        block_sizes=bs,
+        interpret=interpret,
+    )
+
+    def prep(x):  # (B, S, H, d) -> (B, H, S_pad, d)
+        x = x.transpose(0, 2, 1, 3)
+        if s_pad != s:
+            x = jnp.pad(x, ((0, 0), (0, 0), (0, s_pad - s), (0, 0)))
+        return x
+
+    out = jax.vmap(kernel)(prep(q), prep(k), prep(v))
+    return out[:, :, :s].transpose(0, 2, 1, 3)

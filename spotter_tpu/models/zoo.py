@@ -33,6 +33,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    KimiLinearDetConfig,
     Lfm2MoeDetConfig,
     OwlViTConfig,
     OwlViTTextConfig,
@@ -46,6 +47,7 @@ from spotter_tpu.models.conditional_detr import ConditionalDetrDetector
 from spotter_tpu.models.dab_detr import DabDetrDetector
 from spotter_tpu.models.deformable_detr import DeformableDetrDetector
 from spotter_tpu.models.detr import DetrDetector
+from spotter_tpu.models.kimi_linear import KimiLinearDetector
 from spotter_tpu.models.lfm2_moe import Lfm2MoeDetector
 from spotter_tpu.models.owlvit import OwlViTDetector
 from spotter_tpu.models.qwen3_next import Qwen3NextDetector
@@ -359,6 +361,44 @@ def _build_lfm2_moe_det(model_name: str) -> BuiltDetector:
 
         cfg, params = load_lfm2_moe_det(model_name)
     return _held_detector(model_name, Lfm2MoeDetector, cfg, params)
+
+
+def tiny_kimi_linear_det_config(num_labels: int = 80) -> KimiLinearDetConfig:
+    return KimiLinearDetConfig(
+        hidden_size=32,
+        intermediate_size=48,
+        moe_intermediate_size=16,
+        num_attention_heads=2,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=4,
+        v_head_dim=8,
+        linear_head_dim=8,
+        linear_num_heads=4,
+        gate_low_rank_dim=8,
+        num_routed_experts=8,
+        num_experts=4,
+        num_experts_per_token=2,
+        image_size=(32, 48),
+        patch_size=8,
+        num_detection_tokens=5,
+        num_labels=num_labels,
+        id2label=tuple(coco_id2label_80().items()),
+    )
+
+
+def _build_kimi_linear_det(model_name: str) -> BuiltDetector:
+    """Kimi Linear's decoder layers as a detector body (2.1 B parameters at
+    five layers and one chip's quarter of the experts)."""
+    if os.environ.get(TINY_ENV):
+        cfg = tiny_kimi_linear_det_config()
+        params = _init_random(KimiLinearDetector(cfg), cfg.image_size)
+        logger.info("Built tiny random kimi_linear_det for %s (%s)", model_name, TINY_ENV)
+    else:
+        from spotter_tpu.convert.loader import load_kimi_linear_det
+
+        cfg, params = load_kimi_linear_det(model_name)
+    return _held_detector(model_name, KimiLinearDetector, cfg, params)
 
 
 def tiny_owlvit_config() -> OwlViTConfig:
@@ -727,6 +767,11 @@ register(ModelFamily(
     # no tp rules, as above: every layer is held whole on its chip
     name="lfm2_moe_det", matches=("lfm2-moe-det", "lfm2_moe_det"),
     build=_build_lfm2_moe_det,
+))
+register(ModelFamily(
+    # no tp rules, as above: every mixer is whole and the experts' share is the configuration's
+    name="kimi_linear_det", matches=("kimi-linear-det", "kimi_linear_det"),
+    build=_build_kimi_linear_det,
 ))
 register(
     # plain DETR (+ Table-Transformer, a pre-norm DETR with identical keys)

@@ -12,8 +12,10 @@ Shapes are the published ones: the RT-DETRv2-R101 decoder's sampling at serving
 batch 8, the ViT towers' token counts (YOLOS-base 4396, OWLv2-B/16 3601), the
 OWL logit head at OWLv2-B/16 (3600 patches) and OWL-ViT-B/32 (576) against the
 22-amenity vocabulary, LFM2-8B-A1B's expert and attention shapes and its six
-served layers whole at the bucket of 8 (~55 s). The whole R101 engine program
-per bucket (~30 s each) is under `-m slow`.
+served layers whole at the bucket of 8 (~55 s), Kimi Linear's KDA kernel,
+latent attention, grouped products at hidden 2304 and its five served layers
+whole at the bucket of 8. The whole R101 engine program per bucket (~30 s each)
+is under `-m slow`.
 """
 
 import os
@@ -30,7 +32,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 import spotter_tpu.models.rtdetr as rtdetr_mod
 from spotter_tpu.models import layers
-from spotter_tpu.ops import delta_rule, moe, msda
+from spotter_tpu.ops import delta_rule, kda, moe, msda
 from spotter_tpu.ops.openvocab import fused_class_logits
 
 
@@ -194,6 +196,86 @@ def test_lfm2_moe_program_at_the_bucket_of_8(chip, monkeypatch):
     compiled = jax.jit(lambda p, x: module.apply({"params": p}, x)).lower(params, pixels).compile()
     text = compiled.as_text()
     assert "expert_matmul_kernel" in text and "splash_mqa_fwd" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16 * 2**30
+
+
+def test_kda_kernel_at_kimi_linear_shapes(chip):
+    """One KDA layer's call at the bucket of 8: 4300 tokens, 32 heads of 128,
+    bfloat16, the float32 gate a value a key channel (reference rows taken out
+    of tiles of 8 sublanes, six masked products a head and chunk)."""
+    fn = partial(kda.chunked_kda, impl="pallas")
+    x, gate = ((8, 4300, 32, 128), jnp.bfloat16), ((8, 4300, 32, 128), jnp.float32)
+    text = compile_for(chip, fn, x, x, x, gate, ((8, 4300, 32), jnp.float32)).as_text()
+    assert "tpu_custom_call" in text and "kda_kernel" in text
+    assert "gated_delta_rule_kernel" not in text  # no reader of that kernel's events counts this one
+    # the shape benchmarks/kernels/kda.py reads the bucket from
+    assert "bf16[8,4352,4096]" in text
+
+
+def test_latent_attention_kernel_at_kimi_linear_shapes(chip):
+    """The latent-attention layer's call at the bucket of 8: 32 heads, keys of
+    192 (one and a half lane tiles), values of 128."""
+    qk, v = ((8, 4300, 32, 192), jnp.bfloat16), ((8, 4300, 32, 128), jnp.bfloat16)
+    text = compile_for(chip, layers.causal_latent_attention, qk, qk, v).as_text()
+    assert "tpu_custom_call" in text and "splash_mha_fwd_no_residuals" in text
+    # the shape benchmarks/kernels/mla_causal_attention.py reads the bucket from
+    assert "bf16[8,32,4608,128]" in text
+
+
+@pytest.mark.parametrize("k, n, form, result", [
+    (2304, 2048, {"swiglu": True, "out_dtype": jnp.bfloat16}, "bf16[8192,1024]"),
+    (1024, 2304, {}, "f32[8192,18,128]"),
+], ids=["gate_up", "down"])
+def test_expert_matmul_kernel_at_kimi_linear_shapes(chip, k, n, form, result):
+    """A window's two grouped products at d 2304 = 18 x 128, I 1024, 64 experts
+    held: the first call's row block 2304 wide; the weighted call writing a
+    token's eighteen lane tiles at once against the expert's whole matrix (a
+    block of 1024 x 2304 bfloat16, 4.7 MB, twice for the pipeline: whether that
+    fits VMEM only this compile says)."""
+
+    def fn(x, w, tile_expert, tile_live, *row_weight):
+        return moe.expert_matmul(x, w, tile_expert, tile_live, moe.ROW_TILE, impl="pallas",
+                                 row_weight=row_weight[0] if row_weight else None, **form)
+
+    tiles = ((moe.WINDOW_ROWS // moe.ROW_TILE,), jnp.int32)
+    weights = [] if form else [((moe.WINDOW_ROWS,), jnp.float32)]
+    text = compile_for(chip, fn, ((moe.WINDOW_ROWS, k), jnp.bfloat16), ((64, k, n), jnp.bfloat16),
+                       tiles, tiles, *weights).as_text()
+    assert "tpu_custom_call" in text and "expert_matmul_kernel" in text and result in text
+
+
+def test_kimi_linear_program_at_the_bucket_of_8(chip, monkeypatch):
+    """The five served layers at the published widths, bfloat16 held, one
+    bucket: every kernel inside one program the chip's compiler accepts, in
+    the memory of one chip; the held parameters counted."""
+    import json
+    import pathlib
+
+    from spotter_tpu.models import kimi_linear
+    from spotter_tpu.models.configs import KimiLinearDetConfig
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = KimiLinearDetConfig.from_hf(json.loads(
+        (root / "benchmarks" / "configs" / "kimi_linear_det_ep4.json").read_text()))
+    monkeypatch.setattr(kimi_linear, "flash_attention_enabled", lambda: True)
+    monkeypatch.setattr(kimi_linear, "chunked_kda", partial(kda.chunked_kda, impl="pallas"))
+    monkeypatch.setattr(moe, "routed_experts", partial(moe.routed_experts, impl="pallas"))
+    module = kimi_linear.KimiLinearDetector(cfg, dtype=jnp.bfloat16)
+    h, w = cfg.image_size
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, h, w, 3), jnp.float32))["params"])
+    held = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes))
+    # ISSUE 35: 2.117 B to within the seams: four KDA mixers of 39,514,272, the latent
+    # attention's 29,114,880, the dense SwiGLU's 63,700,992, four routed layers of 460,652,800
+    assert abs(held - 2.117e9) < 5e6, held
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim >= 2 else jnp.float32, sharding=chip), shapes)
+    pixels = jax.ShapeDtypeStruct((8, h, w, 3), jnp.float32, sharding=chip)
+    compiled = jax.jit(lambda p, x: module.apply({"params": p}, x)).lower(params, pixels).compile()
+    text = compiled.as_text()
+    assert "kda_kernel" in text and "expert_matmul_kernel" in text and "splash_mha_fwd" in text
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16 * 2**30
 
