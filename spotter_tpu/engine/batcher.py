@@ -786,12 +786,14 @@ class MicroBatcher:
             self.engine.metrics.record_stage_samples(
                 obs.QUEUE_WAIT, queue_waits_ms
             )
-            # and the span table (`host_spans`): a wait, measured from the
-            # items' own stamps, so no `obs.span` object and no annotation
-            obs.record_span(
-                "batcher.queue_wait", sum(queue_waits_ms) / 1e3,
-                count=len(queue_waits_ms),
-            )
+            # and the span table (`host_spans`) and the timeline, an image
+            # each: a wait, measured from the items' own stamps, so no
+            # `obs.span` object and no annotation
+            for item in batch:
+                obs.record_span(
+                    "batcher.queue_wait", t_dispatch - item.t_submit,
+                    start=item.t_submit,
+                )
             self.engine.metrics.record_pack(
                 padding_waste_pct=plan.padding_waste_pct,
                 slack_ms=slack_ms,

@@ -310,11 +310,8 @@ class Metrics:
         self._padding_waste_pct: deque[float] = deque(maxlen=window)
         self._slack_at_dispatch_ms: deque[float] = deque(maxlen=window)
         self._ragged_packs_total = 0
-        # Edge data plane (ISSUE 11): bytes on the /detect wire in each
-        # direction plus how many responses went out as binary frames vs
-        # default JSON — the measured substrate for the ≥25% bytes-per-
-        # request claim (wire_bytes_out_per_request in snapshot()).
-        self._wire_bytes_in_total = 0
+        # Edge data plane (ISSUE 11): the bytes of the /detect replies plus
+        # how many went out as binary frames vs default JSON
         self._wire_bytes_out_total = 0
         self._wire_requests_total = 0
         self._wire_frame_responses_total = 0
@@ -516,11 +513,10 @@ class Metrics:
         with self._lock:
             self._coalesced_submits_total += n
 
-    def record_wire(self, bytes_in: int, bytes_out: int, frame: bool) -> None:
-        """One /detect exchange's bytes on the wire (ISSUE 11): request body
-        in, response body out, and which encoding the response used."""
+    def record_wire(self, bytes_out: int, frame: bool) -> None:
+        """One /detect reply's bytes on the wire (ISSUE 11) and which
+        encoding it used."""
         with self._lock:
-            self._wire_bytes_in_total += int(bytes_in)
             self._wire_bytes_out_total += int(bytes_out)
             self._wire_requests_total += 1
             if frame:
@@ -691,6 +687,7 @@ class Metrics:
         # nesting the two here would be the only place the order matters
         perf_snap = self.perf.snapshot()
         host_spans = obs_trace.host_spans_snapshot()
+        timeline = obs_trace.timeline_snapshot()
         starved_staging_s, starved_upstream_s = self.starvation.totals()
         with self._lock:
             lats = sorted(self._latencies_ms)
@@ -765,9 +762,13 @@ class Metrics:
                 else None
             )
 
+            # the timeline, where this process serves a profiler: every
+            # span's stamps, for a reader that joins them to a capture
+            extra = {} if timeline is None else {"host_timeline": timeline}
             return {
                 **perf_snap,
                 **stage_stats,
+                **extra,
                 # identity stamp (ISSUE 12): who produced this snapshot —
                 # the substrate for fleet aggregation (staleness, restart
                 # detection via generation, per-replica labels)
@@ -838,16 +839,10 @@ class Metrics:
                 "text_cache_misses_total": self._text_cache_misses_total,
                 "text_cache_hit_ms_p50": _median(self._text_hit_ms),
                 "text_cache_miss_ms_p50": _median(self._text_miss_ms),
-                "wire_bytes_in_total": self._wire_bytes_in_total,
                 "wire_bytes_out_total": self._wire_bytes_out_total,
                 "wire_requests_total": self._wire_requests_total,
                 "wire_frame_responses_total": self._wire_frame_responses_total,
                 "wire_json_responses_total": self._wire_json_responses_total,
-                "wire_bytes_out_per_request": (
-                    self._wire_bytes_out_total / self._wire_requests_total
-                    if self._wire_requests_total
-                    else 0.0
-                ),
                 "admit_limit": self._admit_limit,
                 "admit_in_flight": self._admit_in_flight,
                 "admit_sheds_total": dict(self._admit_sheds_total),

@@ -8,7 +8,8 @@ Three mechanisms, all opt-in and zero-cost when off:
   that traces the host.
 - `maybe_start_profiler_server()`: starts jax.profiler's gRPC server when
   `SPOTTER_TPU_PROFILER_PORT` is set, so TensorBoard / xprof can connect and
-  capture live TPU traces from a serving pod.
+  capture live TPU traces from a serving pod, and the span timeline that
+  `/metrics` then serves as `host_timeline`.
 - `capture(log_dir, duration_s)`: timed start_trace/stop_trace pair used by
   the `/profile` endpoint — the device work of whatever traffic is in
   flight lands in the trace. (For ad-hoc scoped captures, use
@@ -34,7 +35,9 @@ _server_started = False
 
 
 def maybe_start_profiler_server() -> int | None:
-    """Start jax.profiler.start_server once if the env asks for it."""
+    """Start jax.profiler.start_server once if the env asks for it, and with
+    it the span timeline (`obs.enable_timeline`): a process that can be
+    captured keeps the stamps a capture is joined to."""
     global _server_started
     port = os.environ.get(PROFILER_PORT_ENV, "")
     if not port:
@@ -43,7 +46,10 @@ def maybe_start_profiler_server() -> int | None:
         if not _server_started:
             jax.profiler.start_server(int(port))
             _server_started = True
-            logger.info("jax profiler server listening on :%s", port)
+            from spotter_tpu import obs
+
+            obs.enable_timeline()
+            logger.info("jax profiler server listening on :%s; span timeline on", port)
     return int(port)
 
 

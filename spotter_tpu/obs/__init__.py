@@ -46,11 +46,14 @@ from spotter_tpu.obs.trace import (  # noqa: F401
     ROUTE,
     STAGES,
     TRACEPARENT_HEADER,
+    Timeline,
     Trace,
     batch_trace_id,
     batch_traces,
     begin_trace,
     current_trace,
+    disable_timeline,
+    enable_timeline,
     new_request_id,
     host_spans_snapshot,
     parse_traceparent,
@@ -59,6 +62,7 @@ from spotter_tpu.obs.trace import (  # noqa: F401
     set_batch_traces,
     set_current_trace,
     span,
+    timeline_snapshot,
     trace_id_for_request,
     trace_stats,
     traceparent_value,

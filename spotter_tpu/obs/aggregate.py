@@ -105,6 +105,8 @@ def flatten_counters(snap: dict) -> dict[str, float]:
         for k, v in obj.items():
             if k in ("latency_ms_histogram", "stage_ms_histogram"):
                 continue  # handled below with explicit bucket keys
+            if k == "host_timeline":
+                continue  # one process's stamps: nothing in it adds up
             key = f"{prefix}{k}"
             ctx = counter_ctx or k.endswith("_total")
             if isinstance(v, bool):

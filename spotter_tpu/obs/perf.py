@@ -579,7 +579,6 @@ class PerfLedger:
             "mfu_pct": round(mfu, 3),
             "useful_mfu_pct": round(useful_mfu, 3),
             "device_duty_cycle_pct": round(duty, 3),
-            "perf_window_s": self.window_s,
             "peak_tflops": self.peak_tflops,
             "device_kind": self.device_kind,
             "devices": self.n_devices,

@@ -69,6 +69,8 @@ _QUANTILE_TAGS = {"p50": "0.5", "p90": "0.9", "p99": "0.99"}
 _SKIP_KEYS = {
     "latency_ms_histogram", "pools", "dp_degraded", "compile_shapes",
     "stage_ms_histogram", "slo_burn_raw", "perf_raw", "replica",
+    # the spans' stamps, for a reader that joins them to a capture
+    "host_timeline",
 }
 
 

@@ -12,13 +12,16 @@ Span capture is a monotonic-clock read and a list append under the GIL; when
 no trace is active (flight recorder off, or a codepath outside a request)
 no Span or Trace is allocated.
 
-`span` is the one way to time host work, and it has three sinks (ISSUE 26):
-the request `Trace` (as ever), a process-wide table `name -> count, wall_ms,
-cpu_ms` that `/metrics` serves as `host_spans` (on in every run, traced or
-not), and, for spans with no `await` inside, a profiler annotation that puts
-the span on the device trace's own clock. This module stays stdlib-only: the
-serving process hands the annotation factory in (`set_annotator`), so the
-supervisor never imports jax for it.
+`span` is the one way to time host work, and it has four sinks: the request
+`Trace` (as ever), a process-wide table `name -> count, wall_ms, cpu_ms`
+that `/metrics` serves as `host_spans` (on in every run, traced or not),
+for spans with no `await` inside, a profiler annotation that puts the span
+on the device trace's own clock, and, in a process set up to be captured, the
+`Timeline`: a bounded ring of every span's stamps on the monotonic clock,
+which `/metrics` serves as `host_timeline` and which a reader joins to the
+device trace afterwards. This module stays stdlib-only: the serving process
+hands the annotation factory in (`set_annotator`), so the supervisor never
+imports jax for it.
 
 Stage-name vocabulary: `STAGES` is the ONE list of stage names shared by
 trace spans and the Metrics stage histograms (ISSUE 7 satellite —
@@ -26,7 +29,10 @@ trace spans and the Metrics stage histograms (ISSUE 7 satellite —
 neither matched the decode+h2d split from PR 3).
 """
 
+import bisect
+import collections
 import contextvars
+import gc
 import hashlib
 import os
 import re
@@ -314,10 +320,12 @@ def set_annotator(factory) -> None:
 
 
 def record_span(
-    name: str, wall_s: float, cpu_s: float = 0.0, count: int = 1
+    name: str, wall_s: float, cpu_s: float = 0.0, count: int = 1,
+    start: float | None = None,
 ) -> None:
     """Add to the table without a `span` object: a wait that is measured
-    from two stamps the caller already has (the batcher's queue wait)."""
+    from two stamps the caller already has (the batcher's queue wait).
+    With `start` (the first stamp) the span goes on the timeline too."""
     with _table_lock:
         row = _table.get(name)
         if row is None:
@@ -325,11 +333,14 @@ def record_span(
         row[0] += count
         row[1] += wall_s * 1e3
         row[2] += cpu_s * 1e3
+    timeline = _timeline
+    if timeline is not None and start is not None:
+        timeline.add(name, start, start + wall_s)
 
 
 def host_spans_snapshot() -> dict:
     with _table_lock:
-        return {
+        out = {
             name: {
                 "count": count,
                 "wall_ms": round(wall_ms, 3),
@@ -337,18 +348,134 @@ def host_spans_snapshot() -> dict:
             }
             for name, (count, wall_ms, cpu_ms) in _table.items()
         }
+    count, wall_s = _gc_pauses
+    if count:
+        out[GC_SPAN] = {"count": count, "wall_ms": round(wall_s * 1e3, 3), "cpu_ms": 0.0}
+    return out
 
 
 def reset_host_spans() -> None:
+    global _gc_pauses
     with _table_lock:
         _table.clear()
+    _gc_pauses = (0, 0.0)
+
+
+# ---- the timeline (the `host_timeline` key of /metrics) ----
+
+# Python's collector pauses, one span each while the timeline is on: a
+# collection stops every thread, so whatever the chip waited on waited on it
+GC_SPAN = "python.gc"
+# A benchmark window is 51 s of sending and its replies' tail, read after
+# its end: 90 s still reach back to a capture made 3 s into it.
+TIMELINE_SECONDS = 90.0
+# A served image makes eight spans and a batch eleven; the benchmark's
+# fastest cell (YOLOS-base, 29 images a second in batches of about 16) makes
+# some 280 a second, and the collector 15 more: 27,000 in 90 s, so 2**16
+# stamps hold 90 s with room. An entry is a tuple of five references (name
+# and batch are shared objects; two floats and the thread id are its own):
+# 160 bytes, 10.5 MB with the ring full.
+TIMELINE_ENTRIES = 1 << 16
+
+
+class Timeline:
+    """The last `seconds` of spans, at most `entries` of them, as
+    `(name, thread id, t0, t1, batch)` stamps of `_now` (the monotonic
+    clock, one for every process of the host). Appends come from every
+    thread and from the collector's callback, so they take no lock: a
+    bounded `deque`'s append is one step for the interpreter. A stamp is no
+    stack frame, so a span with an `await` inside is on it like any other.
+    `snapshot()` encodes it for `/metrics`: the names and threads as tables,
+    each entry as integers, microseconds of the monotonic clock."""
+
+    def __init__(self, seconds: float = TIMELINE_SECONDS, entries: int = TIMELINE_ENTRIES) -> None:
+        self.seconds = float(seconds)
+        self._ring: collections.deque = collections.deque(maxlen=int(entries))
+        self._appended = 0
+        self._since = _now()
+
+    def add(self, name: str, t0: float, t1: float, batch=None) -> None:
+        self._ring.append((name, threading.get_ident(), t0, t1, batch))
+        self._appended += 1  # a lost increment only makes `complete_from` earlier
+
+    def snapshot(self) -> dict:
+        """`complete_from_us`: from then on every span that ended is here
+        (the later of when the ring began, `seconds` ago, and the newest
+        stamp the count bound pushed out)."""
+        now = _now()
+        rows = list(self._ring)
+        complete_from = max(self._since, now - self.seconds)
+        if self._appended > len(rows) and rows:
+            complete_from = max(complete_from, rows[0][3])
+        # appended at their ends, so (nearly) in the order of t1
+        rows = rows[bisect.bisect_left(rows, complete_from, key=lambda r: r[3]):]
+        names: dict = {}
+        threads: dict = {}
+        entries = [
+            [names.setdefault(name, len(names)), threads.setdefault(ident, len(threads)),
+             round(t0 * 1e6), round(t1 * 1e6), batch]
+            for name, ident, t0, t1, batch in rows
+        ]
+        thread_names = {t.ident: t.name for t in threading.enumerate()}
+        return {
+            "now_us": round(now * 1e6),
+            "complete_from_us": round(complete_from * 1e6),
+            "names": list(names),
+            "threads": [thread_names.get(ident, str(ident)) for ident in threads],
+            "entries": entries,
+        }
+
+
+_timeline: Timeline | None = None
+_gc_pauses = (0, 0.0)  # (collections, seconds) while the timeline is on
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook. A collection can start between any two steps
+    of any thread's Python code, that of a thread inside `_table_lock`
+    too, so this takes no lock: collections never overlap, and the ring's
+    append is one step."""
+    global _gc_t0, _gc_pauses
+    if phase == "start":
+        _gc_t0 = _now()
+        return
+    t1 = _now()
+    count, wall_s = _gc_pauses
+    _gc_pauses = (count + 1, wall_s + (t1 - _gc_t0))
+    timeline = _timeline
+    if timeline is not None:
+        timeline.add(GC_SPAN, _gc_t0, t1)
+
+
+def enable_timeline() -> None:
+    """Turn the timeline on (idempotent): in a process that serves a
+    profiler (engine/profiler.py), so that a capture can be joined to it.
+    Off, a span pays one `None` check for it and nothing is allocated."""
+    global _timeline
+    if _timeline is None:
+        _timeline = Timeline()
+        gc.callbacks.append(_on_gc)
+
+
+def disable_timeline() -> None:
+    global _timeline
+    if _timeline is not None:
+        gc.callbacks.remove(_on_gc)
+        _timeline = None
+
+
+def timeline_snapshot() -> dict | None:
+    timeline = _timeline
+    return None if timeline is None else timeline.snapshot()
 
 
 class span:
     """`with span("detector.pil_decode", annotate=True):` — time one piece
     of host work. On exit it goes to the request trace (the ambient one, an
-    explicit one, or each of a batch's: pass the list), to the span table,
-    and, with `annotate`, it is a profiler annotation while it runs.
+    explicit one, or each of a batch's: pass the list), to the span table
+    and, where it is on, to the timeline, with its `batch` tag; with
+    `annotate`, it is a profiler annotation while it runs.
 
     `stage`: the name from the stage vocabulary that the trace records
     the span under (`engine.decode` is the engine's half of `decode`); a
@@ -409,6 +536,9 @@ class span:
             self._ann.__exit__(None, None, None)
             self._ann = None
         record_span(self.name, self.seconds, cpu_s)
+        timeline = _timeline
+        if timeline is not None:
+            timeline.add(self.name, self._t0, t1, self.args.get("batch"))
         tr = self.trace if self.trace is not None else _current.get()
         if not tr:
             return

@@ -386,11 +386,13 @@ class AmenitiesDetector:
         upstream work for as long as it is in here (in fetch, PIL decode
         or the batcher's queue, or its reply being drawn and encoded): with
         the chip idle and no batch staging, that is what the chip waits
-        for (`starved_upstream_s_total`)."""
+        for (`starved_upstream_s_total`). The same interval is the span
+        `detector.image` (a wait: no annotation, and on no request trace)."""
         starvation = self.engine.metrics.starvation
         starvation.move(upstream=+1)
         try:
-            return await self._process_image(url, *args, **kwargs)
+            with obs.span("detector.image", obs.NO_TRACE):
+                return await self._process_image(url, *args, **kwargs)
         finally:
             starvation.move(upstream=-1)
 

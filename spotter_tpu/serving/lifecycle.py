@@ -32,6 +32,7 @@ import asyncio
 import logging
 import os
 import signal
+import threading
 import time
 from typing import Awaitable, Callable, Optional
 
@@ -128,8 +129,11 @@ def enable_compile_cache() -> str:
 
     Must run before the first jit compilation of the process. Thresholds are
     zeroed so every bucket program is cached — the ladder is a handful of
-    programs and a preempted replica wants all of them back.
+    programs and a preempted replica wants all of them back. From here on
+    every program the process compiles is counted as a hit or a miss of the
+    cache (`compile_cache_totals`, in `/metrics`).
     """
+    global _cache_listening
     import jax
 
     cache_dir = compile_cache_dir()
@@ -139,8 +143,41 @@ def enable_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    with _cache_lock:
+        if not _cache_listening:
+            jax.monitoring.register_event_listener(_count_cache_event)
+            _cache_listening = True
     logger.info("persistent compile cache at %s", cache_dir)
     return cache_dir
+
+
+# jax.monitoring's events for a program read from the cache and for one
+# compiled and written to it (jax 0.9.0: `_src/compiler.py`,
+# `_src/compilation_cache.py`)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_cache_lock = threading.Lock()
+_cache_counts = {"hits": 0, "misses": 0}
+_cache_listening = False
+
+
+def _count_cache_event(event: str, **_kwargs) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        with _cache_lock:
+            _cache_counts[key] += 1
+
+
+def compile_cache_totals() -> dict:
+    """Programs this process loaded from the persistent compile cache and
+    programs it compiled and wrote there, since `enable_compile_cache`."""
+    with _cache_lock:
+        return {
+            "compile_cache_hits_total": _cache_counts["hits"],
+            "compile_cache_misses_total": _cache_counts["misses"],
+        }
 
 
 def pool_from_env() -> Optional[str]:

@@ -322,6 +322,12 @@ def make_app(
             await watcher.start()
 
     async def detect(request: web.Request) -> web.Response:
+        # the request's life in the handler, sheds included, as one span (a
+        # wait: no annotation, and on no request trace)
+        with obs.span("app.detect", obs.NO_TRACE):
+            return await _detect(request)
+
+    async def _detect(request: web.Request) -> web.Response:
         # Request-scoped trace (ISSUE 7): continue the edge's traceparent or
         # mint ids from/with X-Request-ID; EVERY branch below — sheds
         # included — echoes the request id, and completed traces land in
@@ -453,7 +459,6 @@ def make_app(
                 resp.headers[wire.NEGATIVE_HEADER] = verdicts
             out_bytes = resp.body
             det.engine.metrics.record_wire(
-                request.content_length or 0,
                 len(out_bytes) if isinstance(out_bytes, (bytes, bytearray)) else 0,
                 frame,
             )
@@ -530,6 +535,8 @@ def make_app(
         # JSON view unchanged for existing consumers; ?format=prometheus or
         # Accept: text/plain selects the text exposition (ISSUE 7)
         snap = det.engine.metrics.snapshot()
+        # the persistent compile cache's hits and misses (set-up's compiles)
+        snap.update(lifecycle.compile_cache_totals())
         # output-integrity plane (ISSUE 17): verification + probe + attest
         # counters ride the replica snapshot additively
         plane = request.app.get("integrity")
