@@ -20,6 +20,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    JoyAIFlashDetConfig,
     KimiLinearDetConfig,
     Lfm2MoeDetConfig,
     OwlViTConfig,
@@ -337,3 +338,9 @@ def load_kimi_linear_det(model_name: str) -> tuple[KimiLinearDetConfig, dict]:
     from spotter_tpu.convert.kimi_linear_rules import convert_kimi_linear
 
     return load_config_and_safetensors(model_name, KimiLinearDetConfig, convert_kimi_linear)
+
+
+def load_joyai_flash_det(model_name: str) -> tuple[JoyAIFlashDetConfig, dict]:
+    from spotter_tpu.convert.joyai_flash_rules import convert_joyai_flash
+
+    return load_config_and_safetensors(model_name, JoyAIFlashDetConfig, convert_joyai_flash)

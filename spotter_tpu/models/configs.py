@@ -805,3 +805,72 @@ class KimiLinearDetConfig:
             if hf.get(key, want) != want:
                 raise ValueError(f"{key} {hf[key]!r} is not served: this family computes {want!r}")
         return cfg
+
+
+@dataclass(frozen=True)
+class JoyAIFlashDetConfig:
+    """JoyAI-LLM-Flash's decoder layers as a detector body (`joyai_flash_det`):
+    the language model's widths under DeepSeek-V3's keys (its config.json's
+    keys are those of transformers' `DeepseekV3Config`), the detector's seams
+    under YOLOS's. `num_routed_experts` is the router's width, `num_experts`
+    how many of them this chip holds (the checkpoint's `n_routed_experts`),
+    from `expert_offset` on (`models/joyai_flash.py`, `ops/moe.py`)."""
+
+    hidden_size: int = 2048
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 5
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 32000000.0
+    rms_norm_eps: float = 1e-6
+    num_routed_experts: int = 256
+    num_experts: int = 128
+    expert_offset: int = 0
+    num_experts_per_token: int = 8
+    moe_renormalize: bool = True
+    routed_scaling_factor: float = 2.5
+    image_size: tuple[int, int] = (800, 1344)
+    patch_size: int = 16
+    num_channels: int = 3
+    num_detection_tokens: int = 100
+    num_labels: int = 91
+    id2label: tuple[tuple[int, str], ...] = ()
+
+    @property
+    def id2label_dict(self) -> dict[int, str]:
+        return dict(self.id2label)
+
+    @property
+    def num_tokens(self) -> int:
+        h, w = self.image_size
+        return (h // self.patch_size) * (w // self.patch_size) + self.num_detection_tokens
+
+    @classmethod
+    def from_hf(cls, hf: dict) -> "JoyAIFlashDetConfig":
+        """From a checkpoint's config.json as a dict. `n_routed_experts` is
+        the share held; the router's width is `num_routed_experts` where the
+        share is not the whole."""
+        names = {f.name for f in fields(cls)} - {"id2label", "image_size"}
+        held = hf["n_routed_experts"]
+        renamed = {"num_experts": held, "num_routed_experts": hf.get("num_routed_experts", held),
+                   "num_experts_per_token": hf["num_experts_per_tok"],
+                   "moe_renormalize": hf["norm_topk_prob"]}
+        if hf.get("q_lora_rank") is None:
+            raise ValueError("q_lora_rank None is not served: this family computes a query latent")
+        for key, want in (("n_shared_experts", 1), ("scoring_func", "sigmoid"),
+                          ("topk_method", "noaux_tc"), ("n_group", 1), ("topk_group", 1),
+                          ("rope_scaling", None), ("rope_interleave", True), ("attention_bias", False),
+                          ("hidden_act", "silu"), ("moe_layer_freq", 1)):
+            if hf.get(key, want) != want:
+                raise ValueError(f"{key} {hf[key]!r} is not served: this family computes {want!r}")
+        return cls(
+            image_size=tuple(hf["image_size"]),
+            id2label=tuple(sorted((int(k), v) for k, v in hf.get("id2label", {}).items())),
+            **{**{k: hf[k] for k in names if k in hf}, **renamed},
+        )

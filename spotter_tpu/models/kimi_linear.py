@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from spotter_tpu.models.configs import KimiLinearDetConfig
+from spotter_tpu.models.configs import JoyAIFlashDetConfig, KimiLinearDetConfig
 from spotter_tpu.models.layers import (
     FLASH_ATTN_MIN_SEQ,
     MLPHead,
@@ -55,12 +55,12 @@ from spotter_tpu.models.layers import (
     flash_attention_enabled,
 )
 from spotter_tpu.models.lfm2_moe import RMSNorm, _dense  # a plain-weight norm, a bias-free QuantDense
-from spotter_tpu.models.qwen3_next import rms_norm
+from spotter_tpu.models.qwen3_next import apply_rotary, rms_norm, rotary_tables
 from spotter_tpu.ops import moe as moe_ops
 from spotter_tpu.ops.kda import RawGate, chunked_kda
 
 NORM_TOPK_EPS = 1e-20  # modeling_kimi.py's KimiMoEGate: weights / (sum + 1e-20)
-ATTENTION_IMAGES = 16  # images the latent-attention layer works at once (DecoderLayer)
+ATTENTION_IMAGES = 16  # images the latent-attention layer works at once
 
 
 class KimiDeltaAttention(nn.Module):
@@ -113,36 +113,87 @@ class KimiDeltaAttention(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    config: KimiLinearDetConfig
+    """Multi-head latent attention as a prefill runs it, over a batch.
+
+    Keys and values come out of a latent: `kv_a_proj_with_mqa` gives
+    `kv_lora_rank` channels and `qk_rope_head_dim` key channels that every
+    head shares; `kv_b_proj` of the latent's RMSNorm gives each head
+    `qk_nope_head_dim` key and `v_head_dim` value channels (`latent_kv`).
+    Queries come from `q_proj`, or, given `q_lora_rank`, out of a latent of
+    their own, `q_b_proj(q_a_layernorm(q_a_proj x))` (`latent_q`). Given
+    `rope_theta`, the decoupled channels (each query head's last
+    `qk_rope_head_dim` and the shared key channels) turn by the token's index
+    in the half-split convention of `qwen3_next.apply_rotary` (`latent_rope`);
+    without it nothing turns (Kimi's `mla_use_nope`). Causal softmax over keys
+    of nope + rope channels, scaled by their count^-0.5,
+    then `o_proj`. Keys and values are expanded a head: nothing is cached.
+
+    Keys of 192 fill lanes of 256, and the kernel wants them padded and
+    head-major: a batch's q, k and their copies are the program's peak (7.7 GB
+    at 32 images). Over `ATTENTION_IMAGES` the layer runs in parts, one after
+    the other, each part's copies freed."""
+
+    config: KimiLinearDetConfig | JoyAIFlashDetConfig
     dtype: jnp.dtype = jnp.float32
+    q_lora_rank: int | None = None
+    rope_theta: float | None = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        b, t, _ = x.shape
         heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        q = _dense(heads * (nope + pe), self.dtype, "q_proj")(x).reshape(b, t, heads, nope + pe)
-        with jax.named_scope("latent_kv"):
-            latent = _dense(rank + pe, self.dtype, "kv_a_proj_with_mqa")(x)
-            normed = RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")(latent[..., :rank])
-            kv = _dense(heads * (nope + dv), self.dtype, "kv_b_proj")(normed)
-            kv = kv.reshape(b, t, heads, nope + dv)
-            k_pe = jnp.broadcast_to(latent[:, :, None, rank:], (b, t, heads, pe))
-            k, v = jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
-        q = q * (nope + pe)**-0.5
-        if flash_attention_enabled() and t >= FLASH_ATTN_MIN_SEQ:
-            out = causal_latent_attention(q, k, v)
+        if self.q_lora_rank is None:
+            q_proj = _dense(heads * (nope + pe), self.dtype, "q_proj")
         else:
-            logits = jnp.einsum("bqhd,bshd->bhqs", q, k).astype(jnp.float32)
-            logits = jnp.where(np.tril(np.ones((t, t), bool)), logits, -jnp.inf)
-            weights = nn.softmax(logits, axis=-1).astype(self.dtype)
-            out = jnp.einsum("bhqs,bshd->bqhd", weights, v)
-        return _dense(cfg.hidden_size, self.dtype, "o_proj")(out.reshape(b, t, heads * dv))
+            q_a = _dense(self.q_lora_rank, self.dtype, "q_a_proj")
+            q_norm = RMSNorm(cfg.rms_norm_eps, name="q_a_layernorm")
+            q_b = _dense(heads * (nope + pe), self.dtype, "q_b_proj")
+
+            def q_proj(x):
+                with jax.named_scope("latent_q"):
+                    return q_b(q_norm(q_a(x)))
+
+        kv_a = _dense(rank + pe, self.dtype, "kv_a_proj_with_mqa")
+        kv_norm = RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")
+        kv_b = _dense(heads * (nope + dv), self.dtype, "kv_b_proj")
+        o_proj = _dense(cfg.hidden_size, self.dtype, "o_proj")
+
+        def attend(x):
+            b, t, _ = x.shape
+            q = q_proj(x).reshape(b, t, heads, nope + pe)
+            with jax.named_scope("latent_kv"):
+                latent = kv_a(x)
+                kv = kv_b(kv_norm(latent[..., :rank])).reshape(b, t, heads, nope + dv)
+                k_pe = latent[:, :, None, rank:]
+            if self.rope_theta is not None:
+                with jax.named_scope("latent_rope"):
+                    cos, sin = rotary_tables(t, pe, self.rope_theta)
+                    q = jnp.concatenate([q[..., :nope], apply_rotary(q[..., nope:], cos, sin)], -1)
+                    k_pe = apply_rotary(k_pe, cos, sin)
+            with jax.named_scope("latent_kv"):
+                k_pe = jnp.broadcast_to(k_pe, (b, t, heads, pe))
+                k, v = jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+            q = q * (nope + pe)**-0.5
+            if flash_attention_enabled() and t >= FLASH_ATTN_MIN_SEQ:
+                out = causal_latent_attention(q, k, v)
+            else:
+                logits = jnp.einsum("bqhd,bshd->bhqs", q, k).astype(jnp.float32)
+                logits = jnp.where(np.tril(np.ones((t, t), bool)), logits, -jnp.inf)
+                weights = nn.softmax(logits, axis=-1).astype(self.dtype)
+                out = jnp.einsum("bhqs,bshd->bqhd", weights, v)
+            return o_proj(out.reshape(b, t, heads * dv))
+
+        parts = [x[i:i + ATTENTION_IMAGES] for i in range(0, x.shape[0], ATTENTION_IMAGES)]
+        done = [attend(parts[0])]
+        for part in parts[1:]:
+            part, done[-1] = jax.lax.optimization_barrier((part, done[-1]))
+            done.append(attend(part))
+        return done[0] if len(done) == 1 else jnp.concatenate(done, axis=0)
 
 
 class DenseMlp(nn.Module):
-    config: KimiLinearDetConfig
+    config: KimiLinearDetConfig | JoyAIFlashDetConfig
     width: int
     dtype: jnp.dtype = jnp.float32
 
@@ -157,7 +208,7 @@ class SparseMoe(nn.Module):
     """Returns (the layer's output, the tokens each held expert took of each
     image (B, held), the selections of each image the bias moved (B,))."""
 
-    config: KimiLinearDetConfig
+    config: KimiLinearDetConfig | JoyAIFlashDetConfig
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -209,18 +260,7 @@ class DecoderLayer(nn.Module):
                 mixed, spread = KimiDeltaAttention(cfg, dtype=self.dtype, name="self_attn")(normed)
         else:
             with jax.named_scope("latent_attention"):
-                mixer = LatentAttention(cfg, dtype=self.dtype, name="self_attn")
-                # keys of 192 fill lanes of 256, and the kernel wants them padded
-                # and head-major: a batch's q, k and their copies are the program's
-                # peak (7.7 GB at 32 images). Over ATTENTION_IMAGES the layer runs
-                # in parts, one after the other, each part's copies freed.
-                parts = [normed[i:i + ATTENTION_IMAGES]
-                         for i in range(0, normed.shape[0], ATTENTION_IMAGES)]
-                done = [mixer(parts[0])]
-                for part in parts[1:]:
-                    part, done[-1] = jax.lax.optimization_barrier((part, done[-1]))
-                    done.append(mixer(part))
-                mixed = done[0] if len(done) == 1 else jnp.concatenate(done, axis=0)
+                mixed = LatentAttention(cfg, dtype=self.dtype, name="self_attn")(normed)
         x = x + mixed
         normed = RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x)
         if self.dense:

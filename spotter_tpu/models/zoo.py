@@ -33,6 +33,7 @@ from spotter_tpu.models.configs import (
     DabDetrConfig,
     DeformableDetrConfig,
     DetrConfig,
+    JoyAIFlashDetConfig,
     KimiLinearDetConfig,
     Lfm2MoeDetConfig,
     OwlViTConfig,
@@ -47,6 +48,7 @@ from spotter_tpu.models.conditional_detr import ConditionalDetrDetector
 from spotter_tpu.models.dab_detr import DabDetrDetector
 from spotter_tpu.models.deformable_detr import DeformableDetrDetector
 from spotter_tpu.models.detr import DetrDetector
+from spotter_tpu.models.joyai_flash import JoyAIFlashDetector
 from spotter_tpu.models.kimi_linear import KimiLinearDetector
 from spotter_tpu.models.lfm2_moe import Lfm2MoeDetector
 from spotter_tpu.models.owlvit import OwlViTDetector
@@ -399,6 +401,43 @@ def _build_kimi_linear_det(model_name: str) -> BuiltDetector:
 
         cfg, params = load_kimi_linear_det(model_name)
     return _held_detector(model_name, KimiLinearDetector, cfg, params)
+
+
+def tiny_joyai_flash_det_config(num_labels: int = 80) -> JoyAIFlashDetConfig:
+    return JoyAIFlashDetConfig(
+        hidden_size=32,
+        intermediate_size=48,
+        moe_intermediate_size=16,
+        num_attention_heads=2,
+        q_lora_rank=24,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=4,
+        v_head_dim=8,
+        rope_theta=10000.0,
+        num_routed_experts=8,
+        num_experts=4,
+        num_experts_per_token=2,
+        image_size=(32, 48),
+        patch_size=8,
+        num_detection_tokens=5,
+        num_labels=num_labels,
+        id2label=tuple(coco_id2label_80().items()),
+    )
+
+
+def _build_joyai_flash_det(model_name: str) -> BuiltDetector:
+    """JoyAI-LLM-Flash's decoder layers as a detector body (2.6 B parameters
+    at five layers and one chip's half of the experts)."""
+    if os.environ.get(TINY_ENV):
+        cfg = tiny_joyai_flash_det_config()
+        params = _init_random(JoyAIFlashDetector(cfg), cfg.image_size)
+        logger.info("Built tiny random joyai_flash_det for %s (%s)", model_name, TINY_ENV)
+    else:
+        from spotter_tpu.convert.loader import load_joyai_flash_det
+
+        cfg, params = load_joyai_flash_det(model_name)
+    return _held_detector(model_name, JoyAIFlashDetector, cfg, params)
 
 
 def tiny_owlvit_config() -> OwlViTConfig:
@@ -772,6 +811,11 @@ register(ModelFamily(
     # no tp rules, as above: every mixer is whole and the experts' share is the configuration's
     name="kimi_linear_det", matches=("kimi-linear-det", "kimi_linear_det"),
     build=_build_kimi_linear_det,
+))
+register(ModelFamily(
+    # no tp rules, as above: every mixer is whole and the experts' share is the configuration's
+    name="joyai_flash_det", matches=("joyai-flash-det", "joyai_flash_det"),
+    build=_build_joyai_flash_det,
 ))
 register(
     # plain DETR (+ Table-Transformer, a pre-norm DETR with identical keys)

@@ -165,7 +165,7 @@ def test_model_pools_from_registry_covers_the_zoo():
     assert set(by_name) == {
         "conditional_detr", "dab_detr", "deformable_detr", "rtdetr",
         "owlvit", "yolos", "detr", "qwen3_next_det", "lfm2_moe_det",
-        "kimi_linear_det",
+        "kimi_linear_det", "joyai_flash_det",
     }
     assert by_name["owlvit"].open_vocab
     # big models shard tp, small models pack dp (ISSUE 20d)

@@ -14,7 +14,8 @@ OWL logit head at OWLv2-B/16 (3600 patches) and OWL-ViT-B/32 (576) against the
 22-amenity vocabulary, LFM2-8B-A1B's expert and attention shapes and its six
 served layers whole at the bucket of 8 (~55 s), Kimi Linear's KDA kernel,
 latent attention, grouped products at hidden 2304 and its five served layers
-whole at the bucket of 8. The whole R101 engine program per bucket (~30 s each)
+whole at the bucket of 8, JoyAI-LLM-Flash's grouped products at I 768 and its
+five served layers whole at the bucket of 32. The whole R101 engine program per bucket (~30 s each)
 is under `-m slow`.
 """
 
@@ -276,6 +277,59 @@ def test_kimi_linear_program_at_the_bucket_of_8(chip, monkeypatch):
     compiled = jax.jit(lambda p, x: module.apply({"params": p}, x)).lower(params, pixels).compile()
     text = compiled.as_text()
     assert "kda_kernel" in text and "expert_matmul_kernel" in text and "splash_mha_fwd" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("k, n, form, result", [
+    (2048, 1536, {"swiglu": True, "out_dtype": jnp.bfloat16}, "bf16[8192,768]"),
+    (768, 2048, {}, "f32[8192,16,128]"),
+], ids=["gate_up", "down"])
+def test_expert_matmul_kernel_at_joyai_flash_shapes(chip, k, n, form, result):
+    """A window's two grouped products at d 2048, I 768 = 6 x 128, 128 of
+    256 experts held: the first call's gate | up 1536 wide with the SwiGLU on
+    the way out; the weighted call against a matrix only 768 deep."""
+
+    def fn(x, w, tile_expert, tile_live, *row_weight):
+        return moe.expert_matmul(x, w, tile_expert, tile_live, moe.ROW_TILE, impl="pallas",
+                                 row_weight=row_weight[0] if row_weight else None, **form)
+
+    tiles = ((moe.WINDOW_ROWS // moe.ROW_TILE,), jnp.int32)
+    weights = [] if form else [((moe.WINDOW_ROWS,), jnp.float32)]
+    text = compile_for(chip, fn, ((moe.WINDOW_ROWS, k), jnp.bfloat16), ((128, k, n), jnp.bfloat16),
+                       tiles, tiles, *weights).as_text()
+    assert "tpu_custom_call" in text and "expert_matmul_kernel" in text and result in text
+
+
+def test_joyai_flash_program_at_the_bucket_of_32(chip, monkeypatch):
+    """The five served layers at the published widths, bfloat16 held, the
+    largest bucket: every kernel inside one program the chip's compiler
+    accepts, in the memory of one chip (5.70 GB of arguments and 7.85 GB of
+    temporaries when this was written); the held parameters counted."""
+    import json
+    import pathlib
+
+    from spotter_tpu.models import joyai_flash, kimi_linear
+    from spotter_tpu.models.configs import JoyAIFlashDetConfig
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    cfg = JoyAIFlashDetConfig.from_hf(json.loads(
+        (root / "benchmarks" / "configs" / "joyai_flash_det_ep2.json").read_text()))
+    monkeypatch.setattr(kimi_linear, "flash_attention_enabled", lambda: True)
+    monkeypatch.setattr(moe, "routed_experts", partial(moe.routed_experts, impl="pallas"))
+    module = joyai_flash.JoyAIFlashDetector(cfg, dtype=jnp.bfloat16)
+    h, w = cfg.image_size
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, h, w, 3), jnp.float32))["params"])
+    # 2.63 B: the dense layer's 70.39 M, four routed layers of 635.58 M, the seams
+    assert sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(shapes)) == 2_631_453_792
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim >= 2 else jnp.float32, sharding=chip), shapes)
+    pixels = jax.ShapeDtypeStruct((32, h, w, 3), jnp.float32, sharding=chip)
+    compiled = jax.jit(lambda p, x: module.apply({"params": p}, x)).lower(params, pixels).compile()
+    text = compiled.as_text()
+    assert "expert_matmul_kernel" in text and "splash_mha_fwd" in text
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16 * 2**30
 
