@@ -53,11 +53,13 @@ from spotter_tpu.models.layers import (
     PatchEmbed,
     causal_latent_attention,
     flash_attention_enabled,
+    latent_attention_tokens,
 )
 from spotter_tpu.models.lfm2_moe import RMSNorm, _dense  # a plain-weight norm, a bias-free QuantDense
-from spotter_tpu.models.qwen3_next import apply_rotary, rms_norm, rotary_tables
+from spotter_tpu.models.qwen3_next import rms_norm, rotary_tables
 from spotter_tpu.ops import moe as moe_ops
 from spotter_tpu.ops.kda import RawGate, chunked_kda
+from spotter_tpu.utils.quant import int8_dense, int8_dense_wanted
 
 NORM_TOPK_EPS = 1e-20  # modeling_kimi.py's KimiMoEGate: weights / (sum + 1e-20)
 ATTENTION_IMAGES = 16  # images the latent-attention layer works at once
@@ -112,6 +114,62 @@ class KimiDeltaAttention(nn.Module):
         return _dense(cfg.hidden_size, self.dtype, "o_proj")(out.reshape(b, t, width)), spread
 
 
+def _turn(x, cos, sin):
+    """x (..., S, D) turned by the token's index, the half-split convention of
+    `qwen3_next.apply_rotary` over the whole of D: in float32, back in x's
+    type. cos, sin (S, D). Each half is written from the two halves read,
+    with no rotated copy of the whole between."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([(x1 * cos[:, :half] - x2 * sin[:, :half]).astype(x.dtype),
+                            (x2 * cos[:, half:] + x1 * sin[:, half:]).astype(x.dtype)], axis=-1)
+
+
+class HeadsDense(nn.Module):
+    """`_dense(heads * sum(widths))`'s parameter (`kernel`, (in, heads *
+    sum(widths)), each head's columns together), its product written
+    head-major: (B, S, in) -> one (B, heads, S, w) a width, in order. Each
+    is a product of its own columns, so XLA writes it where the attention
+    reads it. The int8 path (`utils/quant.py`, as `QuantDense` takes it)
+    turns the whole product instead."""
+
+    heads: int
+    widths: tuple[int, ...]
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, n = x.shape
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (n, self.heads * sum(self.widths)), jnp.float32)
+        ends = np.cumsum(self.widths)[:-1]
+        if int8_dense_wanted(n, batch=b):
+            y = int8_dense(x, kernel, self.dtype).reshape(b, s, self.heads, -1)
+            return jnp.split(y.transpose(0, 2, 1, 3), ends, axis=-1)
+        w = kernel.astype(self.dtype).reshape(n, self.heads, -1)
+        return [jnp.einsum("bsn,nhd->bhsd", x.astype(self.dtype), part)
+                for part in jnp.split(w, ends, axis=-1)]
+
+
+class HeadsOut(nn.Module):
+    """`_dense(features)` over the heads' outputs side by side, read
+    head-major: (B, H, S, d) -> (B, S, features), the parameter `kernel` (H
+    d, features) as `_dense`'s."""
+
+    features: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, h, s, d = x.shape
+        kernel = self.param("kernel", nn.initializers.lecun_normal(), (h * d, self.features),
+                            jnp.float32)
+        if int8_dense_wanted(h * d, batch=b):
+            return int8_dense(x.transpose(0, 2, 1, 3).reshape(b, s, h * d), kernel, self.dtype)
+        return jnp.einsum("bhsd,hdo->bso", x.astype(self.dtype),
+                          kernel.astype(self.dtype).reshape(h, d, self.features))
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention as a prefill runs it, over a batch.
 
@@ -128,10 +186,21 @@ class LatentAttention(nn.Module):
     of nope + rope channels, scaled by their count^-0.5,
     then `o_proj`. Keys and values are expanded a head: nothing is cached.
 
-    Keys of 192 fill lanes of 256, and the kernel wants them padded and
-    head-major: a batch's q, k and their copies are the program's peak (7.7 GB
-    at 32 images). Over `ATTENTION_IMAGES` the layer runs in parts, one after
-    the other, each part's copies freed."""
+    Everything from the products to `o_proj` is head-major and padded, as the
+    kernel reads it (`layers.causal_latent_attention`): the tokens are padded
+    once, on the hidden state, to the kernel's multiple; `q_b_proj` (or
+    `q_proj`) and `kv_b_proj` write each head's parts by products of their
+    own columns (`HeadsDense`); q is written once, by the product itself
+    where nothing turns, else by the pass that joins and scales it (the
+    turned halves written just before); k by the pass that joins k_nope and
+    the shared k_pe; `o_proj` reads the real tokens of the kernel's result
+    where it lies (`HeadsOut`; XLA folds the cut into the product). The
+    padded tokens follow every real one, so no real token sees them. The
+    einsum form (short sequences, the CPU) takes the same tensors.
+
+    Keys of 192 fill lanes of 256: a batch's q, k and v are the program's
+    peak. Over `ATTENTION_IMAGES` the layer runs in parts, one after the
+    other, each part's operands freed."""
 
     config: KimiLinearDetConfig | JoyAIFlashDetConfig
     dtype: jnp.dtype = jnp.float32
@@ -143,12 +212,15 @@ class LatentAttention(nn.Module):
         cfg = self.config
         heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        # the turned channels in a product of their own; unturned, q is one
+        # product, its scale applied as it is written
+        q_widths = (nope + pe,) if self.rope_theta is None else (nope, pe)
         if self.q_lora_rank is None:
-            q_proj = _dense(heads * (nope + pe), self.dtype, "q_proj")
+            q_proj = HeadsDense(heads, q_widths, self.dtype, name="q_proj")
         else:
             q_a = _dense(self.q_lora_rank, self.dtype, "q_a_proj")
             q_norm = RMSNorm(cfg.rms_norm_eps, name="q_a_layernorm")
-            q_b = _dense(heads * (nope + pe), self.dtype, "q_b_proj")
+            q_b = HeadsDense(heads, q_widths, self.dtype, name="q_b_proj")
 
             def q_proj(x):
                 with jax.named_scope("latent_q"):
@@ -156,33 +228,38 @@ class LatentAttention(nn.Module):
 
         kv_a = _dense(rank + pe, self.dtype, "kv_a_proj_with_mqa")
         kv_norm = RMSNorm(cfg.rms_norm_eps, name="kv_a_layernorm")
-        kv_b = _dense(heads * (nope + dv), self.dtype, "kv_b_proj")
-        o_proj = _dense(cfg.hidden_size, self.dtype, "o_proj")
+        kv_b = HeadsDense(heads, (nope, dv), self.dtype, name="kv_b_proj")
+        o_proj = HeadsOut(cfg.hidden_size, self.dtype, name="o_proj")
 
         def attend(x):
             b, t, _ = x.shape
-            q = q_proj(x).reshape(b, t, heads, nope + pe)
+            s = latent_attention_tokens(t)
+            x = jnp.pad(x, ((0, 0), (0, s - t), (0, 0)))
+            q = q_proj(x)
             with jax.named_scope("latent_kv"):
                 latent = kv_a(x)
-                kv = kv_b(kv_norm(latent[..., :rank])).reshape(b, t, heads, nope + dv)
-                k_pe = latent[:, :, None, rank:]
+                k_nope, v = kv_b(kv_norm(latent[..., :rank]))
+                k_pe = latent[:, None, :, rank:]
             if self.rope_theta is not None:
                 with jax.named_scope("latent_rope"):
-                    cos, sin = rotary_tables(t, pe, self.rope_theta)
-                    q = jnp.concatenate([q[..., :nope], apply_rotary(q[..., nope:], cos, sin)], -1)
-                    k_pe = apply_rotary(k_pe, cos, sin)
+                    cos, sin = rotary_tables(s, pe, self.rope_theta)
+                    q = [q[0], _turn(q[1], cos, sin)]
+                    k_pe = _turn(k_pe, cos, sin)
             with jax.named_scope("latent_kv"):
-                k_pe = jnp.broadcast_to(k_pe, (b, t, heads, pe))
-                k, v = jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
-            q = q * (nope + pe)**-0.5
+                # [k_nope | k_pe] as the sum of the two, each padded to the key's
+                # width: XLA broadcasts the shared k_pe over the heads inside the
+                # pass that writes k (a concatenation writes the broadcast first)
+                lanes = ((0, 0), (0, 0), (0, 0))
+                k = jnp.pad(k_nope, lanes + ((0, pe),)) + jnp.pad(k_pe, lanes + ((nope, 0),))
+            q = jnp.concatenate(q, axis=-1) * (nope + pe)**-0.5
             if flash_attention_enabled() and t >= FLASH_ATTN_MIN_SEQ:
                 out = causal_latent_attention(q, k, v)
             else:
-                logits = jnp.einsum("bqhd,bshd->bhqs", q, k).astype(jnp.float32)
-                logits = jnp.where(np.tril(np.ones((t, t), bool)), logits, -jnp.inf)
+                logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
+                logits = jnp.where(np.tril(np.ones((s, s), bool)), logits, -jnp.inf)
                 weights = nn.softmax(logits, axis=-1).astype(self.dtype)
-                out = jnp.einsum("bhqs,bshd->bqhd", weights, v)
-            return o_proj(out.reshape(b, t, heads * dv))
+                out = jnp.einsum("bhqk,bhkd->bhqd", weights, v)
+            return o_proj(out[:, :, :t])
 
         parts = [x[i:i + ATTENTION_IMAGES] for i in range(0, x.shape[0], ATTENTION_IMAGES)]
         done = [attend(parts[0])]

@@ -747,16 +747,26 @@ def grid_sample_bilinear_nhwc(value: jnp.ndarray, grid: jnp.ndarray) -> jnp.ndar
     return top * (1 - wy) + bot * wy
 
 
+def latent_attention_tokens(s: int) -> int:
+    """The token count `causal_latent_attention` takes for S tokens: S padded
+    to a multiple of its block, the block at most `_CAUSAL_BLOCK`."""
+    blk = min(_CAUSAL_BLOCK, -(-s // 128) * 128)
+    return -(-s // blk) * blk
+
+
 @jax.named_scope("causal_attention")
 def causal_latent_attention(q, k, v, interpret: bool = False):
     """Causal multi-head attention whose value width is not its key width
     (latent attention expanded a head: keys of 192, values of 128): q, k (B,
-    S, H, dqk), q pre-scaled; v (B, S, H, dv) -> (B, S, H, dv). The splash
+    H, S, dqk), q pre-scaled; v (B, H, S, dv) -> (B, H, S, dv), head-major as
+    the kernel reads and writes them, so nothing here moves a byte. The splash
     kernel's multi-head form (`splash_mha_fwd_no_residuals` on a device
     trace) with `head_dim_v` the values' own, once per image under vmap, under
     a causal mask, so the blocks above the diagonal are never visited. Below
-    `causal_gqa_attention`, whose lines stay where they are. S is padded to a
-    multiple of the block as there."""
+    `causal_gqa_attention`, whose lines stay where they are. S is already a
+    multiple of the block (`latent_attention_tokens`); padded tokens follow
+    every real one, and no real token attends forward, so it needs no segment
+    ids."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
     )
@@ -764,25 +774,18 @@ def causal_latent_attention(q, k, v, interpret: bool = False):
         splash_attention_mask as _sm,
     )
 
-    b, s, h, _ = q.shape
-    blk = min(_CAUSAL_BLOCK, -(-s // 128) * 128)
-    s_pad = -(-s // blk) * blk
+    _, h, s, _ = q.shape
+    if s != latent_attention_tokens(s):
+        raise ValueError(f"{s} tokens: pad them to {latent_attention_tokens(s)} first")
+    blk = min(_CAUSAL_BLOCK, s)
     bs = _sk.BlockSizes(
         block_q=blk, block_kv=blk, block_kv_compute=blk,
         block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
         block_q_dq=blk, block_kv_dq=blk,
     )
     kernel = _sk.make_splash_mha_single_device(
-        mask=_sm.MultiHeadMask([_sm.CausalMask((s_pad, s_pad))] * h),
+        mask=_sm.MultiHeadMask([_sm.CausalMask((s, s))] * h),
         block_sizes=bs,
         interpret=interpret,
     )
-
-    def prep(x):  # (B, S, H, d) -> (B, H, S_pad, d)
-        x = x.transpose(0, 2, 1, 3)
-        if s_pad != s:
-            x = jnp.pad(x, ((0, 0), (0, 0), (0, s_pad - s), (0, 0)))
-        return x
-
-    out = jax.vmap(kernel)(prep(q), prep(k), prep(v))
-    return out[:, :, :s].transpose(0, 2, 1, 3)
+    return jax.vmap(kernel)(q, k, v)

@@ -29,7 +29,7 @@ from PIL import Image
 from test_qwen3_next import _jpeg_client, _write_safetensors
 
 from spotter_tpu.models import kimi_linear as served
-from spotter_tpu.models import layers
+from spotter_tpu.models import layers, zoo
 from spotter_tpu.models.configs import KimiLinearDetConfig
 from spotter_tpu.ops import kda, moe
 from spotter_tpu.ops.delta_rule import chunked_gated_delta_rule
@@ -238,18 +238,54 @@ def test_latent_attention_by_hand(params, tokens):
 
 def test_the_causal_kernel_takes_values_narrower_than_keys():
     """`causal_latent_attention` (the splash kernel, interpret mode) at keys of
-    192 and values of 128, 300 tokens (padded to 384): eager attention's answer."""
+    192 and values of 128, head-major, 300 tokens padded to 384 with noise:
+    eager attention's answer on the 300, for the pad follows every real
+    token. Tokens not yet padded to the kernel's multiple are refused."""
     rng = np.random.default_rng(4)
-    q = rng.standard_normal((1, 300, 2, 192)).astype(np.float32) * 192**-0.5
-    k = rng.standard_normal((1, 300, 2, 192)).astype(np.float32)
-    v = rng.standard_normal((1, 300, 2, 128)).astype(np.float32)
+    q = rng.standard_normal((1, 2, 384, 192)).astype(np.float32) * 192**-0.5
+    k = rng.standard_normal((1, 2, 384, 192)).astype(np.float32)
+    v = rng.standard_normal((1, 2, 384, 128)).astype(np.float32)
     got = layers.causal_latent_attention(q, k, v, interpret=True)
-    scores = np.einsum("bqhd,bshd->bhqs", q, k)
+    assert got.shape == (1, 2, 384, 128)
+    q, k, v = q[:, :, :300], k[:, :, :300], v[:, :, :300]
+    scores = np.einsum("bhqd,bhkd->bhqk", q, k)
     scores = np.where(np.tril(np.ones((300, 300), bool)), scores, -np.inf)
     weights = np.exp(scores - scores.max(-1, keepdims=True))
-    want = np.einsum("bhqs,bshd->bqhd", weights / weights.sum(-1, keepdims=True), v)
-    assert got.shape == (1, 300, 2, 128)
-    np.testing.assert_allclose(got, want, atol=2e-5)
+    want = np.einsum("bhqk,bhkd->bhqd", weights / weights.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got[:, :, :300], want, atol=2e-5)
+    with pytest.raises(ValueError, match="pad them to 384"):
+        layers.causal_latent_attention(q, k, v, interpret=True)
+
+
+@pytest.mark.parametrize("family", ["joyai_flash", "kimi_linear"])
+def test_latent_attention_through_the_kernel_is_its_einsum_form(family, monkeypatch):
+    """The layer through the splash kernel (interpret mode, the flash path
+    forced) against its own einsum form, float32, 300 tokens (the kernel's
+    384 less 84 padded ones): the query latent with its rotary term (the tiny
+    JoyAI configuration) and NoPE with no query latent (the tiny Kimi one).
+    The kernel is handed q, k and v head-major and already padded."""
+    if family == "joyai_flash":
+        cfg = zoo.tiny_joyai_flash_det_config()
+        module = served.LatentAttention(cfg, q_lora_rank=cfg.q_lora_rank, rope_theta=cfg.rope_theta)
+    else:
+        cfg = zoo.tiny_kimi_linear_det_config()
+        module = served.LatentAttention(cfg)
+    x = np.random.default_rng(6).standard_normal((2, 300, cfg.hidden_size)).astype(np.float32)
+    p = _randomise(module.init(jax.random.PRNGKey(0), x))
+    want = module.apply(p, x)
+    handed = []
+
+    def kernel(q, k, v):
+        handed.append((q.shape, k.shape, v.shape))
+        return layers.causal_latent_attention(q, k, v, interpret=True)
+
+    monkeypatch.setattr(served, "flash_attention_enabled", lambda: True)
+    monkeypatch.setattr(served, "FLASH_ATTN_MIN_SEQ", 0)
+    monkeypatch.setattr(served, "causal_latent_attention", kernel)
+    got = module.apply(p, x)
+    heads, qk = cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    assert handed == [((2, heads, 384, qk), (2, heads, 384, qk), (2, heads, 384, cfg.v_head_dim))]
+    np.testing.assert_allclose(got, want, atol=1e-5)
 
 
 def test_moe_layer_and_its_counters(params, tokens):

@@ -216,12 +216,74 @@ def test_kda_kernel_at_kimi_linear_shapes(chip):
 
 def test_latent_attention_kernel_at_kimi_linear_shapes(chip):
     """The latent-attention layer's call at the bucket of 8: 32 heads, keys of
-    192 (one and a half lane tiles), values of 128."""
-    qk, v = ((8, 4300, 32, 192), jnp.bfloat16), ((8, 4300, 32, 128), jnp.bfloat16)
+    192 (one and a half lane tiles), values of 128, head-major and 4300 tokens
+    already padded to 4608."""
+    qk, v = ((8, 32, 4608, 192), jnp.bfloat16), ((8, 32, 4608, 128), jnp.bfloat16)
     text = compile_for(chip, layers.causal_latent_attention, qk, qk, v).as_text()
     assert "tpu_custom_call" in text and "splash_mha_fwd_no_residuals" in text
     # the shape benchmarks/kernels/mla_causal_attention.py reads the bucket from
     assert "bf16[8,32,4608,128]" in text
+
+
+RELAYOUT_OPS = ("copy", "pad", "slice", "broadcast", "convert")
+_BYTES = {"bf16": 2, "f16": 2, "s16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
+
+
+def relayout_bytes(text, floor=50e6):
+    """Output bytes of the entry computation's copies, pads, slices,
+    broadcasts, converts and loop fusions, each over `floor`: what the
+    program writes to move or reshape data rather than compute it."""
+    import re
+
+    total = 0
+    for line in text[text.index("\nENTRY"):].splitlines():
+        found = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\(", line)
+        if not found:
+            continue
+        shapes, op = found.groups()
+        if op not in RELAYOUT_OPS and not (op == "fusion" and "kind=kLoop" in line):
+            continue
+        size = sum(_BYTES[dt] * int(np.prod([int(d) for d in dims.split(",") if d]))
+                   for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", shapes))
+        total += size if size > floor else 0
+    return total
+
+
+@pytest.mark.parametrize("family", ["joyai_flash_det_ep2", "kimi_linear_det_ep4"])
+def test_latent_attention_layer_writes_its_operands_once(chip, monkeypatch, family):
+    """One latent-attention layer at the published widths, one part of 16
+    images, bfloat16, the flash path on: between the products and the kernel,
+    and between the kernel and `o_proj`, the program moves at most 5 GB
+    (2.68 GB on JoyAI's widths and 1.54 on Kimi's when this was written;
+    11.22 and 9.07, in 21 and 15 such ops, when q, k and v were made
+    token-major and the kernel's wrapper turned and padded each). The
+    kernel is there, and its result holds the member the benchmark counts
+    images from, (images, heads, tokens padded, values)."""
+    import json
+    import pathlib
+
+    from spotter_tpu.models import kimi_linear
+    from spotter_tpu.models.configs import JoyAIFlashDetConfig, KimiLinearDetConfig
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    raw = json.loads((root / "benchmarks" / "configs" / f"{family}.json").read_text())
+    monkeypatch.setattr(kimi_linear, "flash_attention_enabled", lambda: True)
+    if family.startswith("joyai"):
+        cfg = JoyAIFlashDetConfig.from_hf(raw)
+        module = kimi_linear.LatentAttention(cfg, dtype=jnp.bfloat16, q_lora_rank=cfg.q_lora_rank,
+                                             rope_theta=cfg.rope_theta)
+    else:
+        cfg = KimiLinearDetConfig.from_hf(raw)
+        module = kimi_linear.LatentAttention(cfg, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((16, cfg.num_tokens, cfg.hidden_size), jnp.bfloat16, sharding=chip)
+    shapes = jax.eval_shape(module.init, jax.random.PRNGKey(0), x)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16 if a.ndim >= 2 else jnp.float32,
+                                       sharding=chip), shapes)
+    text = jax.jit(module.apply).lower(params, x).compile().as_text()
+    assert "splash_mha_fwd_no_residuals" in text
+    assert f"bf16[16,{cfg.num_attention_heads},4608,{cfg.v_head_dim}]" in text
+    assert relayout_bytes(text) <= 5e9
 
 
 @pytest.mark.parametrize("k, n, form, result", [
